@@ -58,6 +58,7 @@ def run_block_mfu(batch: int, hidden: int, layers: int, iters: int) -> dict:
 
     import tensorframes_tpu as tfs
     from tensorframes_tpu import config as tfs_config
+    from tensorframes_tpu import dsl
     from tensorframes_tpu.models import MLP
     from tensorframes_tpu.runtime import costmodel
 
@@ -71,7 +72,9 @@ def run_block_mfu(batch: int, hidden: int, layers: int, iters: int) -> dict:
         jax.block_until_ready(
             tfs.map_blocks(graph, df, trim=True).column("probs").values
         )
-        entry = costmodel.program_costs().get(graph.fingerprint())
+        entry = costmodel.program_costs().get(
+            dsl.build(graph)[0].fingerprint()
+        )
         flops_per_call = entry["flops_per_exec"] if entry else None
         if flops_per_call is None:
             # ledger off (TFS_COST_LEDGER=0) or capture unavailable:

@@ -125,6 +125,9 @@ def main():
             os.environ.setdefault(k, v)
     here = os.path.dirname(os.path.abspath(__file__))
     sys.path.insert(0, os.path.dirname(here))
+    from tensorframes_tpu import config
+
+    config.enable_compilation_cache()
     for mod in (
         "convert_bench",
         "pipeline_bench",

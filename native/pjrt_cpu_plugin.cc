@@ -3,8 +3,8 @@
 //
 // Purpose (VERDICT r3 #4): un-gate the native executor host
 // (native/pjrt_host.cc) from TPU chip health. jaxlib ships no dlopen-able
-// CPU plugin, and the TPU plugin hangs when the shared chip is wedged;
-// this plugin gives the host an always-available CPU backend, the same
+// CPU plugin, and an accelerator plugin needs its device; this plugin
+// gives the host an always-available CPU backend, the same
 // role libtensorflow's CPU kernels played for the reference's tests
 // (every reference suite ran the real native runtime,
 // /root/reference/src/test/scala/org/tensorframes/TensorFlossTestSparkContext.scala:14-22).

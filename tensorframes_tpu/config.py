@@ -17,7 +17,8 @@ Knobs:
   partitioning (None = single block).
 - ``default_mesh``: mesh used by verbs when ``mesh=`` is omitted
   (None = single device).
-- ``compilation_cache_dir``: enables JAX's persistent compilation cache
+- `enable_compilation_cache()` (a call, not a knob): turns on JAX's
+  persistent compilation cache at a place the deployment controls
   (survives process restarts — the reference re-imported its graph into
   a fresh TF session per task, `DebugRowOps.scala:790`).
 - ``aggregate_buffer_rows``: host-side group batching threshold (the
@@ -57,6 +58,7 @@ __all__ = [
     "tuned",
     "default_value",
     "reset_tuning",
+    "enable_compilation_cache",
 ]
 
 
@@ -161,7 +163,6 @@ class Config:
     )
     default_num_blocks: Optional[int] = None
     default_mesh: Optional[object] = None
-    compilation_cache_dir: Optional[str] = None
     aggregate_buffer_rows: int = dataclasses.field(
         default_factory=lambda: _env_int(
             "TFS_AGGREGATE_BUFFER_ROWS", 10, "aggregate_buffer_rows",
@@ -809,9 +810,8 @@ class Config:
     )
     # Device-grant watchdog (`runtime.faults.device_grant`): when > 0,
     # the scheduler's device acquisition runs under a watchdog thread
-    # and falls back to the CPU backend with a loud one-time warning if
-    # the accelerator backend wedges at device grant for this long
-    # (the stuck-shared-TPU failure mode). 0 disables the watchdog.
+    # and raises `faults.DeviceGrantTimeout` if the accelerator backend
+    # wedges at device grant for this long. 0 disables the watchdog.
     # Env override TFS_DEVICE_GRANT_TIMEOUT_S seeds the initial value.
     device_grant_timeout_s: float = dataclasses.field(
         default_factory=lambda: _env_float(
@@ -973,12 +973,33 @@ def update(**kwargs) -> None:
             # superseded
             _EXPLICIT.add(k)
             _TUNED.pop(k, None)
-    if "compilation_cache_dir" in kwargs and kwargs["compilation_cache_dir"]:
-        import jax
 
-        jax.config.update(
-            "jax_compilation_cache_dir", kwargs["compilation_cache_dir"]
+
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its
+    directory. A directory given from outside wins: where
+    ``JAX_COMPILATION_CACHE_DIR`` is set, jax has already read it into
+    ``jax_compilation_cache_dir`` and this sets no other. Otherwise the
+    cache is ``<checkout>/.jax_cache``, resolved from this package's own
+    location — a fixed path, because the path is part of the cache key
+    (a temp name, pid or time would never hit). Entry points
+    (`chip_smoke.py`, `bench.py`, `benchmarks/run_all.py`) call this at
+    start-up; importing the package never does."""
+    import os
+
+    import jax
+
+    path = jax.config.jax_compilation_cache_dir
+    if not path:
+        path = os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache",
         )
+        jax.config.update("jax_compilation_cache_dir", path)
+    # cache every program, not only those that took over a second to
+    # compile: a verb's many small block programs are most of a cold run
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
 
 
 @contextlib.contextmanager

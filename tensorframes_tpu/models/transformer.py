@@ -55,23 +55,34 @@ class TransformerLM:
         p: Dict[str, jax.Array] = {
             "embed": init(next(keys), (vocab, d_model), 0.02),
             "pos": init(next(keys), (max_seq, d_model), 0.02),
-            "ln_f_g": jnp.ones((d_model,)),
-            "ln_f_b": jnp.zeros((d_model,)),
+            "ln_f_g": jnp.ones((d_model,), jnp.float32),
+            "ln_f_b": jnp.zeros((d_model,), jnp.float32),
         }
-        s = 1.0 / np.sqrt(d_model)
+        s = float(1.0 / np.sqrt(d_model))  # python float: stays float32
+        # (gain, bias) rows, float32 like every other parameter: under the
+        # package's x64 mode a dtype-less ones() is float64, which promotes
+        # the whole residual stream (and hands the TPU kernel 64-bit
+        # operands Mosaic refuses)
+        ln = jnp.stack(
+            [jnp.ones((d_model,), jnp.float32),
+             jnp.zeros((d_model,), jnp.float32)]
+        )
         for i in range(n_layers):
             p[f"l{i}_qkv"] = init(next(keys), (d_model, 3 * d_model), s)
             p[f"l{i}_proj"] = init(next(keys), (d_model, d_model), s)
             p[f"l{i}_mlp_up"] = init(next(keys), (d_model, 4 * d_model), s)
             p[f"l{i}_mlp_down"] = init(next(keys), (4 * d_model, d_model), s)
-            p[f"l{i}_ln1"] = jnp.ones((2, d_model)) * jnp.array([[1.0], [0.0]])
-            p[f"l{i}_ln2"] = jnp.ones((2, d_model)) * jnp.array([[1.0], [0.0]])
+            p[f"l{i}_ln1"] = ln
+            p[f"l{i}_ln2"] = ln
         self.params = p
 
     # ------------------------------------------------------------------
     def _attention(self, q, k, v, mesh: Optional[Mesh]):
         """(S, H, hd) -> (S, H, hd); ring attention per head when a mesh
-        is given, full attention otherwise."""
+        is given; on a TPU the Pallas kernel (forward compiled for the
+        MXU, backward = `full_attention`'s VJP via the kernel's
+        `custom_vjp`, so `train_step` differentiates it); full attention
+        elsewhere."""
         qh = jnp.swapaxes(q, 0, 1)  # (H, S, hd)
         kh = jnp.swapaxes(k, 0, 1)
         vh = jnp.swapaxes(v, 0, 1)
@@ -207,7 +218,7 @@ class TransformerLM:
                 f"n_heads={H} and vocab={V} must divide model axis {mp}"
             )
         v_per = V // mp
-        scale = 1.0 / np.sqrt(hd)
+        scale = float(1.0 / np.sqrt(hd))
         ring = functools.partial(
             _ring_shard, axis_name="seq", causal=True, scale=scale
         )

@@ -10,9 +10,16 @@ P·V ride the MXU via `jnp.dot(..., preferred_element_type=f32)`; masking
 
 This kernel is the single-device building block the ring attention in
 `parallel/ring.py` composes across chips (K/V rotation over ICI); it is
-also used directly by `models.TransformerLM` for unsharded TPU runs. On
-CPU it runs in Pallas interpret mode (tests) — production CPU paths use
-`parallel.ring.full_attention`.
+also used directly by `models.TransformerLM` for unsharded TPU runs.
+It compiles for the TPU unless the caller passes ``interpret=True``
+(the CPU tests do) — it never picks interpret mode from the backend by
+itself, so a chip run cannot be an interpreted one without saying so.
+Production CPU paths use `parallel.ring.full_attention`.
+
+Differentiation: the forward pass is the kernel; the backward pass is
+`full_attention`'s VJP, recomputed from q/k/v (`jax.custom_vjp` — a
+`pallas_call` has no transpose rule of its own). A fused backward
+kernel is ROADMAP Queue 2 item 3.
 """
 
 from __future__ import annotations
@@ -90,14 +97,19 @@ def flash_attention(
     scale: Optional[float] = None,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ) -> jax.Array:
     """Single-device blockwise attention. q/k/v: (seq, head_dim)."""
-    seq, d = q.shape
     if scale is None:
-        scale = 1.0 / np.sqrt(d)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        scale = 1.0 / np.sqrt(q.shape[-1])
+    return _flash(
+        q, k, v, bool(causal), float(scale), block_q, block_k,
+        bool(interpret),
+    )
+
+
+def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
+    seq, d = q.shape
 
     blk_q = min(block_q, max(8, seq))
     blk_k = min(block_k, max(8, seq))
@@ -113,7 +125,7 @@ def flash_attention(
 
     kernel = functools.partial(
         _flash_kernel,
-        scale=float(scale),
+        scale=scale,
         causal=causal,
         seq_len=seq,
         blk_q=blk_q,
@@ -137,3 +149,23 @@ def flash_attention(
         interpret=interpret,
     )(qp, kp, vp)
     return out[:seq] if pad_q else out
+
+
+_flash = jax.custom_vjp(_flash_forward, nondiff_argnums=(3, 4, 5, 6, 7))
+
+
+def _flash_fwd(q, k, v, *static):
+    return _flash_forward(q, k, v, *static), (q, k, v)
+
+
+def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
+    from ..parallel.ring import full_attention
+
+    _, vjp = jax.vjp(
+        lambda q, k, v: full_attention(q, k, v, causal=causal, scale=scale),
+        *res,
+    )
+    return vjp(g)
+
+
+_flash.defvjp(_flash_fwd, _flash_bwd)

@@ -117,7 +117,7 @@ def ring_attention(
     same sharding. Sequence length must divide the axis size.
     """
     if scale is None:
-        scale = 1.0 / np.sqrt(q.shape[-1])
+        scale = float(1.0 / np.sqrt(q.shape[-1]))
     fn = functools.partial(
         _ring_shard, axis_name=axis, causal=causal, scale=scale
     )
@@ -131,7 +131,7 @@ def ring_attention(
 def full_attention(q, k, v, *, causal=False, scale=None):
     """Reference single-device attention (for conformance tests)."""
     if scale is None:
-        scale = 1.0 / np.sqrt(q.shape[-1])
+        scale = float(1.0 / np.sqrt(q.shape[-1]))
     scores = (q.astype(jnp.float32) @ k.astype(jnp.float32).T) * scale
     if causal:
         n, m = scores.shape

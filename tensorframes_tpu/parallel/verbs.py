@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax import shard_map
 
 from ..frame import Column, TensorFrame
@@ -167,6 +167,38 @@ def _mesh_in_specs(params, bindings, main, col_of=None):
     )
 
 
+def _on_mesh(mesh: Mesh, in_specs, feeds) -> List:
+    """Commit device-resident feeds to ``mesh`` under their shard_map
+    specs. A verb output (or ``df.to_device()`` column) is committed to
+    the device that produced it, and jit refuses committed arguments
+    whose devices differ from the shard_map's ("incompatible devices");
+    host arrays pass through — jit shards those itself."""
+    return [
+        jax.device_put(f, NamedSharding(mesh, sp))
+        if isinstance(f, jax.Array)
+        else f
+        for f, sp in zip(feeds, in_specs)
+    ]
+
+
+def _concat_parts(mesh: Mesh, parts: List):
+    """`api._concat_parts` for mesh verbs: the shard output lives on the
+    mesh, while a tail computed from a device-committed frame lives on
+    that one device — replicate such a part over the mesh first, so the
+    concatenate sees one device set."""
+    if len(parts) > 1 and mesh.devices.size > 1:
+        rep = NamedSharding(mesh, P())
+        parts = [
+            jax.device_put(p, rep)
+            if isinstance(p, jax.Array)
+            and p.committed
+            and len(p.sharding.device_set) == 1
+            else p
+            for p in parts
+        ]
+    return _api._concat_parts(parts)
+
+
 # ---------------------------------------------------------------------------
 # map_blocks
 # ---------------------------------------------------------------------------
@@ -252,7 +284,7 @@ def map_blocks(
         )
         outs = _mesh_call(
             "mesh.map_blocks", graph.fingerprint(), s * ndev, ndev,
-            sharded, *_feeds(main),
+            sharded, *_on_mesh(mesh, in_specs, _feeds(main)),
         )
         maybe_check_numerics(fetch_list, outs, "map_blocks (mesh shards)")
         shard_out = None
@@ -290,7 +322,7 @@ def map_blocks(
     out_cols = [
         Column(
             _base(f),
-            _api._concat_parts(acc[_base(f)])
+            _concat_parts(mesh, acc[_base(f)])
             if acc[_base(f)]
             else _api._empty_output(summary, _base(f), drop_lead=True),
         )
@@ -473,7 +505,7 @@ def map_rows(
         )
         outs = _mesh_call(
             "mesh.map_rows", graph.fingerprint(), s * ndev, ndev,
-            sharded, *_feeds(main),
+            sharded, *_on_mesh(mesh, in_specs, _feeds(main)),
         )
         maybe_check_numerics(fetch_list, outs, "map_rows (mesh shards)")
         for n, o in zip(out_names, outs):
@@ -499,7 +531,7 @@ def map_rows(
     out_cols = [
         Column(
             n,
-            _api._concat_parts(parts)
+            _concat_parts(mesh, parts)
             if parts
             else _api._empty_output(summary, n, drop_lead=False),
         )
@@ -622,7 +654,7 @@ def _fn_mesh(
                 )
             ),
         )
-        outs = sharded(*_feeds(main))
+        outs = sharded(*_on_mesh(mesh, in_specs, _feeds(main)))
         shard_out = None
         for name, o in outs.items():
             _validate(
@@ -659,7 +691,7 @@ def _fn_mesh(
         )
         acc = {n: [v] for n, v in empties.items()}
     out_cols = [
-        Column(n, _api._concat_parts(parts)) for n, parts in acc.items()
+        Column(n, _concat_parts(mesh, parts)) for n, parts in acc.items()
     ]
     if trim:
         offsets = list(np.cumsum([0] + (block_sizes or [0])))
@@ -724,7 +756,7 @@ def fused_map_blocks(
         )
         outs = _mesh_call(
             "mesh.lazy.force", graph.fingerprint(), s * ndev, ndev,
-            sharded, *[main[c] for c in cols_used],
+            sharded, *_on_mesh(mesh, in_specs, [main[c] for c in cols_used]),
         )
         maybe_check_numerics(out_names, outs, "lazy fused map (mesh shards)")
         for n, o in zip(out_names, outs):
@@ -753,7 +785,7 @@ def fused_map_blocks(
                 )
             acc[n].append(o)
     out_cols = [
-        Column(n, _api._concat_parts(acc[n])) for n in out_names if acc[n]
+        Column(n, _concat_parts(mesh, acc[n])) for n in out_names if acc[n]
     ]
     shadow = set(out_names)
     cols = out_cols + [
@@ -819,7 +851,8 @@ def fused_reduce_blocks(
         )
         outs = _mesh_call(
             "mesh.reduce_blocks.fused", fused_graph.fingerprint(),
-            s * ndev, ndev, sharded, *[main[c] for c in cols_used],
+            s * ndev, ndev, sharded,
+            *_on_mesh(mesh, in_specs, [main[c] for c in cols_used]),
         )
         partials.append(tuple(outs))
     if cols_used and tail[cols_used[0]].shape[0] > 0:
@@ -961,7 +994,8 @@ def reduce_blocks(
             )
             outs = _mesh_call(
                 "mesh.reduce_blocks", graph.fingerprint(), s * ndev, ndev,
-                sharded, shard_valids, *[main[c] for c in cols_used],
+                sharded, shard_valids,
+                *_on_mesh(mesh, col_specs, [main[c] for c in cols_used]),
             )
         else:
             def local_then_gather(*cols):
@@ -990,7 +1024,8 @@ def reduce_blocks(
             )
             outs = _mesh_call(
                 "mesh.reduce_blocks", graph.fingerprint(), s * ndev, ndev,
-                sharded, *[main[c] for c in cols_used],
+                sharded,
+                *_on_mesh(mesh, col_specs, [main[c] for c in cols_used]),
             )
         partials.append(tuple(outs))
     if cols_used and tail[cols_used[0]].shape[0] > 0:
@@ -1108,7 +1143,7 @@ def reduce_rows(
         )
         outs = _mesh_call(
             "mesh.reduce_rows", graph.fingerprint(), s * ndev, ndev,
-            sharded, *[main[c] for c in cols_used],
+            sharded, *_on_mesh(mesh, in_specs, [main[c] for c in cols_used]),
         )
         partials.append(tuple(np.asarray(o) for o in outs))
 
@@ -1248,7 +1283,7 @@ def aggregate(
         )
         outs = _mesh_call(
             "mesh.aggregate.segment", graph.fingerprint(), s * ndev, ndev,
-            sharded, gid[: s * ndev], *main_cols,
+            sharded, *_on_mesh(mesh, in_specs, [gid[: s * ndev], *main_cols]),
         )
         acc = [np.asarray(o)[:num_keys] for o in outs]
     if tail_cols and tail_cols[0].shape[0] > 0:
@@ -1339,7 +1374,8 @@ def _aggregate_mesh_general(
         if lead >= ndev and lead % ndev == 0:
             return _mesh_call(
                 "mesh.aggregate.chunk", graph.fingerprint(), lead, ndev,
-                sharded, *feeds,
+                sharded,
+                *_on_mesh(mesh, [P("data")] * len(feeds), feeds),
             )
         return local(*feeds)
 

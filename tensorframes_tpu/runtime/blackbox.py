@@ -24,7 +24,7 @@ surface the repo already has:
   ambient `telemetry.current_program()`, else the newest span in the
   ring carrying a ``program`` attribute).
 
-Trigger taxonomy (every escape hatch reports through THIS choke
+Trigger classification (every escape hatch reports through THIS choke
 point): ``deadline`` (`DeadlineExceeded`), ``cancel`` (`Cancelled`),
 ``shed`` (`OverloadError` from admission), ``oom`` (resource-class
 split exhaustion, `faults.record_oom`), ``fault`` (any other

@@ -294,9 +294,10 @@ def _local_devices() -> List:
     t = _config.get().device_grant_timeout_s
     if (t and t > 0) or _dl.remaining() is not None:
         # device-grant watchdog: a wedged accelerator backend (stuck at
-        # device grant — the shared-TPU failure mode) times out here and
-        # the process degrades to the CPU backend with a loud one-time
-        # warning instead of hanging forever. An active verb DEADLINE
+        # device grant) times out here and the verb raises
+        # `faults.DeviceGrantTimeout` naming the budget instead of
+        # hanging forever — it never carries on with the CPU backend's
+        # devices (no `fallback=` is passed). An active verb DEADLINE
         # arms the watchdog too (min of the two budgets, applied inside
         # device_grant): a deadlined verb can never wedge at grant even
         # with the config watchdog off.

@@ -273,6 +273,13 @@ def serve(
     same port keeps serving /metrics, /healthz, /diagnostics, /trace —
     the serving data plane and its autoscaling signals are one
     surface."""
+    # The wire format is Arrow IPC: import pyarrow HERE, on the thread
+    # that mounts the front-end, not lazily inside the handler threads.
+    # A process whose first pyarrow import happens in one handler thread
+    # while others already decode requests segfaults inside pyarrow
+    # (seen with 8 concurrent clients against a fresh process).
+    import pyarrow  # noqa: F401
+
     from ..utils import telemetry_http as _http
 
     srv = _http.active_server()

@@ -211,7 +211,7 @@ class TestBenchmarkSmoke:
     """The benchmark suite (SURVEY §6: the reference's `ignore`d perf
     harnesses, live here) must run end to end and emit parseable JSON."""
 
-    def test_run_all_smoke(self):
+    def test_run_all_smoke(self, tmp_path):
         import json
         import subprocess
         import sys
@@ -219,6 +219,9 @@ class TestBenchmarkSmoke:
         env = dict(os.environ)
         env.update(
             JAX_PLATFORMS="cpu",
+            # run_all turns the persistent compile cache on: keep this
+            # run's entries out of the checkout
+            JAX_COMPILATION_CACHE_DIR=str(tmp_path / "jax_cache"),
             BENCH_SMOKE="1",
             CONVERT_CELLS="20000",
             MAPSUM_ROWS="20000",
@@ -261,6 +264,69 @@ class TestBenchmarkSmoke:
                 assert m["value"] == 0.0, m
             else:
                 assert m["value"] > 0, m
+
+
+class TestChipEntryPoints:
+    """`chip_smoke.py` and `bench.py` are what runs on the TPU. Here, on
+    the CPU, the smoke is rehearsed at tiny sizes and both are shown to
+    refuse a machine with no TPU instead of measuring its CPU."""
+
+    ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+    def _run(self, script, *args, **env):
+        import subprocess
+        import sys
+
+        return subprocess.run(
+            [sys.executable, os.path.join(self.ROOT, script), *args],
+            capture_output=True, text=True, timeout=600, cwd=self.ROOT,
+            env={**os.environ, "JAX_PLATFORMS": "cpu", **env},
+        )
+
+    def test_chip_smoke_rehearsal(self, tmp_path):
+        import json
+
+        cache = tmp_path / "cache"
+        proc = self._run(
+            "chip_smoke.py", "--rehearse", "--out", str(tmp_path / "out"),
+            JAX_COMPILATION_CACHE_DIR=str(cache),
+        )
+        assert proc.returncode == 0, proc.stderr[-3000:]
+        lines = [json.loads(ln) for ln in proc.stdout.splitlines()]
+        phases = {ln["phase"] for ln in lines}
+        assert phases >= {
+            "start", "a_readme", "b_map_chain", "c_reduce_blocks",
+            "c_reduce_stream", "d_aggregate_16_keys", "d_aggregate_300_keys",
+            "e_map_rows_mlp", "f_inception_v3", "g_serving", "h_plan",
+            "i_flash_attention_float32", "i_flash_attention_bfloat16",
+            "i_transformer_train_step", "total",
+        }, phases
+        # a rehearsal is never reported as a chip run
+        assert not any(ln.get("ok") for ln in lines)
+        assert lines[-1]["rehearsal"] == "passed"
+        assert lines[-1]["device"]["platform"] == "cpu"
+        # the compile cache went where the variable said, nowhere else
+        assert lines[0]["compilation_cache_dir"] == str(cache)
+        assert any(cache.iterdir())
+
+    def test_serving_phase_alone_in_a_fresh_process(self, tmp_path):
+        # 8 concurrent clients against a process whose FIRST pyarrow use
+        # is the serving wire path: segfaulted inside pyarrow until
+        # `serving.serve()` imported it on the mounting thread
+        proc = self._run(
+            "chip_smoke.py", "--rehearse", "--phases", "g",
+            "--out", str(tmp_path / "out"),
+            JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
+        )
+        assert proc.returncode == 0, proc.stderr[-3000:]
+        assert '"phase": "g_serving"' in proc.stdout
+
+    @pytest.mark.parametrize("script", ["chip_smoke.py", "bench.py"])
+    def test_no_tpu_is_an_error_not_a_cpu_number(self, script, tmp_path):
+        proc = self._run(script, BENCH_ROWS="1000")
+        assert proc.returncode != 0
+        assert proc.stdout.strip() == "", proc.stdout
+        assert "needs a TPU" in proc.stderr
 
 
 class TestCostAnalysis:

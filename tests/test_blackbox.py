@@ -244,7 +244,7 @@ class TestLivenessAndDegradation:
 
 
 # ---------------------------------------------------------------------------
-# trigger taxonomy: every escape hatch reports through the choke point
+# trigger classification: every escape hatch reports through the choke point
 # ---------------------------------------------------------------------------
 
 

@@ -1,4 +1,4 @@
-"""Fault-tolerant dispatch runtime (ISSUE 6): taxonomy, classified
+"""Fault-tolerant dispatch runtime (ISSUE 6): classification, classified
 retries with backoff, device failover with circuit breaker, OOM block
 splitting, the deterministic fault-injection harness, the device-grant
 watchdog, and the `_prefetch_iter` failure paths.
@@ -32,7 +32,7 @@ FAST_RETRY = dict(retry_backoff_base_s=0.001, retry_backoff_max_s=0.002)
 
 
 # ---------------------------------------------------------------------------
-# taxonomy
+# classification
 # ---------------------------------------------------------------------------
 
 
@@ -43,7 +43,7 @@ class TestClassify:
             "INTERNAL: Failed to enqueue program",
             "DATA_LOSS: chip rebooted",
             "ABORTED: device lost",
-            "DEADLINE_EXCEEDED: tunnel rpc",
+            "DEADLINE_EXCEEDED: device rpc",
         ):
             assert rtf.classify(RuntimeError(msg)) == rtf.TRANSIENT, msg
 
@@ -544,6 +544,18 @@ class TestDeviceGrantWatchdog:
             grab=wedged, timeout_s=0.1, fallback=lambda: ["cpu1"]
         ) == ["cpu0"]
         hang.set()
+
+    def test_wedged_grab_without_fallback_raises(self):
+        # no explicit fallback= -> a typed error naming the budget,
+        # never the CPU backend's devices
+        hang = threading.Event()
+        try:
+            with pytest.raises(rtf.DeviceGrantTimeout, match="0.1s"):
+                rtf.device_grant(grab=lambda: hang.wait(30.0), timeout_s=0.1)
+            assert rtf.ledger_snapshot()["grant_timeouts"] == 1
+            assert rtf.classify(rtf.DeviceGrantTimeout("x")) == "deterministic"
+        finally:
+            hang.set()
 
     def test_grab_error_propagates(self):
         def broken():

@@ -396,6 +396,63 @@ class TestDistributedAggregate:
             np.testing.assert_allclose(s, vals[keys == k].sum(0))
 
 
+class TestDeviceCommittedFrameOnMesh:
+    """A verb output is COMMITTED to the device that produced it; every
+    `mesh=` verb must place such feeds on the mesh itself instead of
+    handing jit a committed single-device argument next to a shard_map
+    over all devices ("incompatible devices")."""
+
+    N = 8 * 100 + 3  # shard_map body + a 3-row tail
+
+    @pytest.fixture()
+    def committed(self):
+        rng = np.random.default_rng(11)
+        x = rng.integers(0, 50, size=self.N).astype(np.float32)
+        key = rng.integers(0, 5, size=self.N).astype(np.int32)
+        src = tfs.TensorFrame.from_dict({"x": x, "key": key})
+        df = tfs.map_blocks(lambda x, key: {"v": x * 1.0, "k": key}, src)
+        df = df.select(["v", "k"])
+        assert df["v"].values.committed
+        assert len(df["v"].values.devices()) == 1
+        return df, x, key
+
+    @pytest.mark.parametrize(
+        "verb",
+        ["map_blocks", "map_blocks_fn", "map_rows", "reduce_blocks",
+         "reduce_rows", "aggregate_sum", "aggregate_min"],
+    )
+    def test_verb(self, mesh, committed, verb):
+        df, x, key = committed
+        vin = tfs.block(df, "v", tf_name="v_input")
+        if verb == "map_blocks":
+            out = tfs.map_blocks((tfs.block(df, "v") + 3.0).named("z"), df, mesh=mesh)
+            np.testing.assert_array_equal(out["z"].values, x + 3.0)
+        elif verb == "map_blocks_fn":
+            out = tfs.map_blocks(lambda v: {"z": v + 3.0}, df, mesh=mesh)
+            np.testing.assert_array_equal(out["z"].values, x + 3.0)
+        elif verb == "map_rows":
+            out = tfs.map_rows((tfs.row(df, "v") * 2.0).named("z"), df, mesh=mesh)
+            np.testing.assert_array_equal(out["z"].values, x * 2.0)
+        elif verb == "reduce_blocks":
+            s = dsl.reduce_sum(vin, axes=[0]).named("v")
+            assert float(tfs.reduce_blocks(s, df, mesh=mesh)) == x.sum()
+        elif verb == "reduce_rows":
+            v1 = tfs.row(df, "v", tf_name="v_1")
+            v2 = tfs.row(df, "v", tf_name="v_2")
+            got = tfs.reduce_rows((v1 + v2).named("v"), df, mesh=mesh)
+            assert float(got) == x.sum()
+        else:
+            red, ref = {
+                "aggregate_sum": (dsl.reduce_sum, np.sum),
+                "aggregate_min": (dsl.reduce_min, np.min),
+            }[verb]
+            out = tfs.aggregate(
+                red(vin, axes=[0]).named("v"), tfs.group_by(df, "k"), mesh=mesh
+            )
+            for k, v in zip(out["k"].values, out["v"].values):
+                assert float(v) == ref(x[key == k])
+
+
 class TestDistributedTrimmedMap:
     def test_trimmed_per_shard_reduction(self, mesh):
         # Each shard emits one row (its block sum): 16 rows -> 8 rows.

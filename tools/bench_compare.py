@@ -4,14 +4,14 @@
 Usage::
 
     python benchmarks/run_all.py | tee /tmp/bench.jsonl
-    python tools/bench_compare.py /tmp/bench.jsonl BENCH_BASELINE.json
+    python tools/bench_compare.py /tmp/bench.jsonl BENCH_SMOKE_BASELINE.json
 
 Inputs are tolerant by design:
 
 - RESULTS: a file of mixed output where every benchmark metric is one
   JSON object per line (`benchmarks/_util.emit`'s wire format:
   ``{"metric", "value", "unit", ...}``); non-JSON lines are skipped.
-- BASELINE: ``BENCH_BASELINE.json`` — a single metric object, a JSON
+- BASELINE: ``BENCH_SMOKE_BASELINE.json`` — a single metric object, a JSON
   array of them, or JSON lines. Extra fields (history, notes) ignored.
 
 Metrics are matched by exact ``metric`` name (sizes are part of the

@@ -114,8 +114,15 @@ def main():
     assert len(dispatches) >= 1, "no per-block dispatch span"
     per_block = [e for e in dispatches if e["args"].get("block") is not None]
     assert per_block, "no block-labeled dispatch span"
+    by_id = {e["args"]["span_id"]: e for e in events}
+
+    def under_a_verb(e):
+        while e is not None and e["args"]["span_id"] not in verbs:
+            e = by_id.get(e["args"].get("parent_id"))
+        return e is not None
+
     assert all(
-        d["args"].get("parent_id") in verbs for d in per_block
+        under_a_verb(d) for d in per_block
     ), "per-block dispatch spans are not nested under a verb span"
     emit("trace export spans", len(events), "events")
     emit("trace export compile spans", len(compiles), "events")

@@ -49,6 +49,7 @@ from .runtime.deadline import deadline_entry as _deadline_entry
 from .runtime.executor import Executor, default_executor
 from .runtime.faults import maybe_check_numerics
 from .schema import Shape
+from .utils import telemetry as _tele
 
 __all__ = [
     "map_blocks",
@@ -340,7 +341,6 @@ def _dispatch_reduce_block(
     re-raise the original error exactly. Returns the partial tuple."""
     from . import shape_policy as _sp
     from .runtime import faults as _flt
-    from .utils import telemetry as _tele
 
     def run(lo_, hi_, depth):
         feeds = feeds_for(lo_, hi_)
@@ -438,7 +438,6 @@ def _combine_partials(ex, kind, graph, fetch_list, feed_names, build, partials):
     _dl.check(kind)
     cfn = ex.cached(kind, graph, fetch_list, feed_names, make)
     from .runtime import faults as _flt
-    from .utils import telemetry as _tele
 
     # rows stays unset: the combine consumes per-block PARTIALS, and a
     # partial count in the block_rows histogram would skew the per-block
@@ -591,13 +590,14 @@ def _concat_parts(parts: List, anchor=None) -> "np.ndarray":
     callers pass their schedule's anchor device)."""
     if len(parts) == 1:
         return parts[0]
-    if any(isinstance(p, jax.Array) for p in parts):
-        import jax.numpy as jnp
+    with _tele.span("frame.concat", parts=len(parts)):
+        if any(isinstance(p, jax.Array) for p in parts):
+            import jax.numpy as jnp
 
-        return jnp.concatenate(
-            [jnp.asarray(p) for p in _colocate_parts(parts, anchor)]
-        )
-    return np.concatenate(parts)
+            return jnp.concatenate(
+                [jnp.asarray(p) for p in _colocate_parts(parts, anchor)]
+            )
+        return np.concatenate(parts)
 
 
 def _stack_parts(parts: List, anchor=None) -> "np.ndarray":
@@ -892,48 +892,56 @@ def map_blocks(
         )
         if routed is not _gf.SKIP:
             return routed
-    overrides = _ph_overrides(
-        graph, frame, feed_dict, block_level=True, bindings=bindings
-    )
-    summary = analyze_graph(graph, fetch_list, placeholder_shapes=overrides)
-    _check_bindings(summary, bindings)
-    mapping = _match_columns(
-        summary, frame, feed_dict, block_level=True, bindings=bindings
-    )
-    _require_dense(frame, list(mapping.values()), "map_blocks")
-
-    feed_names = sorted(summary.inputs)
-    fn = ex.callable_for(graph, fetch_list, feed_names)
-    # Shape bucketing (`shape_policy`): pad row-local graphs' block feeds
-    # up to the bucket ladder and slice the pad rows off every output, so
-    # drifting block sizes compile O(log max-rows) jit specializations of
-    # this program instead of one per distinct size. trim/bindings/
-    # non-rowwise graphs keep the exact per-shape dispatch.
-    from . import shape_policy as _sp
-
     from . import config as _config
-
-    # the row-local walk feeds bucketing AND OOM split eligibility;
-    # with both knobs off it is dead weight on the hot path — skip it
-    rowwise = (
-        not trim
-        and not bindings
-        and (_sp.enabled(ex) or _config.get().oom_split_depth > 0)
-        and _sp.rowwise_fetches(
-            graph,
-            fetch_list,
-            {p: ph.shape.rank for p, ph in summary.inputs.items()},
-        )
-    )
-    bucketed = rowwise and _sp.enabled(ex)
-
+    from . import shape_policy as _sp
     from .runtime import faults as _flt
     from .runtime import scheduler as _rs
-    from .utils import telemetry as _tele
 
-    sched = _rs.schedule_for(frame, devices=devices, executor=ex)
-    fscope = _flt.scope("map_blocks")
-    fp = graph.fingerprint()
+    with _tele.span("map_blocks.plan", kind="stage"):
+        with _tele.span("graph.analyze"):
+            overrides = _ph_overrides(
+                graph, frame, feed_dict, block_level=True, bindings=bindings
+            )
+            summary = analyze_graph(
+                graph, fetch_list, placeholder_shapes=overrides
+            )
+            _check_bindings(summary, bindings)
+        with _tele.span("frame.match"):
+            mapping = _match_columns(
+                summary, frame, feed_dict, block_level=True,
+                bindings=bindings,
+            )
+            _require_dense(frame, list(mapping.values()), "map_blocks")
+
+        feed_names = sorted(summary.inputs)
+        with _tele.span("executor.lookup"):
+            fn = ex.callable_for(graph, fetch_list, feed_names)
+        # Shape bucketing (`shape_policy`): pad row-local graphs' block
+        # feeds up to the bucket ladder and slice the pad rows off every
+        # output, so drifting block sizes compile O(log max-rows) jit
+        # specializations of this program instead of one per distinct
+        # size. trim/bindings/non-rowwise graphs keep the exact per-shape
+        # dispatch.
+        #
+        # the row-local walk feeds bucketing AND OOM split eligibility;
+        # with both knobs off it is dead weight on the hot path — skip it
+        with _tele.span("shape.classify"):
+            rowwise = (
+                not trim
+                and not bindings
+                and (_sp.enabled(ex) or _config.get().oom_split_depth > 0)
+                and _sp.rowwise_fetches(
+                    graph,
+                    fetch_list,
+                    {p: ph.shape.rank for p, ph in summary.inputs.items()},
+                )
+            )
+        bucketed = rowwise and _sp.enabled(ex)
+
+        with _tele.span("scheduler.plan"):
+            sched = _rs.schedule_for(frame, devices=devices, executor=ex)
+        fscope = _flt.scope("map_blocks")
+        fp = graph.fingerprint()
 
     def _dispatch_rows(bi: int, lo_: int, hi_: int, depth: int) -> List:
         """Dispatch rows ``[lo_, hi_)`` of block ``bi`` with classified
@@ -943,16 +951,19 @@ def map_blocks(
         and concatenates the halves — valid exactly for row-local
         graphs, bounded by ``config.oom_split_depth``; unclassifiable
         graphs re-raise the original error."""
-        feeds = [
-            bindings[n]
-            if n in bindings
-            else (
-                frame.column(mapping[n]).values
-                if (lo_ == 0 and hi_ == frame.nrows)
-                else frame.column(mapping[n]).values[lo_:hi_]
-            )
-            for n in feed_names
-        ]
+        if lo_ == 0 and hi_ == frame.nrows:  # the whole frame: no cut
+            feeds = [
+                bindings[n] if n in bindings
+                else frame.column(mapping[n]).values
+                for n in feed_names
+            ]
+        else:
+            with _tele.span("frame.cut", block=bi, rows=hi_ - lo_):
+                feeds = [
+                    bindings[n] if n in bindings
+                    else frame.column(mapping[n]).values[lo_:hi_]
+                    for n in feed_names
+                ]
         bucket = hi_ - lo_
         if bucketed:
             feeds, bucket = _sp.pad_feeds(feeds, hi_ - lo_)
@@ -1007,37 +1018,38 @@ def map_blocks(
 
     acc: Dict[str, List[np.ndarray]] = {_base(f): [] for f in fetch_list}
     out_sizes: List[int] = []
-    for bi in range(frame.num_blocks):
-        lo, hi = frame.offsets[bi], frame.offsets[bi + 1]
-        if lo == hi:
-            out_sizes.append(0)
-            continue  # empty block: contributes nothing (the reference's
-            # empty-partition TODO, `DebugRowOps.scala:386-387`)
-        outs = _dispatch_rows(bi, lo, hi, 0)
-        maybe_check_numerics(fetch_list, outs, f"map_blocks block {bi}")
-        bsize = None
-        for f, o in zip(fetch_list, outs):
-            # keep device arrays on device; shape checks are metadata-only
-            if not trim and (o.ndim == 0 or o.shape[0] != hi - lo):
-                raise ValueError(
-                    f"map_blocks: output {f!r} has lead dim "
-                    f"{o.shape[0] if o.ndim else '<scalar>'} but the block "
-                    f"has {hi - lo} rows; use trim=True for row-count-"
-                    "changing maps"
-                )
-            if trim:
-                if o.ndim == 0:
+    with _tele.span("map_blocks.blocks", kind="stage"):
+        for bi in range(frame.num_blocks):
+            lo, hi = frame.offsets[bi], frame.offsets[bi + 1]
+            if lo == hi:
+                out_sizes.append(0)
+                continue  # empty block: contributes nothing (the reference's
+                # empty-partition TODO, `DebugRowOps.scala:386-387`)
+            outs = _dispatch_rows(bi, lo, hi, 0)
+            maybe_check_numerics(fetch_list, outs, f"map_blocks block {bi}")
+            bsize = None
+            for f, o in zip(fetch_list, outs):
+                # keep device arrays on device; shape checks are metadata-only
+                if not trim and (o.ndim == 0 or o.shape[0] != hi - lo):
                     raise ValueError(
-                        f"map_blocks(trim): output {f!r} must have a lead dim"
+                        f"map_blocks: output {f!r} has lead dim "
+                        f"{o.shape[0] if o.ndim else '<scalar>'} but the block "
+                        f"has {hi - lo} rows; use trim=True for row-count-"
+                        "changing maps"
                     )
-                if bsize is None:
-                    bsize = o.shape[0]
-                elif o.shape[0] != bsize:
-                    raise ValueError(
-                        "map_blocks(trim): outputs disagree on row count"
-                    )
-            acc[_base(f)].append(o)
-        out_sizes.append(bsize if trim else hi - lo)
+                if trim:
+                    if o.ndim == 0:
+                        raise ValueError(
+                            f"map_blocks(trim): output {f!r} must have a lead dim"
+                        )
+                    if bsize is None:
+                        bsize = o.shape[0]
+                    elif o.shape[0] != bsize:
+                        raise ValueError(
+                            "map_blocks(trim): outputs disagree on row count"
+                        )
+                acc[_base(f)].append(o)
+            out_sizes.append(bsize if trim else hi - lo)
 
     anchor = sched.anchor_device() if sched is not None else None
     out_cols = []
@@ -1150,90 +1162,99 @@ def map_rows(
             graph, frame, mesh, feed_dict, fetch_list, executor,
             bindings=bindings,
         )
-    overrides = _ph_overrides(
-        graph, frame, feed_dict, block_level=False, bindings=bindings
-    )
-    summary = analyze_graph(graph, fetch_list, placeholder_shapes=overrides)
-    _check_bindings(summary, bindings)
-    mapping = _match_columns(
-        summary, frame, feed_dict, block_level=False, bindings=bindings
-    )
-    params = sorted(summary.inputs)
-    col_params = [p for p in params if p not in bindings]
-    cols_used = [mapping[p] for p in col_params]
-    out_names = [_base(f) for f in fetch_list]
-    dense = all(frame.column(c).is_dense for c in cols_used)
-    if bindings and not dense:
-        raise ValueError(
-            "map_rows: bindings are not supported with ragged feed "
-            "columns; densify the columns or bake the values as constants"
-        )
-    if bindings and not col_params:
-        raise ValueError(
-            "map_rows: every placeholder is bound, so nothing varies per "
-            "row; use map_blocks (or run the graph once and broadcast)"
-        )
+    with _tele.span("map_rows.plan", kind="stage"):
+        with _tele.span("graph.analyze"):
+            overrides = _ph_overrides(
+                graph, frame, feed_dict, block_level=False, bindings=bindings
+            )
+            summary = analyze_graph(
+                graph, fetch_list, placeholder_shapes=overrides
+            )
+            _check_bindings(summary, bindings)
+        with _tele.span("frame.match"):
+            mapping = _match_columns(
+                summary, frame, feed_dict, block_level=False, bindings=bindings
+            )
+        params = sorted(summary.inputs)
+        col_params = [p for p in params if p not in bindings]
+        cols_used = [mapping[p] for p in col_params]
+        out_names = [_base(f) for f in fetch_list]
+        dense = all(frame.column(c).is_dense for c in cols_used)
+        if bindings and not dense:
+            raise ValueError(
+                "map_rows: bindings are not supported with ragged feed "
+                "columns; densify the columns or bake the values as constants"
+            )
+        if bindings and not col_params:
+            raise ValueError(
+                "map_rows: every placeholder is bound, so nothing varies per "
+                "row; use map_blocks (or run the graph once and broadcast)"
+            )
 
-    if dense and not bindings:
-        # block_scheduler="global": one vmapped SPMD dispatch instead
-        # of one per block
-        routed = _gf.maybe_map_rows(
-            graph, fetch_list, frame, feed_dict, ex, devices,
-            pre=(summary, mapping),
-        )
-        if routed is not _gf.SKIP:
-            return routed
-    if dense:
-        in_axes = tuple(None if p in bindings else 0 for p in params)
-        bind_sig = ",".join(sorted(bindings))
-        vfn = ex.cached(
-            f"vmap-rows-[{bind_sig}]" if bindings else "vmap-rows",
-            graph,
-            fetch_list,
-            params,
-            lambda: jax.jit(
-                jax.vmap(
-                    build_callable(graph, fetch_list, params),
-                    in_axes=in_axes,
+        if dense and not bindings:
+            # block_scheduler="global": one vmapped SPMD dispatch instead
+            # of one per block (it has no block loop, so it runs, and
+            # returns, inside this span)
+            routed = _gf.maybe_map_rows(
+                graph, fetch_list, frame, feed_dict, ex, devices,
+                pre=(summary, mapping),
+            )
+            if routed is not _gf.SKIP:
+                return routed
+        if dense:
+            in_axes = tuple(None if p in bindings else 0 for p in params)
+            bind_sig = ",".join(sorted(bindings))
+            with _tele.span("executor.lookup"):
+                vfn = ex.cached(
+                    f"vmap-rows-[{bind_sig}]" if bindings else "vmap-rows",
+                    graph,
+                    fetch_list,
+                    params,
+                    lambda: jax.jit(
+                        jax.vmap(
+                            build_callable(graph, fetch_list, params),
+                            in_axes=in_axes,
+                        )
+                    ),
                 )
-            ),
-        )
-        # per-block dispatches spread across local devices like
-        # map_blocks; outputs stay device-resident per block and
-        # `_concat_parts` below concatenates ON DEVICE (colocating
-        # cross-device parts), so a chained verb never pays a hidden
-        # per-block D2H sync
-        from . import shape_policy as _sp
-        from .graph import vectorize as _vec
-        from .runtime import faults as _flt
-        from .runtime import scheduler as _rs
-        from .utils import telemetry as _tele
+            # per-block dispatches spread across local devices like
+            # map_blocks; outputs stay device-resident per block and
+            # `_concat_parts` below concatenates ON DEVICE (colocating
+            # cross-device parts), so a chained verb never pays a hidden
+            # per-block D2H sync
+            from . import shape_policy as _sp
+            from .graph import vectorize as _vec
+            from .runtime import faults as _flt
+            from .runtime import scheduler as _rs
 
-        # Bucketed vmapped dispatch (`graph/vectorize.py` companion):
-        # the vmapped per-row program is row-independent by
-        # construction, so padding a block up the bucket ladder and
-        # slicing the pad rows off is always sound — drifting block
-        # sizes (and the branchy per-row graphs the vectorizer just
-        # unlocked) compile O(log max-rows) specializations instead of
-        # one per distinct size. Bindings keep the exact per-shape
-        # dispatch (bound feeds must stay whole).
-        bucketed = not bindings and _sp.enabled(ex) and _vec.enabled()
+            # Bucketed vmapped dispatch (`graph/vectorize.py` companion):
+            # the vmapped per-row program is row-independent by
+            # construction, so padding a block up the bucket ladder and
+            # slicing the pad rows off is always sound — drifting block
+            # sizes (and the branchy per-row graphs the vectorizer just
+            # unlocked) compile O(log max-rows) specializations instead of
+            # one per distinct size. Bindings keep the exact per-shape
+            # dispatch (bound feeds must stay whole).
+            bucketed = not bindings and _sp.enabled(ex) and _vec.enabled()
 
-        sched = _rs.schedule_for(frame, devices=devices, executor=ex)
-        fscope = _flt.scope("map_rows")
-        fp = graph.fingerprint()
+            with _tele.span("scheduler.plan"):
+                sched = _rs.schedule_for(frame, devices=devices, executor=ex)
+            fscope = _flt.scope("map_rows")
+            fp = graph.fingerprint()
 
+    if dense:
         def _dispatch_rows(bi: int, lo_: int, hi_: int, depth: int):
             # classified faults: transient retries (+ failover under the
             # scheduler); OOM splits the row range in half — always
             # valid here, the vmapped per-row program is row-independent
             # by construction (bound placeholders stay whole)
-            feeds = [
-                bindings[p]
-                if p in bindings
-                else frame.column(mapping[p]).values[lo_:hi_]
-                for p in params
-            ]
+            with _tele.span("frame.cut", block=bi, rows=hi_ - lo_):
+                feeds = [
+                    bindings[p]
+                    if p in bindings
+                    else frame.column(mapping[p]).values[lo_:hi_]
+                    for p in params
+                ]
             bucket = hi_ - lo_
             if bucketed:
                 feeds, bucket = _sp.pad_feeds(feeds, hi_ - lo_)
@@ -1281,14 +1302,15 @@ def map_rows(
             )
 
         acc: Dict[str, List[np.ndarray]] = {n: [] for n in out_names}
-        for bi in range(frame.num_blocks):
-            lo, hi = frame.offsets[bi], frame.offsets[bi + 1]
-            if lo == hi:
-                continue
-            outs = _dispatch_rows(bi, lo, hi, 0)
-            maybe_check_numerics(out_names, outs, f"map_rows block {bi}")
-            for n, o in zip(out_names, outs):
-                acc[n].append(o)
+        with _tele.span("map_rows.blocks", kind="stage"):
+            for bi in range(frame.num_blocks):
+                lo, hi = frame.offsets[bi], frame.offsets[bi + 1]
+                if lo == hi:
+                    continue
+                outs = _dispatch_rows(bi, lo, hi, 0)
+                maybe_check_numerics(out_names, outs, f"map_rows block {bi}")
+                for n, o in zip(out_names, outs):
+                    acc[n].append(o)
         anchor = sched.anchor_device() if sched is not None else None
         out_cols = [
             Column(
@@ -1690,7 +1712,6 @@ def reduce_rows(
     # contract is a left fold in row order, which non-associative
     # graphs rely on — regrouping by device would break it.
     from .runtime import scheduler as _rs
-    from .utils import telemetry as _tele
 
     # single-row blocks never dispatch (their partial is a bare column
     # slice), so they carry zero planning weight — otherwise their slot's
@@ -1967,7 +1988,6 @@ def aggregate(
     _count(
         "aggregate.plan.exact" if combiners is None else "aggregate.plan.chunk"
     )
-    from .utils import telemetry as _tele
 
     fp = graph.fingerprint()
     if combiners is None:

@@ -52,6 +52,8 @@ import numpy as np
 from .aggregate import _chunk_combiners, _rowwise_transform
 from .graph.ir import Graph, base_name as _base
 from .ops.lowering import build_callable
+from .utils import telemetry as _tele
+from .utils.profiling import count as _count
 
 __all__ = [
     "bucket_for",
@@ -160,8 +162,6 @@ def observe_fill(n: int, bucket: int, verb: Optional[str] = None) -> None:
     bucket-economics signal the workload profile and the future ladder
     autotuner consume. Gated on the telemetry master switch like every
     histogram; the verb label rides the ambient verb span."""
-    from .utils import telemetry as _tele
-
     if bucket <= 0 or not _tele.enabled():
         return
     if verb is None:
@@ -179,14 +179,13 @@ def pad_feeds(feeds: Sequence, n: int) -> Tuple[List, int]:
     observe_fill(n, b)
     if b == n:
         return list(feeds), n
-    from .utils.profiling import count as _count
-
     _count("shape_bucketing.padded_dispatch")
     # pad waste observability: total synthetic rows dispatched (the
     # price paid for the bounded compile count — `diagnostics` readers
     # compare this against real row counters)
     _count("shape_bucketing.pad_rows", b - n)
-    return [pad_lead(f, n, b) for f in feeds], b
+    with _tele.span("shape.pad", rows=n, bucket=b):
+        return [pad_lead(f, n, b) for f in feeds], b
 
 
 def mesh_shard_plan(nrows: int, ndev: int):
@@ -224,10 +223,11 @@ def slice_pad_rows(outs: Sequence, n: int, bucket: int) -> List:
     it instead of a slice masking the contract violation."""
     if bucket == n:
         return list(outs)
-    return [
-        o[:n] if getattr(o, "ndim", 0) and o.shape[0] == bucket else o
-        for o in outs
-    ]
+    with _tele.span("shape.unpad", rows=n, bucket=bucket):
+        return [
+            o[:n] if getattr(o, "ndim", 0) and o.shape[0] == bucket else o
+            for o in outs
+        ]
 
 
 # ---------------------------------------------------------------------------

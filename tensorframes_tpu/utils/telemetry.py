@@ -47,6 +47,11 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Callable, Dict, List, Optional, Tuple
 
+# resolved once: `enabled()` runs at every span site, and a function-local
+# import there cost a fifth of the site (config.py imports nothing of the
+# package, so there is no cycle to dodge)
+from .. import config as _config
+
 __all__ = [
     "Span",
     "enabled",
@@ -80,8 +85,6 @@ __all__ = [
 
 def enabled() -> bool:
     """Telemetry master switch (``config.telemetry`` / ``TFS_TELEMETRY``)."""
-    from .. import config as _config
-
     return _config.get().telemetry
 
 
@@ -144,8 +147,6 @@ class _SpanRing:
 
 
 def _ring_size() -> int:
-    from .. import config as _config
-
     return int(getattr(_config.get(), "telemetry_ring_entries", 8192))
 
 
@@ -499,8 +500,6 @@ def _buckets_for(name: str) -> Tuple[float, ...]:
     value must never turn an observation into an exception."""
     fam = _HISTOGRAM_FAMILIES.get(name, "seconds")
     try:
-        from .. import config as _config
-
         over = getattr(_config.get(), "histogram_buckets", None)
         if over:
             raw = over.get(name, over.get(fam))
@@ -762,19 +761,21 @@ def _union_seconds(intervals: List[Tuple[float, float]]) -> float:
 
 def span_aggregates(span_list: Optional[List[Span]] = None) -> Dict:
     """Structured aggregates over the span ring: wall-clock coverage by
-    root spans, totals by verb / by kind, and the per-program
-    compile-vs-execute-vs-host-sync attribution table."""
+    root spans, totals by verb / by kind / by name (with self time), and
+    the per-program compile-vs-execute-vs-host-sync attribution table.
+    A span whose parent has left the list counts as a root."""
     ss = spans() if span_list is None else span_list
     if not ss:
         return {
             "window": 0.0, "covered": 0.0, "coverage": 0.0, "roots": 0,
             "spans": 0, "dropped": spans_dropped(),
-            "by_verb": {}, "by_kind": {}, "by_program": {},
+            "by_verb": {}, "by_kind": {}, "by_name": {}, "by_program": {},
             "by_device": {},
         }
     window0 = min(s.t0 for s in ss)
     window1 = max(s.t1 for s in ss)
-    roots = [s for s in ss if s.parent_id is None]
+    by_id = {s.span_id: s for s in ss}
+    roots = [s for s in ss if s.parent_id not in by_id]
     covered = _union_seconds([(s.t0, s.t1) for s in roots])
     window = max(window1 - window0, 1e-12)
     by_verb: Dict[str, Dict[str, float]] = {}
@@ -782,7 +783,26 @@ def span_aggregates(span_list: Optional[List[Span]] = None) -> Dict:
     by_program: Dict[str, Dict[str, float]] = {}
     dev_intervals: Dict[str, List[Tuple[float, float]]] = {}
     dev_counts: Dict[str, int] = {}
+    # self time: a span's duration less the union of its direct
+    # children's intervals (clipped to it: a cross-thread child may
+    # outlast the region that owns it)
+    child_intervals: Dict[int, List[Tuple[float, float]]] = {}
     for s in ss:
+        p = by_id.get(s.parent_id)
+        if p is not None:
+            a, b = max(s.t0, p.t0), min(s.t1, p.t1)
+            if b > a:
+                child_intervals.setdefault(p.span_id, []).append((a, b))
+    by_name: Dict[str, Dict[str, float]] = {}
+    for s in ss:
+        n = by_name.setdefault(
+            s.name, {"count": 0, "seconds": 0.0, "self_seconds": 0.0}
+        )
+        n["count"] += 1
+        n["seconds"] += s.seconds
+        n["self_seconds"] += s.seconds - _union_seconds(
+            child_intervals.get(s.span_id, [])
+        )
         k = by_kind.setdefault(s.kind, {"seconds": 0.0, "count": 0})
         k["seconds"] += s.seconds
         k["count"] += 1
@@ -838,6 +858,7 @@ def span_aggregates(span_list: Optional[List[Span]] = None) -> Dict:
         "dropped": spans_dropped(),
         "by_verb": by_verb,
         "by_kind": by_kind,
+        "by_name": by_name,
         "by_program": by_program,
         "by_device": by_device,
     }
@@ -1097,39 +1118,6 @@ def _fmt_rate(v, unit: str) -> str:
     return f"{v:.2f} {unit}"
 
 
-def _verb_roofline(span_list: List[Span], costs: Dict) -> Dict[str, Dict]:
-    """Per-verb modeled flops/bytes: each dispatch span's program cost
-    (average per exec) attributed to the span's root ``verb`` ancestor.
-    Average-per-exec is exact when a program converged onto one bucket
-    rung; a multi-shape program's split is approximate and documented
-    so."""
-    by_id = {s.span_id: s for s in span_list}
-    out: Dict[str, Dict] = {}
-    for s in span_list:
-        if s.kind != "dispatch":
-            continue
-        prog = s.attrs.get("program")
-        c = costs.get(str(prog)) if prog else None
-        if not c or not c["execs"]:
-            continue
-        node, hops = s, 0
-        verb = None
-        while node is not None and hops < 64:
-            if node.kind == "verb":
-                verb = node.name
-                break
-            node = by_id.get(node.parent_id)
-            hops += 1
-        if verb is None:
-            continue
-        v = out.setdefault(verb, {"flops": 0.0, "bytes": 0.0})
-        if c["total_flops"] is not None:
-            v["flops"] += c["total_flops"] / c["execs"]
-        if c["total_bytes_accessed"] is not None:
-            v["bytes"] += c["total_bytes_accessed"] / c["execs"]
-    return out
-
-
 def diagnostics_data(executor=None) -> Dict:
     """The machine-readable diagnostics payload (what
     ``tfs.diagnostics(format="json")`` and the /diagnostics endpoint
@@ -1150,7 +1138,7 @@ def diagnostics_data(executor=None) -> Dict:
                       "dropped")
         },
         "verbs": agg["by_verb"],
-        "phases": agg["by_kind"],
+        "phases": agg["by_name"],
         "devices": agg["by_device"],
         "programs": agg["by_program"],
     }
@@ -1159,13 +1147,11 @@ def diagnostics_data(executor=None) -> Dict:
     try:
         from ..runtime import costmodel as _cm
 
-        costs = _cm.program_costs()
         data["cost"] = {
             "enabled": _cm.enabled(),
             "peaks": _cm.device_peaks(),
             "programs": _cm.roofline(agg["by_program"]),
             "verb_peaks": _cm.verb_peaks(),
-            "verb_roofline": _verb_roofline(ss, costs),
         }
     except Exception as e:
         data["cost"] = {"error": f"{type(e).__name__}: {e}"}
@@ -1294,7 +1280,6 @@ def diagnostics_data(executor=None) -> Dict:
             }
         data["executor"] = es
         from ..runtime.executor import default_executor
-        from .. import config as _config
 
         ex = executor if executor is not None else default_executor()
         per_prog = getattr(ex, "program_shape_compiles", None)
@@ -1336,7 +1321,6 @@ def _render_diagnostics(data: Dict) -> str:
     )
 
     cost = data.get("cost", {})
-    verb_roof = cost.get("verb_roofline", {})
     if data["verbs"]:
         lines.append("")
         lines.append("verbs:")
@@ -1344,26 +1328,21 @@ def _render_diagnostics(data: Dict) -> str:
             data["verbs"].items(), key=lambda kv: -kv[1]["seconds"]
         ):
             rows = f"  rows={int(v['rows'])}" if v["rows"] else ""
-            extra = ""
-            vr = verb_roof.get(name)
-            if vr and v["seconds"] > 0 and (vr["flops"] or vr["bytes"]):
-                extra = (
-                    f"  ~{_fmt_rate(vr['flops'] / v['seconds'], 'FLOP/s')}"
-                    f" ~{_fmt_rate(vr['bytes'] / v['seconds'], 'B/s')}"
-                )
             lines.append(
                 f"  {name:<28} calls={v['calls']:<4} "
-                f"total={v['seconds']:.4f}s{rows}{extra}"
+                f"total={v['seconds']:.4f}s{rows}"
             )
     if data["phases"]:
         lines.append("")
-        lines.append("time by phase (span totals; dispatch is async issue"
-                     " time, not device occupancy):")
-        for kind, k in sorted(
-            data["phases"].items(), key=lambda kv: -kv[1]["seconds"]
+        lines.append("time by phase (span name: count, total, self = total"
+                     " less its child spans; dispatch is async issue time,"
+                     " not device occupancy):")
+        for name, k in sorted(
+            data["phases"].items(), key=lambda kv: -kv[1]["self_seconds"]
         ):
             lines.append(
-                f"  {kind:<10} {k['seconds']:.4f}s ({k['count']} span(s))"
+                f"  {name:<28} n={k['count']:<6} "
+                f"total={k['seconds']:.6f}s self={k['self_seconds']:.6f}s"
             )
     if data.get("devices"):
         lines.append("")
@@ -1781,8 +1760,6 @@ def maybe_serve():
     """Import-time auto-start: serve IFF ``config.telemetry_port`` is
     non-zero (i.e. the operator set TFS_TELEMETRY_PORT). Never raises —
     a busy port logs a warning instead of breaking the import."""
-    from .. import config as _config
-
     if not getattr(_config.get(), "telemetry_port", 0):
         return None
     try:

@@ -94,8 +94,12 @@ class TestSpans:
         dispatches = [s for s in ss if s.kind == "dispatch"]
         assert len(verbs) == 1 and verbs[0].name == "map_blocks"
         assert len(dispatches) == 4  # one per block
+        by_id = {s.span_id: s for s in ss}
         for d in dispatches:
-            assert d.parent_id == verbs[0].span_id
+            # verb -> map_blocks.blocks (the block loop) -> dispatch
+            loop = by_id[d.parent_id]
+            assert loop.name == "map_blocks.blocks"
+            assert loop.parent_id == verbs[0].span_id
             assert d.attrs["program"]  # graph fingerprint label
 
     def test_lazy_force_and_stream_chunks_attribute_to_spans(self):
@@ -242,8 +246,10 @@ class TestExporters:
         verb = [e for e in events if e["cat"] == "verb"][0]
         dispatches = [e for e in events if e["cat"] == "dispatch"]
         assert dispatches
+        by_id = {e["args"]["span_id"]: e for e in events}
         for d in dispatches:
-            assert d["args"]["parent_id"] == verb["args"]["span_id"]
+            loop = by_id[d["args"]["parent_id"]]  # the block loop's span
+            assert loop["args"]["parent_id"] == verb["args"]["span_id"]
             # timestamp containment = what the trace viewer nests by
             assert verb["ts"] <= d["ts"]
             assert verb["ts"] + verb["dur"] >= d["ts"] + d["dur"]
@@ -585,3 +591,167 @@ class TestCrossThreadSpanAttribution:
         # is itself in the trace — no orphan parent ids)
         for e in stages:
             assert e["args"].get("parent_id") in ids
+
+
+# ---------------------------------------------------------------------------
+# spans below the verb (ISSUE 26): plan / cut / pad / dispatch / unpad /
+# concat, self time by name
+# ---------------------------------------------------------------------------
+
+# children of `<verb>.plan`; map_rows' dense route classifies nothing
+_PLAN = ["graph.analyze", "frame.match", "executor.lookup", "scheduler.plan"]
+
+# (verb, rows, blocks) -> the spans of ONE warm call: {name: (count, parent)}
+_CALLS = {
+    "map_blocks-1block-on-rung": ("map_blocks", 64, 1, {}),
+    "map_blocks-1block-off-rung": ("map_blocks", 40, 1, {
+        "shape.pad": (1, "map_blocks.blocks"),
+        "shape.unpad": (1, "map_blocks.blocks"),
+    }),
+    "map_blocks-4blocks-off-rung": ("map_blocks", 40, 4, {
+        "frame.cut": (4, "map_blocks.blocks"),
+        "shape.pad": (4, "map_blocks.blocks"),
+        "shape.unpad": (4, "map_blocks.blocks"),
+        "frame.concat": (1, "map_blocks"),
+    }),
+    "map_rows-dense": ("map_rows", 40, 1, {
+        "frame.cut": (1, "map_rows.blocks"),
+        "shape.pad": (1, "map_rows.blocks"),
+        "shape.unpad": (1, "map_rows.blocks"),
+    }),
+}
+
+
+def _warm_call(case):
+    """The case's verb call, twice: the first compiles, the ring is
+    cleared, and the second is the one the test reads."""
+    verb, rows, blocks, extra = _CALLS[case]
+    df = tfs.TensorFrame.from_dict(
+        {"x": np.arange(rows, dtype=np.float32)}, num_blocks=blocks
+    )
+    ph = (tfs.block if verb == "map_blocks" else tfs.row)(df, "x")
+    fetch = (ph * 2.0).named("z")
+    run = getattr(tfs, verb)
+    run(fetch, df)
+    tele.reset()
+    out = run(fetch, df)
+    np.testing.assert_array_equal(
+        np.asarray(out["z"].values), 2.0 * np.arange(rows, dtype=np.float32)
+    )
+    return verb, blocks, extra
+
+
+class TestSpansBelowTheVerb:
+    @pytest.mark.parametrize("case", sorted(_CALLS))
+    def test_span_tree_of_one_call(self, case):
+        verb, blocks, extra = _warm_call(case)
+        ss = tele.spans()
+        by_id = {s.span_id: s for s in ss}
+        want = {
+            verb: (1, None),
+            f"{verb}.plan": (1, verb),
+            f"{verb}.blocks": (1, verb),
+            f"{verb}.block": (blocks, f"{verb}.blocks"),
+            **{n: (1, f"{verb}.plan") for n in _PLAN},
+            **extra,
+        }
+        if verb == "map_blocks":
+            want["shape.classify"] = (1, "map_blocks.plan")
+        names = [s.name for s in ss]
+        assert set(names) == set(want)  # exactly these, no others
+        (root,) = [s for s in ss if s.kind == "verb"]
+        for s in ss:
+            count, parent = want[s.name]
+            assert names.count(s.name) == count, s.name
+            if parent is None:
+                assert s.parent_id is None
+                continue
+            p = by_id[s.parent_id]
+            assert p.name == parent, (s.name, p.name)
+            assert p.t0 <= s.t0 and s.t1 <= p.t1  # inside its parent
+            # every span of a call reaches the call's verb span: the
+            # identifier the spans of one call share
+            node = s
+            while node.parent_id is not None:
+                node = by_id[node.parent_id]
+            assert node is root
+        kinds = {s.name: s.kind for s in ss}
+        assert kinds[f"{verb}.plan"] == kinds[f"{verb}.blocks"] == "stage"
+        assert kinds[f"{verb}.block"] == "dispatch"
+        for s in ss:
+            if s.name in ("shape.pad", "shape.unpad"):
+                assert s.attrs["bucket"] > s.attrs["rows"]
+
+    @pytest.mark.parametrize("case", sorted(_CALLS))
+    def test_disabled_leaves_the_ring_empty(self, case):
+        with config.override(telemetry=False):
+            _warm_call(case)
+            assert tele.spans() == []
+
+    @pytest.mark.parametrize("what", ["overlapping-children", "orphan"])
+    def test_by_name_self_time(self, what):
+        ms = 1e-3
+
+        def sp(i, parent, name, t0, t1, kind="span"):
+            return tele.Span(i, parent, name, kind, t0 * ms, t1 * ms, 0)
+
+        if what == "overlapping-children":
+            # a 10 ms parent; children [1, 5] and [3, 8] overlap and
+            # cover 7 ms of it: 3 ms are its own
+            ss = [sp(2, 1, "child", 1, 5), sp(3, 1, "child", 3, 8),
+                  sp(1, None, "parent", 0, 10, "verb")]
+            agg = tele.span_aggregates(ss)
+            assert agg["roots"] == 1
+            parent, child = agg["by_name"]["parent"], agg["by_name"]["child"]
+            assert parent["count"] == 1 and child["count"] == 2
+            assert parent["seconds"] == pytest.approx(10 * ms)
+            assert parent["self_seconds"] == pytest.approx(3 * ms)
+            assert child["seconds"] == pytest.approx(9 * ms)
+            assert child["self_seconds"] == pytest.approx(9 * ms)
+        else:
+            # span 7's parent (id 5) has left the ring: 7 is a root, and
+            # its own child still comes off its self time
+            ss = [sp(8, 7, "leaf", 2, 3), sp(7, 5, "orphan", 0, 4),
+                  sp(9, None, "root", 5, 6)]
+            agg = tele.span_aggregates(ss)
+            assert agg["roots"] == 2
+            assert agg["covered"] == pytest.approx(5 * ms)
+            assert agg["by_name"]["orphan"]["self_seconds"] == pytest.approx(3 * ms)
+            assert agg["by_name"]["leaf"]["self_seconds"] == pytest.approx(1 * ms)
+
+    def test_diagnostics_prints_the_by_name_table(self):
+        _warm_call("map_blocks-4blocks-off-rung")
+        data = tfs.diagnostics(format="json")
+        assert data["phases"]["shape.pad"]["count"] == 4
+        assert "verb_roofline" not in data["cost"]
+        text = tfs.diagnostics()
+        (line,) = [l for l in text.splitlines()
+                   if l.strip().startswith("map_blocks.blocks")]
+        assert "n=1" in line and "total=" in line and "self=" in line
+
+
+@pytest.mark.parametrize("program", ["callable_for", "vmap-rows"])
+def test_verb_programs_lower_to_a_module_named_jit_fn(program):
+    """The benchmark finds the verb's own program in the device trace by
+    its XLA module name (`program_modules` = `^jit_fn$` in both files
+    under perf/configs/). A rename of `ops.lowering.build_callable`'s
+    inner function goes together with a `benchmark` PR that changes
+    that pattern."""
+    import re
+
+    df = tfs.TensorFrame.from_dict({"x": np.arange(8, dtype=np.float32)})
+    ex = tfs.Executor()
+    if program == "callable_for":
+        graph, fetches = dsl.build((tfs.block(df, "x") + 3.0).named("z"))
+        fn = ex.callable_for(graph, fetches, ["x"])
+    else:
+        fetch = (tfs.row(df, "x") + 3.0).named("z")
+        tfs.map_rows(fetch, df, executor=ex)
+        graph, fetches = dsl.build(fetch)
+
+        def never():
+            raise AssertionError("map_rows did not cache its program here")
+
+        fn = ex.cached("vmap-rows", graph, fetches, ["x"], never)
+    text = fn.__wrapped__.lower(np.arange(8, dtype=np.float32)).as_text()
+    assert re.search(r"module @(\S+)", text).group(1) == "jit_fn"

@@ -937,6 +937,11 @@ def map_blocks(
                 )
             )
         bucketed = rowwise and _sp.enabled(ex)
+        # bucketed implies no bindings: every feed is a column
+        columns = (
+            [frame.column(mapping[n]).values for n in feed_names]
+            if bucketed else []
+        )
 
         with _tele.span("scheduler.plan"):
             sched = _rs.schedule_for(frame, devices=devices, executor=ex)
@@ -951,22 +956,26 @@ def map_blocks(
         and concatenates the halves — valid exactly for row-local
         graphs, bounded by ``config.oom_split_depth``; unclassifiable
         graphs re-raise the original error."""
-        if lo_ == 0 and hi_ == frame.nrows:  # the whole frame: no cut
-            feeds = [
-                bindings[n] if n in bindings
-                else frame.column(mapping[n]).values
-                for n in feed_names
-            ]
-        else:
+        def _cut() -> List:
+            if lo_ == 0 and hi_ == frame.nrows:  # the whole frame: no cut
+                return [
+                    bindings[n] if n in bindings
+                    else frame.column(mapping[n]).values
+                    for n in feed_names
+                ]
             with _tele.span("frame.cut", block=bi, rows=hi_ - lo_):
-                feeds = [
+                return [
                     bindings[n] if n in bindings
                     else frame.column(mapping[n]).values[lo_:hi_]
                     for n in feed_names
                 ]
-        bucket = hi_ - lo_
+
         if bucketed:
-            feeds, bucket = _sp.pad_feeds(feeds, hi_ - lo_)
+            # a window of the resident columns where they have one, else
+            # the cut, padded (`shape_policy.block_feeds`)
+            feeds, bucket, shift = _sp.block_feeds(columns, lo_, hi_, _cut)
+        else:
+            feeds, bucket, shift = _cut(), hi_ - lo_, None
 
         def _thunk():
             # span inside the thunk: each ATTEMPT records its own
@@ -1014,7 +1023,7 @@ def map_blocks(
             left = _dispatch_rows(bi, lo_, mid, depth + 1)
             right = _dispatch_rows(bi, mid, hi_, depth + 1)
             return [_concat_parts([a, b]) for a, b in zip(left, right)]
-        return _sp.slice_pad_rows(outs, hi_ - lo_, bucket)
+        return _sp.unpad_block(outs, hi_ - lo_, bucket, shift)
 
     acc: Dict[str, List[np.ndarray]] = {_base(f): [] for f in fetch_list}
     out_sizes: List[int] = []
@@ -1236,6 +1245,7 @@ def map_rows(
             # one per distinct size. Bindings keep the exact per-shape
             # dispatch (bound feeds must stay whole).
             bucketed = not bindings and _sp.enabled(ex) and _vec.enabled()
+            columns = [frame.column(c).values for c in cols_used]
 
             with _tele.span("scheduler.plan"):
                 sched = _rs.schedule_for(frame, devices=devices, executor=ex)
@@ -1248,16 +1258,22 @@ def map_rows(
             # scheduler); OOM splits the row range in half — always
             # valid here, the vmapped per-row program is row-independent
             # by construction (bound placeholders stay whole)
-            with _tele.span("frame.cut", block=bi, rows=hi_ - lo_):
-                feeds = [
-                    bindings[p]
-                    if p in bindings
-                    else frame.column(mapping[p]).values[lo_:hi_]
-                    for p in params
-                ]
-            bucket = hi_ - lo_
+            def _cut() -> List:
+                with _tele.span("frame.cut", block=bi, rows=hi_ - lo_):
+                    return [
+                        bindings[p]
+                        if p in bindings
+                        else frame.column(mapping[p]).values[lo_:hi_]
+                        for p in params
+                    ]
+
             if bucketed:
-                feeds, bucket = _sp.pad_feeds(feeds, hi_ - lo_)
+                # see map_blocks._dispatch_rows
+                feeds, bucket, shift = _sp.block_feeds(
+                    columns, lo_, hi_, _cut
+                )
+            else:
+                feeds, bucket, shift = _cut(), hi_ - lo_, None
 
             def _thunk():
                 # per-attempt span (see map_blocks._dispatch_rows)
@@ -1272,7 +1288,7 @@ def map_rows(
 
             try:
                 outs_ = _thunk_outs(_thunk, bi, lo_, hi_)
-                return _sp.slice_pad_rows(outs_, hi_ - lo_, bucket)
+                return _sp.unpad_block(outs_, hi_ - lo_, bucket, shift)
             except Exception as e:
                 if _flt.classify(e) != _flt.RESOURCE:
                     raise

@@ -32,6 +32,30 @@ O(log_growth max-block-rows). Replicating the last row (instead of
 zero-fill) keeps pad rows numerically ordinary, so ``check_numerics``
 and non-total ops (Log, Reciprocal, ...) never see synthetic poison.
 
+The block WINDOW (`block_feeds` / `unpad_block`, the per-block loops of
+`api.map_blocks` and `api.map_rows`' dense route): the same argument
+makes a block's neighbours in its own column as good as replicas of its
+last row — they are real rows, so numerically ordinary, and a row-local
+program never lets them touch the valid rows. Where every feed column
+is a `jax.Array` resident on one device with at least a rung's rows, a
+block off its rung is therefore not cut and padded by eager copies
+(``values[lo:hi]``, ``a[-1:]``, ``broadcast_to``, ``concatenate``: four
+Python-level jax dispatches, three of them with a host-to-device put of
+a start index) but taken as ONE rung-sized window of the columns:
+``dynamic_slice_in_dim(col, start, bucket)`` with ``start = min(lo,
+N - bucket)`` a traced scalar, one jitted call for all feed columns and
+one executable for every block of a rung (`block_window`). The valid
+rows sit at ``[shift, shift + n)`` of the window, ``shift = lo -
+start``: 0 except in blocks near the column's end. The program sees the
+same rung shape as before, and one small jitted static slice
+(`block_unpad`) takes ``[shift, shift + n)`` out of its outputs: three
+dispatches a block, none eager, where there were six. What decides is
+what the input shows, not a knob: a column shorter than the rung (every
+single-block frame off a rung), a host (numpy) column, a column sharded
+over devices or a block already on its rung keeps the cut and the
+replicated pad below, unchanged. The reduce, lazy, stream and mesh
+routes call `pad_feeds` and never take a window.
+
 Exactness: map outputs, min/max, and integer-dtype reductions are
 bit-identical to unbucketed eager execution. Float sum/mean reduce over
 a wider (padded) axis, so XLA's vectorized accumulation may group the
@@ -44,9 +68,11 @@ order matters more than bounded compiles.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from .aggregate import _chunk_combiners, _rowwise_transform
@@ -63,6 +89,8 @@ __all__ = [
     "pad_feeds",
     "pad_lead",
     "slice_pad_rows",
+    "block_feeds",
+    "unpad_block",
     "rowwise_fetches",
     "MaskPlan",
     "masked_reduce_plan",
@@ -228,6 +256,103 @@ def slice_pad_rows(outs: Sequence, n: int, bucket: int) -> List:
             o[:n] if getattr(o, "ndim", 0) and o.shape[0] == bucket else o
             for o in outs
         ]
+
+
+# ---------------------------------------------------------------------------
+# the block window: cut + pad in one program, unpad in another
+# ---------------------------------------------------------------------------
+
+# The two helpers' XLA modules take these functions' names
+# (`jit_block_window`, `jit_block_unpad`): a device trace tells them from
+# the verb's own program, `jit_fn`, by name.
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def block_window(bucket: int, start, *columns):
+    """``bucket`` rows of every column from row ``start`` on. ``start``
+    is traced, so all blocks of one rung over one frame share an
+    executable."""
+    return tuple(
+        jax.lax.dynamic_slice_in_dim(c, start, bucket) for c in columns
+    )
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def block_unpad(shift: int, n: int, *outs):
+    """Rows ``[shift, shift + n)`` of every output: a static slice, one
+    executable per (output shapes, shift, n)."""
+    return tuple(jax.lax.slice_in_dim(o, shift, shift + n) for o in outs)
+
+
+_INT32_MAX = int(np.iinfo(np.int32).max)
+
+
+def _window_rows(columns: Sequence) -> int:
+    """Rows of the feed columns if a window can be taken of them (every
+    one a `jax.Array`, all on the same single device, few enough rows
+    for an int32 start index), else 0."""
+    device = None
+    for c in columns:
+        if not isinstance(c, jax.Array):
+            return 0
+        devices = c.sharding.device_set
+        if len(devices) != 1 or (device is not None and devices != device):
+            return 0
+        device = devices
+    rows = min((c.shape[0] for c in columns), default=0)
+    return rows if rows <= _INT32_MAX else 0
+
+
+def block_feeds(
+    columns: Sequence, lo: int, hi: int, cut: Callable[[], List]
+) -> Tuple[List, int, Optional[int]]:
+    """The feeds of a bucketed map dispatch over rows ``[lo, hi)`` of
+    the feed ``columns``: ``(feeds, bucket, shift)``, every feed
+    ``bucket`` rows long.
+
+    Where the columns have a window (module docstring) and the block is
+    off its rung, the feeds are that window and the valid rows are
+    ``[shift, shift + n)`` of it. Otherwise the caller's ``cut()`` (its
+    ``values[lo:hi]`` per feed) is padded by `pad_feeds` and ``shift``
+    is None. `unpad_block` takes the same triple back."""
+    n = hi - lo
+    b = bucket_for(n)
+    if b == n or _window_rows(columns) < b:
+        feeds, b = pad_feeds(cut(), n)
+        return feeds, b, None
+    observe_fill(n, b)
+    _count("shape_bucketing.window_dispatch")
+    # rows computed beyond the real ones, as `pad_feeds` counts them
+    _count("shape_bucketing.pad_rows", b - n)
+    start = min(lo, columns[0].shape[0] - b)
+    with _tele.span("shape.pad", rows=n, bucket=b):
+        feeds = block_window(b, np.int32(start), *columns)
+    return list(feeds), b, lo - start
+
+
+def unpad_block(
+    outs: Sequence, n: int, bucket: int, shift: Optional[int]
+) -> List:
+    """The ``n`` valid rows of a `block_feeds` dispatch's outputs. A
+    replicated pad (``shift`` None) goes to `slice_pad_rows`; a window's
+    device outputs go through `block_unpad` in one call. As there, an
+    output that did not keep the padded lead dim is returned
+    untouched."""
+    if shift is None:
+        return slice_pad_rows(outs, n, bucket)
+    with _tele.span("shape.unpad", rows=n, bucket=bucket):
+        outs = list(outs)
+        padded = [
+            i for i, o in enumerate(outs)
+            if getattr(o, "ndim", 0) and o.shape[0] == bucket
+        ]
+        if all(isinstance(outs[i], jax.Array) for i in padded):
+            cut = block_unpad(shift, n, *[outs[i] for i in padded])
+        else:  # a host executor's numpy outputs: views
+            cut = [outs[i][shift:shift + n] for i in padded]
+        for i, o in zip(padded, cut):
+            outs[i] = o
+        return outs
 
 
 # ---------------------------------------------------------------------------

@@ -1181,6 +1181,9 @@ def diagnostics_data(executor=None) -> Dict:
             "padded_dispatches": int(
                 counters.get("shape_bucketing.padded_dispatch", 0)
             ),
+            "window_dispatches": int(
+                counters.get("shape_bucketing.window_dispatch", 0)
+            ),
             "pad_rows": int(counters.get("shape_bucketing.pad_rows", 0)),
             "fill": {
                 v: {
@@ -1462,8 +1465,10 @@ def _render_diagnostics(data: Dict) -> str:
         lines.append("")
         lines.append(
             f"bucketing: {bk.get('padded_dispatches', 0)} padded "
+            f"dispatch(es), {bk.get('window_dispatches', 0)} window "
             f"dispatch(es), {bk.get('pad_rows', 0)} pad row(s) "
-            "(synthetic rows paid for the bounded compile count)"
+            "(rows computed beyond the real ones, paid for the bounded "
+            "compile count)"
         )
         for verb, f in bk.get("fill", {}).items():
             lines.append(

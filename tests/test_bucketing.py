@@ -139,6 +139,195 @@ class TestBucketedMap:
         assert not sp.rowwise_fetches(g2, f2, ranks2)
 
 
+# ---------------------------------------------------------------------------
+# the block window (ISSUE 27): cut + pad of a resident column in one program
+# ---------------------------------------------------------------------------
+
+
+def _resident(cols, sizes):
+    """A frame of device-resident columns cut into blocks of ``sizes``."""
+    import jax
+
+    from tensorframes_tpu.frame import Column
+
+    offsets = [int(v) for v in np.cumsum([0] + list(sizes))]
+    return tfs.TensorFrame(
+        [Column(k, jax.device_put(v)) for k, v in cols.items()], offsets
+    )
+
+
+def _ints(n, width=None, mod=13):
+    v = (np.arange(n * (width or 1)) % mod).astype(np.float32)
+    return v.reshape(n, width) if width else v
+
+
+def _two_columns(df):
+    return (tfs.block(df, "x") * 2.0 + tfs.block(df, "y")).named("z")
+
+
+def _times_two(df):
+    return (tfs.block(df, "x") * 2.0).named("z")
+
+
+def _rows_times_two(df):
+    return (tfs.row(df, "x") * 2.0).named("z")
+
+
+# case -> (verb, frame, fetch, injected fault, the (shift, n) pairs the
+# unpad program must see, window dispatches, padded dispatches); rung
+# ladder 8, 16, 32, ...
+_WINDOW_CASES = {
+    # every window starts at its block: 3 x 10 rows in rungs of 16, the
+    # last block (16 rows) on its rung and so neither windowed nor padded
+    "interior-blocks": (
+        "map_blocks", lambda: _resident({"x": _ints(46)}, [10, 10, 10, 16]),
+        _times_two, None, {(0, 10)}, 3, 0,
+    ),
+    # the block at row 90 of 100 has no 16 rows after it: its window
+    # starts at 84 and its rows sit 6 into it
+    "last-blocks": (
+        "map_blocks", lambda: _resident({"x": _ints(100)}, [10] * 10),
+        _times_two, None, {(0, 10), (6, 10)}, 10, 0,
+    ),
+    "two-feed-columns": (
+        "map_blocks",
+        lambda: _resident({"x": _ints(60), "y": _ints(60, mod=7)}, [20] * 3),
+        _two_columns, None, {(0, 20), (12, 20)}, 3, 0,
+    ),
+    "2d-column-map_rows": (
+        "map_rows", lambda: _resident({"x": _ints(60, width=3)}, [20] * 3),
+        _rows_times_two, None, {(0, 20), (12, 20)}, 3, 0,
+    ),
+    # 64 is on its rung; 55 ends the column, 9 rows short of its rung
+    "uneven-blocks": (
+        "map_blocks",
+        lambda: _resident({"x": _ints(287)}, [3, 9, 17, 31, 64, 101, 7, 55]),
+        _times_two, None,
+        {(0, 3), (0, 9), (0, 17), (0, 31), (0, 101), (0, 7), (9, 55)}, 7, 0,
+    ),
+    # block 0's first dispatch runs out of memory: its halves take
+    # smaller windows of the same column (50 -> 25 + 25 rows)
+    "oom-split-half": (
+        "map_blocks", lambda: _resident({"x": _ints(100)}, [50, 50]),
+        _times_two, "resource", {(0, 25), (14, 50)}, 4, 0,
+    ),
+    # controls: nothing to take a window of
+    "control-one-block-short-of-its-rung": (
+        "map_blocks", lambda: _resident({"x": _ints(40)}, [40]),
+        _times_two, None, set(), 0, 1,
+    ),
+    "control-numpy-column": (
+        "map_blocks",
+        lambda: tfs.TensorFrame.from_dict({"x": _ints(40)}, num_blocks=4),
+        _times_two, None, set(), 0, 4,
+    ),
+    "control-numpy-column-map_rows": (
+        "map_rows",
+        lambda: tfs.TensorFrame.from_dict(
+            {"x": _ints(40, width=3)}, num_blocks=4
+        ),
+        _rows_times_two, None, set(), 0, 4,
+    ),
+}
+
+
+class TestBlockWindow:
+    @pytest.mark.parametrize("case", sorted(_WINDOW_CASES))
+    def test_bit_identical_to_unbucketed(self, case, monkeypatch):
+        from tensorframes_tpu.testing import faults as chaos
+        from tensorframes_tpu.utils import telemetry as tele
+
+        verb, make, fetch_of, fault, slices, windows, padded = (
+            _WINDOW_CASES[case]
+        )
+        df = make()
+        run, fetch = getattr(tfs, verb), fetch_of(df)
+        with tfs.config.override(shape_bucketing=False):
+            want = np.asarray(run(fetch, df)["z"].values)
+        tele.reset()
+        seen, unpad = set(), sp.block_unpad
+
+        def spy(shift, n, *outs):
+            seen.add((shift, n))
+            return unpad(shift, n, *outs)
+
+        monkeypatch.setattr(sp, "block_unpad", spy)
+        if fault:
+            with chaos.inject(nth=[0], fault=fault):
+                got = run(fetch, df)["z"].values
+        else:
+            got = run(fetch, df)["z"].values
+        np.testing.assert_array_equal(np.asarray(got), want)
+        assert seen == slices
+        c = tele.flat_counters()
+        assert c.get("shape_bucketing.window_dispatch", 0) == windows
+        assert c.get("shape_bucketing.padded_dispatch", 0) == padded
+        # the same `bucket - n` a dispatch on either path; the split's
+        # failed first attempt of block 0 (50 rows) counted its window too
+        sizes = df.block_sizes() + ([25, 25] if fault else [])
+        assert c.get("shape_bucketing.pad_rows", 0) == sum(
+            sp.bucket_for(n) - n for n in sizes
+        )
+
+    def test_block_feeds_takes_what_the_columns_show(self):
+        import jax
+
+        def cut():
+            raise AssertionError("a window needs no cut")
+
+        x = jax.device_put(_ints(100))
+        feeds, bucket, shift = sp.block_feeds([x], 90, 100, cut)
+        assert (bucket, shift) == (16, 6)
+        np.testing.assert_array_equal(np.asarray(feeds[0]), _ints(100)[84:])
+        # on its rung, shorter than its rung, on the host, or sharded
+        # over devices: the caller's cut, padded by replication
+        sharded = jax.device_put(
+            _ints(64),
+            jax.sharding.NamedSharding(
+                jax.sharding.Mesh(np.array(jax.devices()[:2]), ("d",)),
+                jax.sharding.PartitionSpec("d"),
+            ),
+        )
+        for cols, lo, hi in [
+            ([x], 0, 16), ([x[:10]], 0, 10), ([_ints(100)], 0, 10),
+            ([sharded], 0, 10), ([x, _ints(100)], 0, 10),
+        ]:
+            feeds, bucket, shift = sp.block_feeds(
+                cols, lo, hi, lambda: [c[lo:hi] for c in cols]
+            )
+            assert shift is None and bucket == 16
+            assert all(f.shape[0] == 16 for f in feeds)
+
+    def test_compiles_bounded_over_drifting_blocks(self):
+        """Block sizes drift over one resident frame: the verb's program
+        and the window helper each compile at most once a rung, and a
+        second call on the same frame compiles nothing."""
+        sizes = [3, 9, 17, 31, 64, 101, 7, 55, 5, 12, 90, 33]
+        df = _resident({"x": _ints(sum(sizes))}, sizes)
+        rungs = len(set(df.bucketed_block_sizes()))
+        assert rungs < len(set(sizes))
+        ex = Executor()
+        fetch = (tfs.block(df, "x") * 2.0 + 1.0).named("y")
+        w0, u0 = sp.block_window._cache_size(), sp.block_unpad._cache_size()
+        with tfs.config.override(block_scheduler="off"):
+            out = tfs.map_blocks(fetch, df, executor=ex)
+            np.testing.assert_array_equal(
+                np.asarray(out["y"].values), _ints(sum(sizes)) * 2.0 + 1.0
+            )
+            assert ex.jit_shape_compiles() <= rungs
+            assert sp.block_window._cache_size() - w0 <= rungs
+            w1, u1 = (
+                sp.block_window._cache_size(), sp.block_unpad._cache_size()
+            )
+            # the unpad is a static slice: one a distinct (rung, shift, n)
+            assert u1 - u0 <= len(set(sizes))
+            n_compiles = ex.jit_shape_compiles()
+            tfs.map_blocks(fetch, df, executor=ex)
+            assert ex.jit_shape_compiles() == n_compiles
+            assert sp.block_window._cache_size() == w1
+            assert sp.block_unpad._cache_size() == u1
+
+
 class TestBucketedReduce:
     @pytest.mark.parametrize("op", ["sum", "min", "max", "mean"])
     def test_reduce_matches_unbucketed(self, op):

@@ -463,11 +463,15 @@ class TestBucketFill:
         )
         data = tfs.diagnostics(format="json")
         bk = data["bucketing"]
-        assert bk["padded_dispatches"] > 0
+        # a resident frame of several blocks: each block off its rung
+        # (513 rows; the 512-row ones are on theirs) is a window of the
+        # column (`shape_policy.block_feeds`), none a replicated pad
+        assert bk["window_dispatches"] == 4 and bk["padded_dispatches"] == 0
         assert bk["pad_rows"] > 0
         assert 0.0 < bk["fill"]["map_blocks"]["mean"] <= 1.0
         text = tfs.diagnostics()
         assert "bucketing:" in text and "pad row" in text
+        assert "4 window dispatch(es)" in text
 
     def test_disabled_telemetry_skips_fill(self):
         ex = Executor()

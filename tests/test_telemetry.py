@@ -601,7 +601,12 @@ class TestCrossThreadSpanAttribution:
 # children of `<verb>.plan`; map_rows' dense route classifies nothing
 _PLAN = ["graph.analyze", "frame.match", "executor.lookup", "scheduler.plan"]
 
-# (verb, rows, blocks) -> the spans of ONE warm call: {name: (count, parent)}
+# (verb, rows, blocks) -> the spans of ONE warm call: {name: (count, parent)}.
+# A frame of several blocks is device-resident, so its blocks off their
+# rung are windows of the column (`shape_policy.block_feeds`): the window
+# sits in `shape.pad`, and there is no separate cut. The one-block frames
+# are numpy-backed and shorter than their rung: the cut and the
+# replicated pad, as they were.
 _CALLS = {
     "map_blocks-1block-on-rung": ("map_blocks", 64, 1, {}),
     "map_blocks-1block-off-rung": ("map_blocks", 40, 1, {
@@ -609,7 +614,6 @@ _CALLS = {
         "shape.unpad": (1, "map_blocks.blocks"),
     }),
     "map_blocks-4blocks-off-rung": ("map_blocks", 40, 4, {
-        "frame.cut": (4, "map_blocks.blocks"),
         "shape.pad": (4, "map_blocks.blocks"),
         "shape.unpad": (4, "map_blocks.blocks"),
         "frame.concat": (1, "map_blocks"),
@@ -619,6 +623,11 @@ _CALLS = {
         "shape.pad": (1, "map_rows.blocks"),
         "shape.unpad": (1, "map_rows.blocks"),
     }),
+    "map_rows-4blocks-off-rung": ("map_rows", 40, 4, {
+        "shape.pad": (4, "map_rows.blocks"),
+        "shape.unpad": (4, "map_rows.blocks"),
+        "frame.concat": (1, "map_rows"),
+    }),
 }
 
 
@@ -626,8 +635,14 @@ def _warm_call(case):
     """The case's verb call, twice: the first compiles, the ring is
     cleared, and the second is the one the test reads."""
     verb, rows, blocks, extra = _CALLS[case]
-    df = tfs.TensorFrame.from_dict(
-        {"x": np.arange(rows, dtype=np.float32)}, num_blocks=blocks
+    x = np.arange(rows, dtype=np.float32)
+    if blocks > 1:
+        import jax
+
+        x = jax.device_put(x)
+    df = tfs.TensorFrame(
+        [tfs.Column("x", x)],
+        [int(v) for v in np.linspace(0, rows, blocks + 1)],
     )
     ph = (tfs.block if verb == "map_blocks" else tfs.row)(df, "x")
     fetch = (ph * 2.0).named("z")
@@ -755,3 +770,20 @@ def test_verb_programs_lower_to_a_module_named_jit_fn(program):
         fn = ex.cached("vmap-rows", graph, fetches, ["x"], never)
     text = fn.__wrapped__.lower(np.arange(8, dtype=np.float32)).as_text()
     assert re.search(r"module @(\S+)", text).group(1) == "jit_fn"
+
+
+@pytest.mark.parametrize("helper", ["block_window", "block_unpad"])
+def test_window_helpers_lower_to_modules_of_their_own(helper):
+    """The window and the unpad of `shape_policy` are copies around the
+    verb's program: in a device trace they must not count as `jit_fn`
+    (the benchmark's `program_roofline`) but under their own names
+    (`copy_device_pct`)."""
+    import re
+
+    from tensorframes_tpu import shape_policy as sp
+
+    x = np.arange(32, dtype=np.float32)
+    args = (16, np.int32(3), x) if helper == "block_window" else (3, 10, x)
+    text = getattr(sp, helper).lower(*args).as_text()
+    name = re.search(r"module @(\S+)", text).group(1)
+    assert name == f"jit_{helper}" and not re.match(r"^jit_fn$", name)

@@ -208,6 +208,15 @@ def _check_bindings(
             )
 
 
+def _host_or_device(bindings: Optional[Dict]) -> Dict:
+    """A graph's bound arrays: a `jax.Array` stays on its device, anything
+    else becomes a host array (one array a placeholder)."""
+    return {
+        k: v if isinstance(v, jax.Array) else np.asarray(v)
+        for k, v in (bindings or {}).items()
+    }
+
+
 def _match_columns(
     summary: GraphSummary,
     frame: TensorFrame,
@@ -754,6 +763,190 @@ def _string_passthrough_columns(
 
 
 # ---------------------------------------------------------------------------
+# the block loop: one dispatch per non-empty block, shared by every
+# per-block map (graph or plain function, map_blocks or dense map_rows)
+# ---------------------------------------------------------------------------
+
+
+def _place_bindings(bindings: Dict, sched, ex) -> Dict:
+    """The call's bound values on the devices its schedule dispatches
+    to (`runtime.bindings`): device leaves once, host leaves once a call."""
+    from .runtime import bindings as _rb
+
+    if not bindings:
+        return {}
+    devices = None
+    if sched is not None:
+        devices = [
+            sched.devices[s] for s in sorted(
+                {s for s in sched.assignment if s is not None}
+            )
+        ]
+    return _rb.place(
+        bindings, devices,
+        on_device=getattr(ex, "supports_scheduling", False),
+    )
+
+
+def _run_blocks(
+    verb: str,
+    frame: TensorFrame,
+    fn: Callable,
+    fp: str,
+    feed_names: Sequence[str],
+    columns: Dict[str, object],
+    bound: Dict,
+    out_names: Optional[Sequence[str]],
+    sched,
+    trim: bool = False,
+    rowwise: bool = False,
+    bucketed: bool = False,
+    empty: Optional[Callable[[], Dict]] = None,
+) -> Tuple[List[Column], List[int]]:
+    """Dispatch ``fn`` over every non-empty block of ``frame`` and
+    concatenate what the blocks give: ``(output columns, offsets)``.
+
+    ``feed_names`` orders the program's arguments: a name in ``columns``
+    is fed the block's rows of that column's values, a name in ``bound``
+    the bound tree whole (`_place_bindings`). ``fn`` returns one output
+    per name of ``out_names``, or a dict (a plain function: the names
+    are its keys). ``bucketed`` pads the column feeds up the bucket
+    ladder, or takes a window of resident columns, and takes the pad
+    rows off again (`shape_policy.block_feeds` / `unpad_block`); the
+    caller sets it only for programs it knows row-local. ``rowwise``
+    lets a RESOURCE fault split the block's rows in half, to
+    ``config.oom_split_depth``. ``empty()`` names and shapes the
+    outputs of a frame with no rows.
+
+    Per block: classified fault handling (`runtime.faults`: transient
+    errors retry with backoff and fail over under the scheduler; the
+    verb's deadline is checked at every dispatch), a ``<verb>.block``
+    dispatch span per attempt labeled with the device it ran on, and
+    the numerics check. Spans: ``<verb>.blocks`` around the loop,
+    ``frame.cut`` / ``shape.pad`` / ``shape.unpad`` / ``frame.concat``
+    where a block is cut, padded or joined."""
+    from . import shape_policy as _sp
+    from .runtime import faults as _flt
+
+    fscope = _flt.scope(verb)
+    col_names = [n for n in feed_names if n in columns]
+    col_values = [columns[n] for n in col_names]
+
+    def _dispatch_rows(bi: int, lo_: int, hi_: int, depth: int) -> List:
+        def _cut() -> List:
+            if lo_ == 0 and hi_ == frame.nrows:  # the whole frame: no cut
+                return list(col_values)
+            with _tele.span("frame.cut", block=bi, rows=hi_ - lo_):
+                return [v[lo_:hi_] for v in col_values]
+
+        if bucketed:
+            # a window of the resident columns where they have one, else
+            # the cut, padded (`shape_policy.block_feeds`)
+            cut, bucket, shift = _sp.block_feeds(col_values, lo_, hi_, _cut)
+        else:
+            _sp.exact_dispatch()
+            cut, bucket, shift = _cut(), hi_ - lo_, None
+        by_name = dict(zip(col_names, cut))
+
+        def _thunk():
+            # span inside the thunk: each ATTEMPT records its own
+            # dispatch span labeled with the device it actually ran on
+            # (after failover the retry charges the NEW device, and
+            # backoff sleeps stay outside dispatch spans)
+            device = sched.device(bi) if sched is not None else None
+            feeds = [
+                by_name[n] if n in by_name else bound[n].on(device)
+                for n in feed_names
+            ]
+            call = sched.bind(bi, fn) if sched is not None else fn
+            with _tele.dispatch_span(
+                f"{verb}.block", program=fp, block=bi, rows=hi_ - lo_,
+                bucket=bucket if bucketed else None,
+                device=sched.label(bi) if sched is not None else None,
+            ):
+                return call(*feeds)
+
+        try:
+            outs = fscope.dispatch(
+                _thunk,
+                what=f"{verb} block {bi} rows [{lo_}:{hi_})",
+                sched=sched, index=bi,
+            )
+        except Exception as e:
+            if _flt.classify(e) != _flt.RESOURCE:
+                raise
+            verdict = None
+            if not rowwise:
+                verdict = "reraise:not-row-local"
+            elif not _flt.split_allowed(hi_ - lo_, depth):
+                verdict = "reraise:split-depth-exhausted"
+            mid = (lo_ + hi_) // 2
+            _flt.record_oom(
+                verb, fp, hi_ - lo_, depth,
+                verdict or f"split:[{lo_}:{mid})+[{mid}:{hi_})", e,
+                bucket=bucket if bucketed else None,
+            )
+            if verdict:
+                raise
+            _flt.note_split(verb)
+            left = _dispatch_rows(bi, lo_, mid, depth + 1)
+            right = _dispatch_rows(bi, mid, hi_, depth + 1)
+            return [_concat_parts([a, b]) for a, b in zip(left, right)]
+        if isinstance(outs, dict):  # a plain function names its outputs
+            names[:] = names or list(outs)
+            outs = [outs[n] for n in names]
+        return _sp.unpad_block(outs, hi_ - lo_, bucket, shift)
+
+    names: List[str] = list(out_names or [])
+    acc: List[List] = []
+    out_sizes: List[int] = []
+    with _tele.span(f"{verb}.blocks", kind="stage"):
+        for bi in range(frame.num_blocks):
+            lo, hi = frame.offsets[bi], frame.offsets[bi + 1]
+            if lo == hi:
+                out_sizes.append(0)
+                continue  # empty block: contributes nothing (the reference's
+                # empty-partition TODO, `DebugRowOps.scala:386-387`)
+            outs = _dispatch_rows(bi, lo, hi, 0)
+            maybe_check_numerics(names, outs, f"{verb} block {bi}")
+            bsize = None
+            for f, o in zip(names, outs):
+                # keep device arrays on device; shape checks are metadata-only
+                if not trim and (o.ndim == 0 or o.shape[0] != hi - lo):
+                    raise ValueError(
+                        f"{verb}: output {f!r} has lead dim "
+                        f"{o.shape[0] if o.ndim else '<scalar>'} but the block "
+                        f"has {hi - lo} rows"
+                        + ("; use trim=True for row-count-changing maps"
+                           if verb == "map_blocks" else "")
+                    )
+                if trim:
+                    if o.ndim == 0:
+                        raise ValueError(
+                            f"{verb}(trim): output {f!r} must have a lead dim"
+                        )
+                    if bsize is None:
+                        bsize = o.shape[0]
+                    elif o.shape[0] != bsize:
+                        raise ValueError(
+                            f"{verb}(trim): outputs disagree on row count"
+                        )
+            acc.append(outs)
+            out_sizes.append(bsize if trim else hi - lo)
+
+    anchor = sched.anchor_device() if sched is not None else None
+    if acc:
+        out_cols = [
+            Column(_base(n), _concat_parts([outs[i] for outs in acc], anchor))
+            for i, n in enumerate(names)
+        ]
+    else:  # every block empty: zero-row outputs
+        out_cols = [Column(_base(n), v) for n, v in empty().items()]
+    offsets = list(np.cumsum([0] + out_sizes)) if trim else frame.offsets
+    return out_cols, offsets
+
+
+# ---------------------------------------------------------------------------
 # map_blocks
 # ---------------------------------------------------------------------------
 
@@ -883,7 +1076,7 @@ def map_blocks(
             bindings=bindings,
         )
     ex = executor or default_executor()
-    bindings = {k: np.asarray(v) for k, v in (bindings or {}).items()}
+    bindings = _host_or_device(bindings)
     if not trim and not bindings:
         # block_scheduler="global": eligible row-local graphs dispatch
         # as ONE sharded SPMD program instead of one program per block
@@ -894,7 +1087,6 @@ def map_blocks(
             return routed
     from . import config as _config
     from . import shape_policy as _sp
-    from .runtime import faults as _flt
     from .runtime import scheduler as _rs
 
     with _tele.span("map_blocks.plan", kind="stage"):
@@ -936,142 +1128,19 @@ def map_blocks(
                     {p: ph.shape.rank for p, ph in summary.inputs.items()},
                 )
             )
-        bucketed = rowwise and _sp.enabled(ex)
-        # bucketed implies no bindings: every feed is a column
-        columns = (
-            [frame.column(mapping[n]).values for n in feed_names]
-            if bucketed else []
-        )
-
         with _tele.span("scheduler.plan"):
             sched = _rs.schedule_for(frame, devices=devices, executor=ex)
-        fscope = _flt.scope("map_blocks")
-        fp = graph.fingerprint()
+        bound = _place_bindings(bindings, sched, ex)
 
-    def _dispatch_rows(bi: int, lo_: int, hi_: int, depth: int) -> List:
-        """Dispatch rows ``[lo_, hi_)`` of block ``bi`` with classified
-        fault handling (`runtime.faults`): transient errors retry with
-        backoff (+ device failover under the scheduler); a RESOURCE
-        error (OOM) splits the range in half down the bucket ladder
-        and concatenates the halves — valid exactly for row-local
-        graphs, bounded by ``config.oom_split_depth``; unclassifiable
-        graphs re-raise the original error."""
-        def _cut() -> List:
-            if lo_ == 0 and hi_ == frame.nrows:  # the whole frame: no cut
-                return [
-                    bindings[n] if n in bindings
-                    else frame.column(mapping[n]).values
-                    for n in feed_names
-                ]
-            with _tele.span("frame.cut", block=bi, rows=hi_ - lo_):
-                return [
-                    bindings[n] if n in bindings
-                    else frame.column(mapping[n]).values[lo_:hi_]
-                    for n in feed_names
-                ]
-
-        if bucketed:
-            # a window of the resident columns where they have one, else
-            # the cut, padded (`shape_policy.block_feeds`)
-            feeds, bucket, shift = _sp.block_feeds(columns, lo_, hi_, _cut)
-        else:
-            feeds, bucket, shift = _cut(), hi_ - lo_, None
-
-        def _thunk():
-            # span inside the thunk: each ATTEMPT records its own
-            # dispatch span labeled with the device it actually ran on
-            # (after failover the retry charges the NEW device, and
-            # backoff sleeps stay outside dispatch spans)
-            call = sched.bind(bi, fn) if sched is not None else fn
-            with _tele.dispatch_span(
-                "map_blocks.block", program=fp, block=bi, rows=hi_ - lo_,
-                bucket=bucket if bucketed else None,
-                device=sched.label(bi) if sched is not None else None,
-            ):
-                return call(*feeds)
-
-        try:
-            outs = fscope.dispatch(
-                _thunk,
-                what=f"map_blocks block {bi} rows [{lo_}:{hi_})",
-                sched=sched, index=bi,
-            )
-        except Exception as e:
-            if _flt.classify(e) != _flt.RESOURCE:
-                raise
-            if not rowwise:
-                _flt.record_oom(
-                    "map_blocks", fp, hi_ - lo_, depth,
-                    "reraise:not-row-local", e,
-                    bucket=bucket if bucketed else None,
-                )
-                raise
-            if not _flt.split_allowed(hi_ - lo_, depth):
-                _flt.record_oom(
-                    "map_blocks", fp, hi_ - lo_, depth,
-                    "reraise:split-depth-exhausted", e,
-                    bucket=bucket if bucketed else None,
-                )
-                raise
-            mid = (lo_ + hi_) // 2
-            _flt.record_oom(
-                "map_blocks", fp, hi_ - lo_, depth,
-                f"split:[{lo_}:{mid})+[{mid}:{hi_})", e,
-                bucket=bucket if bucketed else None,
-            )
-            _flt.note_split("map_blocks")
-            left = _dispatch_rows(bi, lo_, mid, depth + 1)
-            right = _dispatch_rows(bi, mid, hi_, depth + 1)
-            return [_concat_parts([a, b]) for a, b in zip(left, right)]
-        return _sp.unpad_block(outs, hi_ - lo_, bucket, shift)
-
-    acc: Dict[str, List[np.ndarray]] = {_base(f): [] for f in fetch_list}
-    out_sizes: List[int] = []
-    with _tele.span("map_blocks.blocks", kind="stage"):
-        for bi in range(frame.num_blocks):
-            lo, hi = frame.offsets[bi], frame.offsets[bi + 1]
-            if lo == hi:
-                out_sizes.append(0)
-                continue  # empty block: contributes nothing (the reference's
-                # empty-partition TODO, `DebugRowOps.scala:386-387`)
-            outs = _dispatch_rows(bi, lo, hi, 0)
-            maybe_check_numerics(fetch_list, outs, f"map_blocks block {bi}")
-            bsize = None
-            for f, o in zip(fetch_list, outs):
-                # keep device arrays on device; shape checks are metadata-only
-                if not trim and (o.ndim == 0 or o.shape[0] != hi - lo):
-                    raise ValueError(
-                        f"map_blocks: output {f!r} has lead dim "
-                        f"{o.shape[0] if o.ndim else '<scalar>'} but the block "
-                        f"has {hi - lo} rows; use trim=True for row-count-"
-                        "changing maps"
-                    )
-                if trim:
-                    if o.ndim == 0:
-                        raise ValueError(
-                            f"map_blocks(trim): output {f!r} must have a lead dim"
-                        )
-                    if bsize is None:
-                        bsize = o.shape[0]
-                    elif o.shape[0] != bsize:
-                        raise ValueError(
-                            "map_blocks(trim): outputs disagree on row count"
-                        )
-                acc[_base(f)].append(o)
-            out_sizes.append(bsize if trim else hi - lo)
-
-    anchor = sched.anchor_device() if sched is not None else None
-    out_cols = []
-    for f in fetch_list:
-        base = _base(f)
-        parts = acc[base]
-        data = (
-            _concat_parts(parts, anchor)
-            if parts
-            else _empty_output(summary, base, drop_lead=True)
-        )
-        out_cols.append(Column(base, data))
-    offsets = list(np.cumsum([0] + out_sizes)) if trim else frame.offsets
+    out_cols, offsets = _run_blocks(
+        "map_blocks", frame, fn, graph.fingerprint(), feed_names,
+        {n: frame.column(c).values for n, c in mapping.items()}, bound,
+        fetch_list, sched, trim=trim, rowwise=rowwise, bucketed=rowwise and _sp.enabled(ex),
+        empty=lambda: {
+            _base(f): _empty_output(summary, _base(f), drop_lead=True)
+            for f in fetch_list
+        },
+    )
     return _output_frame(frame, out_cols, append_input=not trim, offsets=offsets)
 
 
@@ -1129,7 +1198,6 @@ def map_rows(
             devices=devices,
         )
     ex = executor or default_executor()
-    bindings = {k: np.asarray(v) for k, v in (bindings or {}).items()}
     if callable(fetches) and not isinstance(fetches, dsl.Tensor):
         if mesh is not None:
             from .parallel import verbs as _pverbs
@@ -1141,6 +1209,7 @@ def map_rows(
         return _map_rows_fn(
             fetches, frame, ex, bindings=bindings, devices=devices
         )
+    bindings = _host_or_device(bindings)
     graph, fetch_list = _as_graph(fetches, fetch_names)
     graph, fetch_list, str_pass = _split_string_passthrough(graph, fetch_list)
     if str_pass:
@@ -1228,115 +1297,33 @@ def map_rows(
                 )
             # per-block dispatches spread across local devices like
             # map_blocks; outputs stay device-resident per block and
-            # `_concat_parts` below concatenates ON DEVICE (colocating
+            # `_concat_parts` concatenates ON DEVICE (colocating
             # cross-device parts), so a chained verb never pays a hidden
             # per-block D2H sync
             from . import shape_policy as _sp
             from .graph import vectorize as _vec
-            from .runtime import faults as _flt
             from .runtime import scheduler as _rs
-
-            # Bucketed vmapped dispatch (`graph/vectorize.py` companion):
-            # the vmapped per-row program is row-independent by
-            # construction, so padding a block up the bucket ladder and
-            # slicing the pad rows off is always sound — drifting block
-            # sizes (and the branchy per-row graphs the vectorizer just
-            # unlocked) compile O(log max-rows) specializations instead of
-            # one per distinct size. Bindings keep the exact per-shape
-            # dispatch (bound feeds must stay whole).
-            bucketed = not bindings and _sp.enabled(ex) and _vec.enabled()
-            columns = [frame.column(c).values for c in cols_used]
 
             with _tele.span("scheduler.plan"):
                 sched = _rs.schedule_for(frame, devices=devices, executor=ex)
-            fscope = _flt.scope("map_rows")
-            fp = graph.fingerprint()
+            bound = _place_bindings(bindings, sched, ex)
 
     if dense:
-        def _dispatch_rows(bi: int, lo_: int, hi_: int, depth: int):
-            # classified faults: transient retries (+ failover under the
-            # scheduler); OOM splits the row range in half — always
-            # valid here, the vmapped per-row program is row-independent
-            # by construction (bound placeholders stay whole)
-            def _cut() -> List:
-                with _tele.span("frame.cut", block=bi, rows=hi_ - lo_):
-                    return [
-                        bindings[p]
-                        if p in bindings
-                        else frame.column(mapping[p]).values[lo_:hi_]
-                        for p in params
-                    ]
-
-            if bucketed:
-                # see map_blocks._dispatch_rows
-                feeds, bucket, shift = _sp.block_feeds(
-                    columns, lo_, hi_, _cut
-                )
-            else:
-                feeds, bucket, shift = _cut(), hi_ - lo_, None
-
-            def _thunk():
-                # per-attempt span (see map_blocks._dispatch_rows)
-                call = sched.bind(bi, vfn) if sched is not None else vfn
-                with _tele.dispatch_span(
-                    "map_rows.block", program=fp, block=bi,
-                    rows=hi_ - lo_,
-                    bucket=bucket if bucketed else None,
-                    device=sched.label(bi) if sched is not None else None,
-                ):
-                    return call(*feeds)
-
-            try:
-                outs_ = _thunk_outs(_thunk, bi, lo_, hi_)
-                return _sp.unpad_block(outs_, hi_ - lo_, bucket, shift)
-            except Exception as e:
-                if _flt.classify(e) != _flt.RESOURCE:
-                    raise
-                if not _flt.split_allowed(hi_ - lo_, depth):
-                    _flt.record_oom(
-                        "map_rows", fp, hi_ - lo_, depth,
-                        "reraise:split-depth-exhausted", e,
-                    )
-                    raise
-                mid = (lo_ + hi_) // 2
-                _flt.record_oom(
-                    "map_rows", fp, hi_ - lo_, depth,
-                    f"split:[{lo_}:{mid})+[{mid}:{hi_})", e,
-                )
-                _flt.note_split("map_rows")
-                left = _dispatch_rows(bi, lo_, mid, depth + 1)
-                right = _dispatch_rows(bi, mid, hi_, depth + 1)
-                return [
-                    _concat_parts([a, b]) for a, b in zip(left, right)
-                ]
-
-        def _thunk_outs(thunk, bi, lo_, hi_):
-            return fscope.dispatch(
-                thunk,
-                what=f"map_rows block {bi} rows [{lo_}:{hi_})",
-                sched=sched, index=bi,
-            )
-
-        acc: Dict[str, List[np.ndarray]] = {n: [] for n in out_names}
-        with _tele.span("map_rows.blocks", kind="stage"):
-            for bi in range(frame.num_blocks):
-                lo, hi = frame.offsets[bi], frame.offsets[bi + 1]
-                if lo == hi:
-                    continue
-                outs = _dispatch_rows(bi, lo, hi, 0)
-                maybe_check_numerics(out_names, outs, f"map_rows block {bi}")
-                for n, o in zip(out_names, outs):
-                    acc[n].append(o)
-        anchor = sched.anchor_device() if sched is not None else None
-        out_cols = [
-            Column(
-                n,
-                _concat_parts(parts, anchor)
-                if parts
-                else _empty_output(summary, n, drop_lead=False),
-            )
-            for n, parts in acc.items()
-        ]
+        # Bucketed vmapped dispatch (`graph/vectorize.py` companion):
+        # the vmapped per-row program is row-independent by
+        # construction, so padding a block up the bucket ladder and
+        # slicing the pad rows off is always sound, and so is splitting
+        # its rows on an OOM. Bindings keep the exact per-shape dispatch.
+        out_cols, _ = _run_blocks(
+            "map_rows", frame, vfn, graph.fingerprint(), params,
+            {p: frame.column(mapping[p]).values for p in col_params}, bound,
+            out_names, sched, rowwise=True,
+            bucketed=not bindings and _sp.enabled(ex) and _vec.enabled(),
+            empty=lambda: {
+                n: _empty_output(summary, n, drop_lead=False)
+                for n in out_names
+            },
+        )
     else:
         vfn = ex.cached(
             "vmap-rows",

@@ -2,11 +2,13 @@
 
 `flash_attention`: blockwise attention computed entirely in VMEM with
 online softmax — O(seq) memory instead of the O(seq^2) score matrix.
-Grid is (q_blocks, k_blocks); the k axis iterates sequentially (TPU grids
-run minor-axis-last), carrying the running max / denominator / weighted
-accumulator in VMEM scratch that persists across k iterations. Q·Kᵀ and
-P·V ride the MXU via `jnp.dot(..., preferred_element_type=f32)`; masking
-(causal + padded tail) happens on the VPU.
+Grid is (batch, heads, q_blocks, k_blocks); the k axis iterates
+sequentially (TPU grids run minor-axis-last), carrying the running max /
+denominator / weighted accumulator in VMEM scratch that persists across
+k iterations. Q·Kᵀ and P·V ride the MXU in the operands' own dtype with
+float32 accumulation; masking (causal + padded tail) happens on the VPU.
+A query head reads the key/value head of its group (grouped-query
+attention) through the block index, so nothing is repeated in memory.
 
 This kernel is the single-device building block the ring attention in
 `parallel/ring.py` composes across chips (K/V rotation over ICI); it is
@@ -41,8 +43,8 @@ def _flash_kernel(
     q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc,
     *, scale: float, causal: bool, seq_len: int, blk_q: int, blk_k: int,
 ):
-    i = pl.program_id(0)
-    j = pl.program_id(1)
+    i = pl.program_id(2)
+    j = pl.program_id(3)
 
     @pl.when(j == 0)
     def _init():
@@ -57,10 +59,13 @@ def _flash_kernel(
 
     @pl.when(needed)
     def _step():
-        q = q_ref[:].astype(jnp.float32)
-        k = k_ref[:].astype(jnp.float32)
-        v = v_ref[:].astype(jnp.float32)
-        s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+        # operands stay in their own dtype (bfloat16 rides the MXU at its
+        # full rate); both products accumulate in float32
+        q, k, v = q_ref[:], k_ref[:], v_ref[:]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale
 
         q_pos = i * blk_q + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
         k_pos = j * blk_k + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
@@ -77,11 +82,11 @@ def _flash_kernel(
         alpha = jnp.exp(m_prev - m_new)
         l_sc[:, 0] = alpha * l_sc[:, 0] + jnp.sum(p, axis=-1)
         acc_sc[:] = alpha[:, None] * acc_sc[:] + jnp.dot(
-            p, v, preferred_element_type=jnp.float32
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32
         )
         m_sc[:, 0] = m_new
 
-    @pl.when(j == pl.num_programs(1) - 1)
+    @pl.when(j == pl.num_programs(3) - 1)
     def _finish():
         l = l_sc[:, 0]
         l = jnp.where(l == jnp.float32(0.0), jnp.float32(1.0), l)
@@ -99,9 +104,19 @@ def flash_attention(
     block_k: int = 128,
     interpret: bool = False,
 ) -> jax.Array:
-    """Single-device blockwise attention. q/k/v: (seq, head_dim)."""
+    """Single-device blockwise attention. q/k/v: one head ``(seq,
+    head_dim)``, or ``(batch, heads, seq, head_dim)`` with k and v
+    holding ``kv_heads`` heads, each serving ``heads // kv_heads``
+    consecutive query heads (grouped-query attention: the kernel reads a
+    kv head's blocks where its query heads ask for them, nothing is
+    repeated in memory)."""
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1])
+    if q.ndim == 4 and q.shape[1] % k.shape[1]:
+        raise ValueError(
+            f"{q.shape[1]} query heads do not divide over {k.shape[1]} "
+            "key/value heads"
+        )
     return _flash(
         q, k, v, bool(causal), float(scale), block_q, block_k,
         bool(interpret),
@@ -109,17 +124,23 @@ def flash_attention(
 
 
 def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
-    seq, d = q.shape
+    if q.ndim == 2:
+        out = _flash_forward(
+            q[None, None], k[None, None], v[None, None],
+            causal, scale, block_q, block_k, interpret,
+        )
+        return out[0, 0]
+    batch, heads, seq, d = q.shape
+    group = heads // k.shape[1]
 
     blk_q = min(block_q, max(8, seq))
     blk_k = min(block_k, max(8, seq))
     pad_q = (-seq) % blk_q
     pad_k = (-seq) % blk_k
-    qp = jnp.pad(q, ((0, pad_q), (0, 0))) if pad_q else q
-    kp = jnp.pad(k, ((0, pad_k), (0, 0))) if pad_k else k
-    vp = jnp.pad(v, ((0, pad_k), (0, 0))) if pad_k else v
-    nq = qp.shape[0] // blk_q
-    nk = kp.shape[0] // blk_k
+    pad = lambda a, n: jnp.pad(a, ((0, 0), (0, 0), (0, n), (0, 0))) if n else a
+    qp, kp, vp = pad(q, pad_q), pad(k, pad_k), pad(v, pad_k)
+    nq = qp.shape[2] // blk_q
+    nk = kp.shape[2] // blk_k
 
     from jax.experimental.pallas import tpu as pltpu
 
@@ -131,15 +152,29 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
         blk_q=blk_q,
         blk_k=blk_k,
     )
+    def kv_block(b, h, i, j):
+        # `lax.div`, not `//`: the operands are never negative, and
+        # Mosaic lowers an index map too (a floor division's sign
+        # handling does not lower under x64)
+        if causal:
+            # a block above the diagonal is skipped: ask for the last
+            # one needed again, which is already there, not for a new one
+            j = jnp.minimum(
+                j, jax.lax.div(i * blk_q + (blk_q - 1), jnp.int32(blk_k))
+            )
+        return (b, jax.lax.div(h, jnp.int32(group)), j, jnp.int32(0))
+
     out = pl.pallas_call(
         kernel,
-        grid=(nq, nk),
+        grid=(batch, heads, nq, nk),
         in_specs=[
-            pl.BlockSpec((blk_q, d), lambda i, j: (i, jnp.int32(0))),
-            pl.BlockSpec((blk_k, d), lambda i, j: (j, jnp.int32(0))),
-            pl.BlockSpec((blk_k, d), lambda i, j: (j, jnp.int32(0))),
+            pl.BlockSpec((None, None, blk_q, d), lambda b, h, i, j: (b, h, i, jnp.int32(0))),
+            pl.BlockSpec((None, None, blk_k, d), kv_block),
+            pl.BlockSpec((None, None, blk_k, d), kv_block),
         ],
-        out_specs=pl.BlockSpec((blk_q, d), lambda i, j: (i, jnp.int32(0))),
+        out_specs=pl.BlockSpec(
+            (None, None, blk_q, d), lambda b, h, i, j: (b, h, i, jnp.int32(0))
+        ),
         out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
         scratch_shapes=[
             pltpu.VMEM((blk_q, 1), jnp.float32),  # running max
@@ -148,7 +183,7 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
         ],
         interpret=interpret,
     )(qp, kp, vp)
-    return out[:seq] if pad_q else out
+    return out[:, :, :seq] if pad_q else out
 
 
 _flash = jax.custom_vjp(_flash_forward, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -161,10 +196,16 @@ def _flash_fwd(q, k, v, *static):
 def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
     from ..parallel.ring import full_attention
 
-    _, vjp = jax.vjp(
-        lambda q, k, v: full_attention(q, k, v, causal=causal, scale=scale),
-        *res,
-    )
+    def plain(q, k, v):
+        if q.ndim == 2:
+            return full_attention(q, k, v, causal=causal, scale=scale)
+        group = q.shape[1] // k.shape[1]
+        one = lambda a, b, c: full_attention(a, b, c, causal=causal, scale=scale)
+        return jax.vmap(jax.vmap(one))(
+            q, jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
+        )
+
+    _, vjp = jax.vjp(plain, *res)
     return vjp(g)
 
 

@@ -24,6 +24,7 @@ from ..ops.lowering import build_callable
 
 __all__ = [
     "Executor",
+    "FnProgram",
     "default_executor",
     "lru_get_or_insert",
     "set_fault_injector",
@@ -69,6 +70,27 @@ def lru_get_or_insert(cache, lock, key, make, limit):
     return fn, True
 
 
+class FnProgram:
+    """What the executor's cache needs of a plain function in a graph's
+    place: a fingerprint. It is the function's identity (for a bound
+    method, the object's and the method's), which the cached program
+    keeps alive, so no other function can come by the same one while the
+    entry lives. A lambda written inside the call is a new function each
+    time and compiles each time, as any `jax.jit` of it would."""
+
+    def __init__(self, fn: Callable):
+        self.fn = fn
+
+    def fingerprint(self) -> str:
+        fn = self.fn
+        owner = getattr(fn, "__self__", None)
+        target = getattr(fn, "__func__", fn)
+        name = getattr(target, "__qualname__", type(target).__name__)
+        return f"fn:{name}@{id(target):x}" + (
+            f"/{id(owner):x}" if owner is not None else ""
+        )
+
+
 class Executor:
     # Compiled programs from this executor may carry `donate_argnums`
     # (the reduce-combine path): the in-process JAX runtime honors
@@ -105,13 +127,6 @@ class Executor:
         # cached-program keys already flagged by the recompile-storm
         # warning (one warning per program, ever)
         self._storm_warned: set = set()
-
-    def jit(self, fn: Callable) -> Callable:
-        """Compile an arbitrary jittable for this executor's runtime.
-        The function front-end kernels route through this seam so the
-        native executor (which overrides it) runs them on the C++ PJRT
-        host instead of in-process JAX."""
-        return jax.jit(fn)
 
     def cached(
         self,

@@ -244,13 +244,6 @@ class NativeExecutor:
 
         return run
 
-    def jit(self, fn: Callable) -> Callable:
-        """The function-front-end seam: compile an arbitrary jittable
-        through the native host (per-shape-signature cache inside
-        `_native_run`), so plain-function verbs run on the C++ PJRT
-        host too when this executor is the default."""
-        return self._native_run(fn)
-
     def cached(self, kind, graph, fetches, feed_names, make):
         if (
             kind.startswith(_MESH_KIND_PREFIXES)

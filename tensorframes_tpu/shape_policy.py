@@ -86,6 +86,7 @@ __all__ = [
     "bucket_ladder",
     "enabled",
     "observe_fill",
+    "exact_dispatch",
     "pad_feeds",
     "pad_lead",
     "slice_pad_rows",
@@ -197,6 +198,13 @@ def observe_fill(n: int, bucket: int, verb: Optional[str] = None) -> None:
     _tele.histogram_observe(
         "bucket_fill", min(1.0, n / bucket), verb=verb
     )
+
+
+def exact_dispatch() -> None:
+    """A block dispatched at its exact shape computes no pad row, and the
+    counter says so: a reader of ``shape_bucketing.pad_rows`` tells "none"
+    (0) from a program that does not count them (no such counter)."""
+    _count("shape_bucketing.pad_rows", 0)
 
 
 def pad_feeds(feeds: Sequence, n: int) -> Tuple[List, int]:

@@ -964,6 +964,16 @@ def _prom_labels(labels: LabelItems, extra: str = "") -> str:
 # — an absent # HELP is a lint error in several Prometheus toolchains.
 _PROM_HELP: Dict[str, str] = {
     "host_sync": "Device-to-host synchronization points",
+    "bindings.bytes_placed": (
+        "Bytes of bound leaves copied to a device (from the host or from "
+        "another device) by verb calls"
+    ),
+    "bindings.leaves": "Leaves of bound pytrees handed to verb programs",
+    "lm.tokens": "Tokens scored by models.lm.score",
+    "moe.routed_rows": (
+        "Token rows routed to experts (tokens x experts per token x "
+        "expert layers) by models.lm.score"
+    ),
     "fault_retries": "Classified dispatch retries by fault class",
     "device_evictions": "Failover circuit-breaker device evictions",
     "block_splits": "OOM-triggered block split-retries by verb",

@@ -40,6 +40,7 @@ def _reset_telemetry():
     from tensorframes_tpu.graph import plan, vectorize
     from tensorframes_tpu.runtime import (
         autotune,
+        bindings,
         blackbox,
         checkpoint,
         costmodel,
@@ -61,6 +62,7 @@ def _reset_telemetry():
     checkpoint.reset_state()  # durable-stream accounting never leaks
     globalframe.reset_state()  # SPMD dispatch/fallback ledger never leaks
     materialize.reset_state()  # cached results never answer another test
+    bindings.reset_state()  # one test's placed copies never serve another
     vectorize.reset_state()  # lowering/fallback ledger never leaks
     blackbox.reset_state()  # one test's incidents never explain another's
     plan.reset_state()  # rewrite/fallback/pushdown ledger never leaks

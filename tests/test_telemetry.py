@@ -605,8 +605,9 @@ _PLAN = ["graph.analyze", "frame.match", "executor.lookup", "scheduler.plan"]
 # A frame of several blocks is device-resident, so its blocks off their
 # rung are windows of the column (`shape_policy.block_feeds`): the window
 # sits in `shape.pad`, and there is no separate cut. The one-block frames
-# are numpy-backed and shorter than their rung: the cut and the
-# replicated pad, as they were.
+# are numpy-backed and shorter than their rung: the replicated pad, as it
+# was, and no cut in either verb (a block that is the whole frame is fed
+# the column as it is: both verbs run `api._run_blocks`).
 _CALLS = {
     "map_blocks-1block-on-rung": ("map_blocks", 64, 1, {}),
     "map_blocks-1block-off-rung": ("map_blocks", 40, 1, {
@@ -619,7 +620,6 @@ _CALLS = {
         "frame.concat": (1, "map_blocks"),
     }),
     "map_rows-dense": ("map_rows", 40, 1, {
-        "frame.cut": (1, "map_rows.blocks"),
         "shape.pad": (1, "map_rows.blocks"),
         "shape.unpad": (1, "map_rows.blocks"),
     }),

@@ -159,3 +159,103 @@ def test_transformer_train_step_through_the_kernel(one_chip, monkeypatch):
     lowered = jax.jit(lm.train_step).lower(shapes, tokens)
     assert "tpu_custom_call" in lowered.as_text()
     lowered.compile()
+
+
+# --- the language model of the benchmark's `lfm2-8b-a1b`, at its widths ---
+
+
+def _lfm2_config():
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perf", "configs", "lfm2-8b-a1b.json")) as f:
+        return json.load(f)
+
+
+def test_grouped_query_flash_attention_at_the_cells_shape(one_chip):
+    # 8 rows x 32 query heads over 8 key/value heads of 64, 4,096
+    # positions, bfloat16, 512-blocks: as `models.lm` calls the kernel
+    lowered, compiled = _compile(
+        functools.partial(flash_attention, causal=True, block_q=512, block_k=512),
+        one_chip,
+        ((8, 32, 4096, 64), jnp.bfloat16),
+        ((8, 8, 4096, 64), jnp.bfloat16),
+        ((8, 8, 4096, 64), jnp.bfloat16),
+    )
+    assert "tpu_custom_call" in lowered.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+
+
+def test_the_whole_scoring_program_at_the_cells_block(one_chip):
+    """`lm.scoring_fn` of the configuration over one block of the cell (8
+    windows of 4,096 ids) with the weights as arguments: it compiles for
+    the chip with the kernel in it, its temporaries fit beside 6.5 GB of
+    weights, and its three outputs are small."""
+    from tensorframes_tpu.models import lm
+
+    cfg = _lfm2_config()
+    shapes = jax.eval_shape(lambda: lm.init_params(cfg, 0))
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), shapes)
+    tokens = jax.ShapeDtypeStruct((8, 4096), jnp.int32, sharding=one_chip)
+    lowered = jax.jit(lm.scoring_fn(cfg)).lower(tokens, params)
+    assert "tpu_custom_call" in lowered.as_text()
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(shapes))
+    assert 6.4e9 < weights < 6.7e9
+    assert weights + memory.temp_size_in_bytes < 15.75 * 2**30
+    out = compiled.out_info if hasattr(compiled, "out_info") else None
+    assert memory.output_size_in_bytes < 8 * 2**20
+    if out is not None:
+        assert {k: v.shape for k, v in out.items()} == {
+            "token_logprob": (8, 4096), "expert_load": (8, 8, 32),
+            "expert_choice": (8, 8, 4096, 4)}
+
+
+def test_expert_layer_is_a_grouped_matmul_under_the_names_the_benchmark_reads(
+    one_chip,
+):
+    """One expert layer at the published widths (32,768 tokens top-4 over
+    32 experts of 1,792): the two grouped matmuls are the chip's own
+    ragged-dot kernel, under operation names that the configuration's
+    `kernel_ops.moe_experts` matches (what `moe_expert_roofline` sums in a
+    device trace) and that nothing else in the layer matches."""
+    import re
+    import sys
+
+    from tensorframes_tpu.models import moe
+
+    cfg = _lfm2_config()
+    d, f, e = cfg["hidden_size"], cfg["moe_intermediate_size"], cfg["num_experts"]
+
+    def layer(u, router, bias, w_up, w_down):
+        idx, w = moe.route(u, router, bias, top_k=cfg["num_experts_per_tok"])
+        return moe.held_experts(u, idx, w, w_up, w_down, (0, e))
+
+    bf16 = jnp.bfloat16
+    _, compiled = _compile(
+        layer, one_chip, ((32768, d), bf16), ((d, e), bf16), ((e,), bf16),
+        ((e, d, 2 * f), bf16), ((e, f, d), bf16),
+    )
+    root = __import__("os").path.dirname(__import__("os").path.dirname(
+        __import__("os").path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from perf.lib.trace import op_label
+
+    pattern = re.compile(cfg["kernel_ops"]["moe_experts"])
+    labels = [
+        op_label(line.strip().removeprefix("ROOT "))
+        for line in compiled.as_text().splitlines() if " = " in line
+    ]
+    matched = sorted({l for l in labels if pattern.search(l)})
+    assert len(matched) == 2, matched
+    assert all(l.startswith("ragged-dot-none") for l in matched)
+    assert {l.split()[1] for l in matched} == {
+        f"f32[{32768 * 4},{2 * f}]", f"f32[{32768 * 4},{d}]"}
+    # no dense evaluation of every expert beside it: no convolution (a
+    # plain dot on the chip) as large as an expert's matmul over all rows
+    assert not [l for l in labels if l.startswith("convolution")
+                and f"[{32768 * 4}," in l]

@@ -812,8 +812,9 @@ def _run_blocks(
     per name of ``out_names``, or a dict (a plain function: the names
     are its keys). ``bucketed`` pads the column feeds up the bucket
     ladder, or takes a window of resident columns, and takes the pad
-    rows off again (`shape_policy.block_feeds` / `unpad_block`); the
-    caller sets it only for programs it knows row-local. ``rowwise``
+    rows off again (`shape_policy.block_dispatch`, which may hand back
+    an exact-shape executable of ``fn`` in its place); the caller sets
+    it only for programs it knows row-local. ``rowwise``
     lets a RESOURCE fault split the block's rows in half, to
     ``config.oom_split_depth``. ``empty()`` names and shapes the
     outputs of a frame with no rows.
@@ -841,11 +842,16 @@ def _run_blocks(
 
         if bucketed:
             # a window of the resident columns where they have one, else
-            # the cut, padded (`shape_policy.block_feeds`)
-            cut, bucket, shift = _sp.block_feeds(col_values, lo_, hi_, _cut)
+            # the cut, padded, or on the exact-shape executable a
+            # repeated pad has bought (`shape_policy.block_dispatch`)
+            blk = _sp.block_dispatch(
+                fn, col_values, lo_, hi_, _cut,
+                sched.device(bi) if sched is not None else None,
+            )
+            cut, bucket, program = blk.feeds, blk.bucket, blk.call
         else:
             _sp.exact_dispatch()
-            cut, bucket, shift = _cut(), hi_ - lo_, None
+            blk, cut, bucket, program = None, _cut(), hi_ - lo_, fn
         by_name = dict(zip(col_names, cut))
 
         def _thunk():
@@ -858,7 +864,7 @@ def _run_blocks(
                 by_name[n] if n in by_name else bound[n].on(device)
                 for n in feed_names
             ]
-            call = sched.bind(bi, fn) if sched is not None else fn
+            call = sched.bind(bi, program) if sched is not None else program
             with _tele.dispatch_span(
                 f"{verb}.block", program=fp, block=bi, rows=hi_ - lo_,
                 bucket=bucket if bucketed else None,
@@ -895,7 +901,7 @@ def _run_blocks(
         if isinstance(outs, dict):  # a plain function names its outputs
             names[:] = names or list(outs)
             outs = [outs[n] for n in names]
-        return _sp.unpad_block(outs, hi_ - lo_, bucket, shift)
+        return list(outs) if blk is None else blk.unpad(outs)
 
     names: List[str] = list(out_names or [])
     acc: List[List] = []

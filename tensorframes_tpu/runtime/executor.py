@@ -25,6 +25,7 @@ from ..ops.lowering import build_callable
 __all__ = [
     "Executor",
     "FnProgram",
+    "ProgramLedger",
     "default_executor",
     "lru_get_or_insert",
     "set_fault_injector",
@@ -46,6 +47,14 @@ _fault_injector = None
 def set_fault_injector(hook) -> None:
     global _fault_injector
     _fault_injector = hook
+
+
+def hand_out(fn: Callable, key: Tuple) -> Callable:
+    """``fn`` as a dispatch site is handed it: under the installed fault
+    injector, or itself. `Executor.cached` hands out its programs this
+    way, and `shape_policy` the exact-shape executables it hangs on one
+    (under the entry's key), so they cross the same boundary."""
+    return fn if _fault_injector is None else _fault_injector(fn, key)
 
 
 def lru_get_or_insert(cache, lock, key, make, limit):
@@ -89,6 +98,24 @@ class FnProgram:
         return f"fn:{name}@{id(target):x}" + (
             f"/{id(owner):x}" if owner is not None else ""
         )
+
+
+class ProgramLedger:
+    """What `shape_policy`'s promotion rule reads and keeps with one
+    cached program (``entry.ledger``, `Executor._instrument`): the
+    cache ``key``; the program's own ``jitted`` function; ``compile_
+    seconds``, what its last XLA compile took (None until one was
+    seen), timed whatever the telemetry and cost-ledger switches say,
+    so that turning them off does not change what the program does;
+    and ``shapes``, the policy's own state per exact feed signature."""
+
+    __slots__ = ("key", "jitted", "compile_seconds", "shapes")
+
+    def __init__(self, key: Tuple, jitted: Callable):
+        self.key = key
+        self.jitted = jitted
+        self.compile_seconds: Optional[float] = None
+        self.shapes: OrderedDict = OrderedDict()
 
 
 class Executor:
@@ -171,9 +198,7 @@ class Executor:
                 self.cache_misses += 1
             else:
                 self.cache_hits += 1
-        if _fault_injector is not None:
-            fn = _fault_injector(fn, key)
-        return fn
+        return hand_out(fn, key)
 
     def _instrument(self, key: Tuple, fn: Callable) -> Callable:
         """Wrap a freshly built cached program with per-shape compile
@@ -185,7 +210,11 @@ class Executor:
         a `_cache_size` (native-host wrappers, plain callables) pass
         through untouched; the jit cache handle is re-exposed on the
         wrapper so introspection (`jit_shape_compiles`, tests poking
-        `fn._cache_size()`) keeps working."""
+        `fn._cache_size()`) keeps working.
+
+        The wrapper is the cache ENTRY; its ``ledger`` (`ProgramLedger`)
+        is what the shape policy's promotion rule reads and keeps, and
+        lives and is evicted with the entry."""
         sizer = getattr(fn, "_cache_size", None)
         if not callable(sizer):
             return fn
@@ -201,6 +230,7 @@ class Executor:
         # under contention.
         compile_seen = [0]
         seen_lock = threading.Lock()
+        book = ProgramLedger(key, fn)
 
         def wrapped(*args, **kwargs):
             from ..utils import telemetry as _tele
@@ -209,19 +239,16 @@ class Executor:
             # jit shape re-specialization attribution: when this call
             # grows the jit cache, the (synchronous) trace+XLA-compile
             # happened inside it — time the call and label the compile
-            # event with the program fingerprint. Tracked when telemetry
-            # OR the cost ledger is on (the ledger captures the
-            # compiler's modeled cost at exactly these events); with
-            # both disabled runs pay nothing beyond the storm check
-            # below.
+            # event with the program fingerprint. Always tracked (two
+            # cache-size reads and two clock reads a dispatch): the
+            # seconds are the price of the shape policy's promotion
+            # rule; `record_compile` and the ledger gate themselves.
             ledger = _cm.enabled()
-            n0 = None
-            if _tele.enabled() or ledger:
-                try:
-                    n0 = sizer()
-                except Exception:
-                    n0 = None
-                t0 = time.perf_counter()
+            try:
+                n0 = sizer()
+            except Exception:
+                n0 = None
+            t0 = time.perf_counter()
             if n0 is not None:
                 with seen_lock:
                     if compile_seen[0] < n0:
@@ -240,6 +267,7 @@ class Executor:
                             record = True
                 if record:
                     t1 = time.perf_counter()
+                    book.compile_seconds = t1 - t0
                     _tele.record_compile(
                         key[1], key[0], t1 - t0, "xla", t0, t1
                     )
@@ -303,6 +331,7 @@ class Executor:
 
         wrapped._cache_size = sizer
         wrapped.__wrapped__ = fn
+        wrapped.ledger = book
         return wrapped
 
     def program_shape_compiles(self) -> Dict[Tuple, int]:
@@ -385,6 +414,12 @@ class Executor:
         fingerprint) where the eager chain creates one per verb."""
         with self._lock:
             return list(self._cache.keys())
+
+    def programs(self) -> List[Callable]:
+        """Snapshot of the live cache entries (the programs `cached`
+        hands out, before any fault injector)."""
+        with self._lock:
+            return list(self._cache.values())
 
     def clear(self) -> None:
         with self._lock:

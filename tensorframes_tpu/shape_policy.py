@@ -56,6 +56,51 @@ over devices or a block already on its rung keeps the cut and the
 replicated pad below, unchanged. The reduce, lazy, stream and mesh
 routes call `pad_feeds` and never take a window.
 
+PROMOTION (`block_dispatch`, the same two loops): the ladder trades pad
+work for compiles, and for a shape that keeps coming back the trade
+turns bad. A one-block frame off its rung has no window (its column is
+shorter than the rung), so every call copies the column into a padded
+one, computes up to growth times the rows and copies the valid rows out
+again, to save a compile it stopped needing after the first call. When
+a shape has earned a program of its own is the classic rent-or-buy
+question, and what the policy observes answers it. Per cached program
+and exact feed signature (row count, trailing shapes, dtypes, the
+device) a ledger line on the executor's cache entry (`ProgramLedger`,
+evicted with the entry; at most ``config.executor_cache_entries`` lines
+a program, least recently seen out first) adds up the RENT paid so far:
+for every replicated pad of device-resident feeds, the seconds the pad
+cost beyond an exact dispatch, from shapes alone: the bytes of the pad
+copy (read ``n``, write ``bucket`` rows of every feed), of the ``bucket
+- n`` pad rows through the program (feed and output row bytes) and of
+the slice (read and write ``n`` rows of every output), over the
+device's HBM bandwidth (`runtime.costmodel.device_peaks`; a device kind
+the table does not know is never promoted, counted once a line as
+``shape_bucketing.promotion_unpriced``). The PRICE is what this
+program's own last XLA compile took, which `Executor._instrument`
+measures whatever the telemetry switches say (a program with no
+measured compile, the native host's, is never promoted). When the rent
+reaches the price the shape is bought: the program's underlying
+`jax.jit` is lowered and compiled ahead of time for the exact signature
+on a thread of its own (span ``shape.promote``, kind ``compile``), and
+calls keep taking the pad until the executable is there: the calling
+thread never waits for a compile it did not wait for before. From then
+on the block is dispatched on the cut itself: no ``shape.pad``, no
+``shape.unpad``, ``bucket == n``, ``shape_bucketing.pad_rows`` counts 0
+and ``shape_bucketing.promoted_dispatch`` 1 (beside ``promotions``,
+shapes bought, and ``promotion_failed``: a compile that raised leaves
+the shape on its pad for good). For any sequence of block sizes the
+seconds spent compiling promotions never exceed the seconds already
+lost to pads, so sizes that drift (none repeats: a call's rent each)
+and small blocks (microseconds of pad against a compile) compile
+exactly what the ladder compiles. The measured price may be a fetch
+from the compile cache while the exact compile is cold; that is why
+the compile may not run on the calling thread, and why nothing else in
+the rule needs the price to be right. The promoted trace reads
+``config`` (matmul precision) as any new rung's compile would: when it
+runs. Windows, blocks on their rung, numpy or sharded columns,
+unbucketed callers and the routes that call `pad_feeds` themselves are
+untouched; no knob decides any of it.
+
 Exactness: map outputs, min/max, and integer-dtype reductions are
 bit-identical to unbucketed eager execution. Float sum/mean reduce over
 a wider (padded) axis, so XLA's vectorized accumulation may group the
@@ -69,6 +114,8 @@ order matters more than bounded compiles.
 from __future__ import annotations
 
 import functools
+import math
+import threading
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -78,6 +125,8 @@ import numpy as np
 from .aggregate import _chunk_combiners, _rowwise_transform
 from .graph.ir import Graph, base_name as _base
 from .ops.lowering import build_callable
+from .runtime import costmodel as _cm
+from .runtime import executor as _ex
 from .utils import telemetry as _tele
 from .utils.profiling import count as _count
 
@@ -92,6 +141,9 @@ __all__ = [
     "slice_pad_rows",
     "block_feeds",
     "unpad_block",
+    "BlockDispatch",
+    "block_dispatch",
+    "drain",
     "rowwise_fetches",
     "MaskPlan",
     "masked_reduce_plan",
@@ -295,20 +347,18 @@ def block_unpad(shift: int, n: int, *outs):
 _INT32_MAX = int(np.iinfo(np.int32).max)
 
 
-def _window_rows(columns: Sequence) -> int:
-    """Rows of the feed columns if a window can be taken of them (every
-    one a `jax.Array`, all on the same single device, few enough rows
-    for an int32 start index), else 0."""
-    device = None
+def _resident_device(columns: Sequence):
+    """The one device every feed column lives on, each a `jax.Array`;
+    else None (a numpy column, a sharded one, columns apart)."""
+    devices = None
     for c in columns:
         if not isinstance(c, jax.Array):
-            return 0
-        devices = c.sharding.device_set
-        if len(devices) != 1 or (device is not None and devices != device):
-            return 0
-        device = devices
-    rows = min((c.shape[0] for c in columns), default=0)
-    return rows if rows <= _INT32_MAX else 0
+            return None
+        held = c.sharding.device_set
+        if len(held) != 1 or (devices is not None and held != devices):
+            return None
+        devices = held
+    return next(iter(devices)) if devices else None
 
 
 def block_feeds(
@@ -322,20 +372,10 @@ def block_feeds(
     off its rung, the feeds are that window and the valid rows are
     ``[shift, shift + n)`` of it. Otherwise the caller's ``cut()`` (its
     ``values[lo:hi]`` per feed) is padded by `pad_feeds` and ``shift``
-    is None. `unpad_block` takes the same triple back."""
-    n = hi - lo
-    b = bucket_for(n)
-    if b == n or _window_rows(columns) < b:
-        feeds, b = pad_feeds(cut(), n)
-        return feeds, b, None
-    observe_fill(n, b)
-    _count("shape_bucketing.window_dispatch")
-    # rows computed beyond the real ones, as `pad_feeds` counts them
-    _count("shape_bucketing.pad_rows", b - n)
-    start = min(lo, columns[0].shape[0] - b)
-    with _tele.span("shape.pad", rows=n, bucket=b):
-        feeds = block_window(b, np.int32(start), *columns)
-    return list(feeds), b, lo - start
+    is None. `unpad_block` takes the same triple back. `block_dispatch`
+    with no program to promote."""
+    d = block_dispatch(None, columns, lo, hi, cut)
+    return d.feeds, d.bucket, d.shift
 
 
 def unpad_block(
@@ -361,6 +401,252 @@ def unpad_block(
         for i, o in zip(padded, cut):
             outs[i] = o
         return outs
+
+
+# ---------------------------------------------------------------------------
+# promotion: a repeated replicated pad buys its exact-shape executable
+# ---------------------------------------------------------------------------
+
+
+class BlockDispatch:
+    """One bucketed map dispatch as `block_dispatch` planned it: the
+    ``feeds`` (``bucket`` rows each), what to ``call`` on them, and
+    `unpad` for its outputs."""
+
+    __slots__ = ("feeds", "bucket", "shift", "call", "rows", "book", "line")
+
+    def __init__(self, feeds, bucket, shift, call, rows, book=None,
+                 line=None):
+        self.feeds = feeds
+        self.bucket = bucket
+        self.shift = shift
+        self.call = call
+        self.rows = rows
+        self.book = book  # a replicated pad of a program that keeps a
+        self.line = line  # ledger: the ledger, and the line the rent goes on
+
+    def unpad(self, outs: Sequence) -> List:
+        """The valid rows of the dispatch's outputs (`unpad_block`). A
+        replicated pad that came this far pays its rent first."""
+        if self.line is not None:
+            self.line.pay(self.book, self.rows, self.bucket, self.feeds, outs)
+        return unpad_block(outs, self.rows, self.bucket, self.shift)
+
+
+def block_dispatch(
+    program: Optional[Callable],
+    columns: Sequence,
+    lo: int,
+    hi: int,
+    cut: Callable[[], List],
+    device=None,
+) -> BlockDispatch:
+    """`block_feeds` for a dispatch of the executor's cached ``program``
+    on ``device`` (None: where the columns live), with promotion (module
+    docstring): a block that would take the replicated pad of resident
+    columns is looked up in the program's ledger by its exact feed
+    signature. Once the signature's rent has bought its executable the
+    dispatch is that executable on the ``cut()`` itself (``bucket ==
+    n``, nothing to unpad); until then it is the pad, and `unpad`
+    charges the rent."""
+    n = hi - lo
+    b = bucket_for(n)
+    resident = None if b == n else _resident_device(columns)
+    # a window: a rung's rows in every column, and few enough for an
+    # int32 start index
+    if resident is not None and (
+        b <= min(c.shape[0] for c in columns) <= _INT32_MAX
+    ):
+        observe_fill(n, b)
+        _count("shape_bucketing.window_dispatch")
+        # rows computed beyond the real ones, as `pad_feeds` counts them
+        _count("shape_bucketing.pad_rows", b - n)
+        start = min(lo, columns[0].shape[0] - b)
+        with _tele.span("shape.pad", rows=n, bucket=b):
+            feeds = block_window(b, np.int32(start), *columns)
+        return BlockDispatch(list(feeds), b, lo - start, program, n)
+    book = line = None
+    if resident is not None:
+        book = _program_ledger(program)
+    if book is not None:
+        line = _line(book, columns, n, resident if device is None else device)
+        if line.exact is not None:
+            observe_fill(n, n)
+            _count("shape_bucketing.promoted_dispatch")
+            exact_dispatch()
+            # handed out as the executor hands its programs out
+            call = _ex.hand_out(line.exact, book.key)
+            return BlockDispatch(cut(), n, None, call, n)
+    feeds, b = pad_feeds(cut(), n)
+    return BlockDispatch(feeds, b, None, program, n, book, line)
+
+
+_ledger_lock = threading.Lock()
+
+
+def _row_bytes(arrays: Sequence) -> int:
+    """Bytes of one row (a step along the lead dim) of all of them."""
+    return sum(
+        math.prod(a.shape[1:]) * np.dtype(a.dtype).itemsize for a in arrays
+    )
+
+
+def _compile_exact(jitted, avals: Sequence, device):
+    """``jitted`` (a `jax.jit`) lowered and compiled ahead of time for
+    feeds of ``avals`` (``(shape, dtype)`` each) on ``device``."""
+    sharding = jax.sharding.SingleDeviceSharding(device)
+    return jitted.lower(*[
+        jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+        for shape, dtype in avals
+    ]).compile()
+
+
+def _program_ledger(program):
+    """The `ProgramLedger` of the executor's cache entry behind
+    ``program`` (itself, or under a fault injector's wrapper). None for
+    the native host's programs, plain callables, and no program."""
+    while program is not None and not hasattr(program, "ledger"):
+        program = getattr(program, "__wrapped__", None)
+    return None if program is None else program.ledger
+
+
+def _line(book, columns: Sequence, n: int, device) -> "_Line":
+    """The line of the ledger ``book`` for ``n`` rows of ``columns`` on
+    ``device``, made at first sight. A ledger holds as many lines as
+    the executor's cache holds programs
+    (``config.executor_cache_entries``), least recently seen out first,
+    so sizes that never come back cost no memory either."""
+    avals = tuple(
+        ((n,) + tuple(c.shape[1:]), np.dtype(c.dtype)) for c in columns
+    )
+    sig = (avals, device)
+    with _ledger_lock:
+        line = book.shapes.get(sig)
+        if line is not None:
+            book.shapes.move_to_end(sig)
+            return line
+        from . import config as _config
+
+        line = book.shapes[sig] = _Line(n, avals, device)
+        limit = max(1, int(_config.get().executor_cache_entries))
+        while len(book.shapes) > limit:
+            book.shapes.popitem(last=False)
+    return line
+
+
+class _Line:
+    """One line of a cached program's ledger (`ProgramLedger.shapes`,
+    on the executor's cache entry): an exact feed signature,
+    the seconds its replicated pads have cost so far (``rent``), and
+    what they bought (``exact``, once ``thread`` has compiled it).
+    ``closed`` lines are never bought: the compile failed, or the
+    device has no known bandwidth to price a pad with."""
+
+    __slots__ = ("rows", "avals", "device", "rent", "exact", "thread",
+                 "closed")
+
+    def __init__(self, rows, avals, device):
+        self.rows = rows
+        self.avals = avals
+        self.device = device
+        self.rent = 0.0
+        self.exact = None
+        self.thread = None
+        self.closed = False
+
+    def pay(self, book, n: int, bucket: int, feeds: Sequence,
+            outs: Sequence) -> None:
+        """Charge one padded dispatch: the seconds its pad cost beyond
+        an exact dispatch, from shapes alone (module docstring), over
+        the device's HBM bandwidth. When the rent so far reaches the
+        price (the program's last XLA compile, ``book.compile_seconds``),
+        buy: start the compile, off this thread."""
+        if self.closed or self.thread is not None:
+            return
+        bandwidth = _cm.device_peaks(self.device)["hbm_bytes_s"]
+        if not bandwidth:
+            self.closed = True
+            _count("shape_bucketing.promotion_unpriced")
+            return
+        fed = _row_bytes(feeds)
+        out = _row_bytes(
+            [o for o in outs if getattr(o, "ndim", 0) and o.shape[0] == bucket]
+        )
+        moved = (
+            (n + bucket) * fed  # the pad copy: read n rows, write bucket
+            + (bucket - n) * (fed + out)  # the pad rows through the program
+            + 2 * n * out  # the slice: read and write n rows
+        )
+        price = book.compile_seconds
+        with _ledger_lock:
+            self.rent += moved / bandwidth
+            if price is None or self.rent < price or self.thread is not None:
+                return
+            self.thread = threading.Thread(
+                target=self._buy,
+                args=(book.jitted, book.key, price),
+                name="tfs-promote",
+            )
+        self.thread.start()
+
+    def _buy(self, jitted, key: Tuple, price: float) -> None:
+        """Lower and compile the program's own `jax.jit` for the exact
+        signature, ahead of time: the same function, so the same XLA
+        module name. A thread of its own; not a daemon, so an
+        interpreter that exits waits for the compiler instead of
+        tearing the backend down under it."""
+        try:
+            with _tele.span(
+                "shape.promote", kind="compile", program=str(key[1]),
+                rows=self.rows, rent=self.rent, price=price,
+            ):
+                compiled = _compile_exact(jitted, self.avals, self.device)
+        except Exception as e:
+            self.closed = True
+            _count("shape_bucketing.promotion_failed")
+            from .utils.log import get_logger
+
+            get_logger("shape_policy").warning(
+                "promotion of program %s/%s to %d rows failed, the shape "
+                "stays on its padded rung: %s: %s",
+                key[0], str(key[1])[:12], self.rows, type(e).__name__, e,
+            )
+            return
+        devices = {self.device}
+
+        def exact(*feeds):
+            # feeds re-placed since the plan (a scheduler's failover)
+            # are not what this was compiled for: the jit itself
+            if any(f.sharding.device_set != devices for f in feeds):
+                return jitted(*feeds)
+            out = compiled(*feeds)
+            _cm.note_exec(key, feeds, out)  # as `_instrument` counts one
+            return out
+
+        self.exact = exact
+        _count("shape_bucketing.promotions")
+
+
+def drain(executor=None, timeout: Optional[float] = None) -> bool:
+    """Join the promotion compiles in flight on ``executor``'s programs
+    (default: the default executor), each for at most ``timeout``
+    seconds. True when none is still running. The teardown of the
+    threads this module starts: for a test, so that no compile outlives
+    it, or a benchmark that wants the next dispatch to be the promoted
+    one; nothing in the package waits."""
+    ex = executor or _ex.default_executor()
+    programs = ex.programs() if hasattr(ex, "programs") else []
+    with _ledger_lock:
+        threads = [
+            line.thread
+            for book in map(_program_ledger, programs)
+            if book is not None
+            for line in book.shapes.values()
+            if line.thread is not None
+        ]
+    for t in threads:
+        t.join(timeout)
+    return not any(t.is_alive() for t in threads)
 
 
 # ---------------------------------------------------------------------------

@@ -1195,6 +1195,19 @@ def diagnostics_data(executor=None) -> Dict:
                 counters.get("shape_bucketing.window_dispatch", 0)
             ),
             "pad_rows": int(counters.get("shape_bucketing.pad_rows", 0)),
+            # promotion (`shape_policy`): dispatches served by an
+            # exact-shape executable a repeated pad bought, shapes
+            # bought, compiles that failed, shapes on a device with no
+            # known bandwidth to price a pad with
+            **{
+                name: int(counters.get("shape_bucketing." + counter, 0))
+                for name, counter in (
+                    ("promoted_dispatches", "promoted_dispatch"),
+                    ("promotions", "promotions"),
+                    ("promotions_failed", "promotion_failed"),
+                    ("promotions_unpriced", "promotion_unpriced"),
+                )
+            },
             "fill": {
                 v: {
                     "mean": f["sum"] / f["count"],
@@ -1478,7 +1491,10 @@ def _render_diagnostics(data: Dict) -> str:
             f"dispatch(es), {bk.get('window_dispatches', 0)} window "
             f"dispatch(es), {bk.get('pad_rows', 0)} pad row(s) "
             "(rows computed beyond the real ones, paid for the bounded "
-            "compile count)"
+            f"compile count); {bk.get('promoted_dispatches', 0)} promoted "
+            f"dispatch(es) on {bk.get('promotions', 0)} exact shape(s) "
+            f"bought ({bk.get('promotions_failed', 0)} failed, "
+            f"{bk.get('promotions_unpriced', 0)} unpriced)"
         )
         for verb, f in bk.get("fill", {}).items():
             lines.append(

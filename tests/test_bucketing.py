@@ -328,6 +328,323 @@ class TestBlockWindow:
             assert sp.block_unpad._cache_size() == u1
 
 
+# ---------------------------------------------------------------------------
+# promotion (ISSUE 29): a repeated replicated pad buys its exact shape
+# ---------------------------------------------------------------------------
+
+# bytes one padded call of `_promo_frame()` moves beyond an exact one:
+# the pad copy, the pad rows through the program, the slice. As the
+# bandwidth it makes a call's rent 1.0 s, so a price reads in calls.
+_PROMO_ROWS = 1000
+_PROMO_CALL_BYTES = (1000 + 1024) * 4 + 24 * 8 + 2 * 1000 * 4
+
+
+class _Promo:
+    """One executor under an injected bandwidth and price: `call` runs
+    the verb, holds the output to unbucketed execution bit for bit, and
+    waits for a promotion it may have started. The price: the
+    executor's clock steps by ``price`` seconds a reading, so that is
+    what every compile it times has taken."""
+
+    def __init__(self, monkeypatch, price, bandwidth=_PROMO_CALL_BYTES):
+        import types
+
+        from tensorframes_tpu.runtime import costmodel, executor
+
+        self.monkeypatch = monkeypatch
+        self.ex = Executor()
+        self.price, self._now = float(price), 0.0
+        monkeypatch.setattr(
+            executor, "time", types.SimpleNamespace(perf_counter=self._tick)
+        )
+        if bandwidth is not None:
+            monkeypatch.setitem(
+                costmodel.DEVICE_PEAKS, "cpu", {"hbm_bytes_s": bandwidth}
+            )
+
+    def _tick(self):
+        self._now += self.price
+        return self._now
+
+    def frame(self, rows=_PROMO_ROWS, sizes=None, **more):
+        return _resident({"x": _ints(rows), **more}, sizes or [rows])
+
+    def call(self, df, verb="map_blocks", fetch_of=_times_two, wait=True):
+        run, fetch = getattr(tfs, verb), fetch_of(df)
+        with tfs.config.override(shape_bucketing=False):
+            want = np.asarray(run(fetch, df)["z"].values)
+        got = run(fetch, df, executor=self.ex)["z"].values
+        np.testing.assert_array_equal(np.asarray(got), want)
+        if wait:
+            assert sp.drain(self.ex, timeout=60)
+
+    def counters(self):
+        from tensorframes_tpu.utils import telemetry as tele
+
+        c = tele.flat_counters()
+        return {
+            k: int(c.get("shape_bucketing." + k, 0))
+            for k in ("padded_dispatch", "window_dispatch", "pad_rows",
+                      "promoted_dispatch", "promotions", "promotion_failed",
+                      "promotion_unpriced")
+        }
+
+    def lines(self):
+        return [
+            line for entry in self.ex.programs()
+            for line in entry.ledger.shapes.values()
+        ]
+
+
+def _promo_repeated(p):
+    """Padded until the rent (1.0 a call) reaches the price (3.0), then
+    the exact executable: no pad row, no new jit specialization."""
+    df = p.frame()
+    for _ in range(3):
+        p.call(df)
+    c = p.counters()
+    assert (c["padded_dispatch"], c["promoted_dispatch"]) == (3, 0)
+    assert c["promotions"] == 1 and c["pad_rows"] == 3 * 24
+    for _ in range(3):
+        p.call(df)
+    c = p.counters()
+    assert (c["padded_dispatch"], c["promoted_dispatch"]) == (3, 3)
+    assert c["promotions"] == 1 and c["pad_rows"] == 3 * 24
+    assert p.ex.jit_shape_compiles() == 1  # the rung; the bought one is apart
+    assert [line.rent for line in p.lines()] == [3.0]
+
+
+def _promo_map_rows(p):
+    # three times the row bytes: a call's rent is the price
+    df = _resident({"x": _ints(_PROMO_ROWS, width=3)}, [_PROMO_ROWS])
+    p.call(df, "map_rows", _rows_times_two)
+    p.call(df, "map_rows", _rows_times_two)
+    c = p.counters()
+    assert (c["padded_dispatch"], c["promoted_dispatch"]) == (1, 1)
+
+
+def _promo_two_columns(p):
+    # two feeds, one output: (2024 * 8 + 24 * 12 + 2000 * 4) bytes a call
+    df = p.frame(y=_ints(_PROMO_ROWS, mod=7))
+    rent = (2024 * 8 + 24 * 12 + 2000 * 4) / _PROMO_CALL_BYTES
+    assert 1.5 < rent < 3.0
+    p.call(df, fetch_of=_two_columns)
+    assert p.counters()["promotions"] == 0
+    assert p.lines()[0].rent == pytest.approx(rent)
+    p.call(df, fetch_of=_two_columns)
+    p.call(df, fetch_of=_two_columns)
+    c = p.counters()
+    assert (c["padded_dispatch"], c["promoted_dispatch"]) == (2, 1)
+
+
+def _promo_sizes_never_repeat(p):
+    """Drift: every size seen once pays one call's rent and buys
+    nothing; the compiles stay on the ladder."""
+    for rows in range(990, 1010):
+        p.call(p.frame(rows))
+    c = p.counters()
+    assert c["padded_dispatch"] == 20 and c["promotions"] == 0
+    assert c["promoted_dispatch"] == 0
+    assert p.ex.jit_shape_compiles() == 1
+    assert all(line.thread is None for line in p.lines())
+
+
+def _promo_rent_under_price(p):
+    """A small block that repeats: its pad costs far less than a
+    compile, so it stays on its rung."""
+    p.price = 1e6
+    df = p.frame()
+    for _ in range(10):
+        p.call(df)
+    c = p.counters()
+    assert (c["padded_dispatch"], c["promotions"]) == (10, 0)
+    assert p.lines()[0].rent == pytest.approx(10.0)
+
+
+def _promo_multi_block_keeps_window(p):
+    p.price = 0.0
+    df = p.frame(100, sizes=[10] * 10)
+    for _ in range(3):
+        p.call(df)
+    c = p.counters()
+    assert c["window_dispatch"] == 30 and c["padded_dispatch"] == 0
+    assert c["promoted_dispatch"] == 0 and not p.lines()
+
+
+def _promo_numpy_column_keeps_pad(p):
+    p.price = 0.0
+    df = tfs.TensorFrame.from_dict({"x": _ints(_PROMO_ROWS)})
+    for _ in range(3):
+        p.call(df)
+    c = p.counters()
+    assert c["padded_dispatch"] == 3 and not p.lines()
+
+
+def _promo_compile_fails(p):
+    def refuse(jitted, avals, device):
+        raise RuntimeError("no such shape today")
+
+    p.monkeypatch.setattr(sp, "_compile_exact", refuse)
+    df = p.frame()
+    for _ in range(5):
+        p.call(df)
+    c = p.counters()
+    assert c["promotion_failed"] == 1 and c["promotions"] == 0
+    assert (c["padded_dispatch"], c["promoted_dispatch"]) == (5, 0)
+
+
+def _promo_unknown_device_kind(p):
+    p.price = 0.0
+    df = p.frame()
+    for _ in range(4):
+        p.call(df)
+    c = p.counters()
+    assert c["promotion_unpriced"] == 1 and c["promotions"] == 0
+    assert (c["padded_dispatch"], c["promoted_dispatch"]) == (4, 0)
+
+
+def _promo_unpriced_program(p):
+    """No compile of the program was timed: nothing to weigh a rent
+    against, so nothing is bought."""
+    p.price = 1e6
+    df = p.frame()
+    p.call(df)
+    for entry in p.ex.programs():
+        entry.ledger.compile_seconds = None
+    for _ in range(4):
+        p.call(df)
+    c = p.counters()
+    assert (c["padded_dispatch"], c["promotions"]) == (5, 0)
+
+
+def _promo_pending_compile_never_blocks(p):
+    """The calling thread never compiles for a promotion: while the
+    compile is held, calls return on the pad."""
+    import threading
+
+    release, compile_exact = threading.Event(), sp._compile_exact
+    callers = set()
+
+    def held(jitted, avals, device):
+        callers.add(threading.get_ident())
+        assert release.wait(60)
+        return compile_exact(jitted, avals, device)
+
+    p.monkeypatch.setattr(sp, "_compile_exact", held)
+    df = p.frame()
+    try:
+        for _ in range(6):
+            p.call(df, wait=False)
+        c = p.counters()
+        assert (c["padded_dispatch"], c["promoted_dispatch"]) == (6, 0)
+        assert not sp.drain(p.ex, timeout=0.01)
+    finally:
+        release.set()
+    assert sp.drain(p.ex, timeout=60)
+    assert callers and threading.get_ident() not in callers
+    p.call(df)
+    c = p.counters()
+    assert (c["padded_dispatch"], c["promoted_dispatch"]) == (6, 1)
+    assert c["promotions"] == 1
+
+
+def _promo_dropped_with_the_cache_entry(p):
+    import gc
+    import weakref
+
+    p.price = 1.0
+    df = p.frame()
+    p.call(df)
+    p.call(df)
+    assert p.counters()["promoted_dispatch"] == 1
+    bought = weakref.ref(p.lines()[0].exact)
+    p.ex.clear()
+    gc.collect()  # the last call's `_dispatch_rows` cycle held what it called
+    assert bought() is None and not p.lines()
+    p.call(df)  # a new entry: its ledger starts empty
+    c = p.counters()
+    assert (c["padded_dispatch"], c["promoted_dispatch"]) == (2, 1)
+    p.call(df)
+    assert p.counters()["promoted_dispatch"] == 2
+    # evicted by another program under a one-entry cache: the same
+    with tfs.config.override(executor_cache_entries=1):
+        p.call(df, fetch_of=lambda d: (tfs.block(d, "x") + 1.0).named("z"))
+        assert len(p.ex.programs()) == 1
+        p.call(df)
+    c = p.counters()
+    assert (c["padded_dispatch"], c["promoted_dispatch"]) == (4, 2)
+
+
+def _promo_ledger_bounded(p):
+    p.price = 1e6
+    with tfs.config.override(executor_cache_entries=2):
+        for rows in (1000, 1001, 1002, 1001, 1003):
+            p.call(p.frame(rows))
+    assert sorted(line.rows for line in p.lines()) == [1001, 1003]
+
+
+def _promo_oom_split_recurses(p):
+    """A promoted dispatch that runs out of memory splits like any
+    other: its halves are windows of the same column."""
+    from tensorframes_tpu.testing import faults as chaos
+
+    p.price = 1.0
+    df = p.frame()
+    p.call(df)
+    p.call(df)
+    assert p.counters()["promoted_dispatch"] == 1
+    with chaos.inject(nth=[1], fault="resource"):  # [0]: the reference
+        p.call(df)
+    c = p.counters()
+    assert c["promoted_dispatch"] == 2 and c["window_dispatch"] == 2
+    assert c["pad_rows"] == 24 + 2 * 12
+
+
+def _promo_failover_leaves_the_device(p):
+    """A transient fault re-places the block: the executable bought for
+    the first device hands the feeds to the program itself."""
+    from tensorframes_tpu.testing import faults as chaos
+
+    p.price = 1.0
+    df = p.frame()
+    p.call(df)
+    p.call(df)
+    assert p.ex.jit_shape_compiles() == 1
+    with chaos.inject(nth=[1], fault="transient"):
+        p.call(df)
+    assert p.counters()["promoted_dispatch"] == 2
+    assert p.ex.jit_shape_compiles() == 2  # the exact shape, elsewhere
+
+
+_PROMOTION_CASES = {
+    "repeated-one-block": _promo_repeated,
+    "map_rows-2d-column": _promo_map_rows,
+    "two-feed-columns": _promo_two_columns,
+    "sizes-never-repeat": _promo_sizes_never_repeat,
+    "rent-under-price": _promo_rent_under_price,
+    "multi-block-keeps-window": _promo_multi_block_keeps_window,
+    "numpy-column-keeps-pad": _promo_numpy_column_keeps_pad,
+    "compile-fails": _promo_compile_fails,
+    "unpriced-program": _promo_unpriced_program,
+    "pending-compile-never-blocks": _promo_pending_compile_never_blocks,
+    "dropped-with-the-cache-entry": _promo_dropped_with_the_cache_entry,
+    "ledger-bounded": _promo_ledger_bounded,
+    "oom-split-recurses": _promo_oom_split_recurses,
+    "failover-leaves-the-device": _promo_failover_leaves_the_device,
+}
+
+
+class TestPromotion:
+    @pytest.mark.parametrize("case", sorted(_PROMOTION_CASES))
+    def test_rent_buys_the_exact_shape(self, case, monkeypatch):
+        _PROMOTION_CASES[case](_Promo(monkeypatch, price=3.0))
+
+    def test_unknown_device_kind_is_never_promoted(self, monkeypatch):
+        _promo_unknown_device_kind(
+            _Promo(monkeypatch, price=3.0, bandwidth=None)
+        )
+
+
 class TestBucketedReduce:
     @pytest.mark.parametrize("op", ["sum", "min", "max", "mean"])
     def test_reduce_matches_unbucketed(self, op):

@@ -745,6 +745,94 @@ class TestSpansBelowTheVerb:
         assert "n=1" in line and "total=" in line and "self=" in line
 
 
+# ---------------------------------------------------------------------------
+# promotion (ISSUE 29): what a bought exact shape shows
+# ---------------------------------------------------------------------------
+
+
+def _promoted(monkeypatch, verb, ex):
+    """A resident one-block frame of 40 rows (rung 64) whose first call
+    pays a rent far over any compile's price (a bandwidth of one byte a
+    second) and so buys the exact shape: ``(call, compiled)``, the next
+    call of the verb and the executable the promotion compiled."""
+    import jax
+
+    from tensorframes_tpu import shape_policy as sp
+    from tensorframes_tpu.runtime import costmodel
+
+    monkeypatch.setitem(costmodel.DEVICE_PEAKS, "cpu", {"hbm_bytes_s": 1.0})
+    bought, compile_exact = [], sp._compile_exact
+
+    def spy(*args):
+        bought.append(compile_exact(*args))
+        return bought[-1]
+
+    monkeypatch.setattr(sp, "_compile_exact", spy)
+    x = np.arange(40, dtype=np.float32)
+    df = tfs.TensorFrame([tfs.Column("x", jax.device_put(x))], [0, 40])
+    ph = (tfs.block if verb == "map_blocks" else tfs.row)(df, "x")
+    fetch = (ph * 2.0).named("z")
+
+    def call():
+        out = getattr(tfs, verb)(fetch, df, executor=ex)
+        np.testing.assert_array_equal(np.asarray(out["z"].values), 2.0 * x)
+
+    call()
+    assert sp.drain(ex, timeout=60)
+    (compiled,) = bought
+    return call, compiled
+
+
+@pytest.mark.parametrize("verb", ["map_blocks", "map_rows"])
+def test_span_tree_of_a_promoted_call(verb, monkeypatch):
+    call, _ = _promoted(monkeypatch, verb, tfs.Executor())
+    # the compile ran on a thread of its own, outside any verb span
+    (promote,) = [s for s in tele.spans() if s.name == "shape.promote"]
+    assert promote.kind == "compile" and promote.parent_id is None
+    (verb_span,) = [s for s in tele.spans() if s.kind == "verb"]
+    assert promote.thread != verb_span.thread
+    assert promote.attrs["rows"] == 40 and promote.attrs["program"]
+    assert promote.attrs["rent"] >= promote.attrs["price"] > 0
+    tele.reset()
+    call()
+    ss = tele.spans()
+    names = {s.name for s in ss}
+    assert not names & {"shape.pad", "shape.unpad", "frame.cut", "shape.promote"}
+    (block,) = [s for s in ss if s.name == f"{verb}.block"]
+    assert block.attrs["bucket"] == block.attrs["rows"] == 40
+    c = tele.flat_counters()
+    assert c["shape_bucketing.promoted_dispatch"] == 1
+    assert c["shape_bucketing.pad_rows"] == 0
+    assert "shape_bucketing.padded_dispatch" not in c
+
+
+def test_diagnostics_bucketing_line_carries_the_promotion_counters(monkeypatch):
+    call, _ = _promoted(monkeypatch, "map_blocks", tfs.Executor())
+    call()
+    tele.counter_inc("shape_bucketing.promotion_failed", 2)
+    tele.counter_inc("shape_bucketing.promotion_unpriced", 3)
+    bk = tfs.diagnostics(format="json")["bucketing"]
+    assert (bk["padded_dispatches"], bk["promoted_dispatches"]) == (1, 1)
+    assert (bk["promotions"], bk["promotions_failed"]) == (1, 2)
+    assert bk["promotions_unpriced"] == 3
+    (line,) = [l for l in tfs.diagnostics().splitlines()
+               if l.startswith("bucketing:")]
+    assert "1 padded dispatch(es)" in line
+    assert "1 promoted dispatch(es) on 1 exact shape(s) bought" in line
+    assert "(2 failed, 3 unpriced)" in line
+
+
+@pytest.mark.parametrize("verb", ["map_blocks", "map_rows"])
+def test_promoted_executable_keeps_the_module_name(verb, monkeypatch):
+    """A promoted shape is the same `jax.jit` compiled ahead of time:
+    in a device trace it is still `jit_fn`, so `program_roofline` and
+    `copy_device_pct` read it as the verb's program."""
+    import re
+
+    _, compiled = _promoted(monkeypatch, verb, tfs.Executor())
+    assert re.search(r"HloModule (\w+)", compiled.as_text()).group(1) == "jit_fn"
+
+
 @pytest.mark.parametrize("program", ["callable_for", "vmap-rows"])
 def test_verb_programs_lower_to_a_module_named_jit_fn(program):
     """The benchmark finds the verb's own program in the device trace by

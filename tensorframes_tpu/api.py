@@ -812,8 +812,9 @@ def _run_blocks(
     per name of ``out_names``, or a dict (a plain function: the names
     are its keys). ``bucketed`` pads the column feeds up the bucket
     ladder, or takes a window of resident columns, and takes the pad
-    rows off again (`shape_policy.block_dispatch`, which may hand back
-    an exact-shape executable of ``fn`` in its place); the caller sets
+    rows off again (`shape_policy.block_dispatch`, which may run the
+    block at its exact shape instead, on ``fn`` or on an executable of
+    it); the caller sets
     it only for programs it knows row-local. ``rowwise``
     lets a RESOURCE fault split the block's rows in half, to
     ``config.oom_split_depth``. ``empty()`` names and shapes the
@@ -842,8 +843,9 @@ def _run_blocks(
 
         if bucketed:
             # a window of the resident columns where they have one, else
-            # the cut, padded, or on the exact-shape executable a
-            # repeated pad has bought (`shape_policy.block_dispatch`)
+            # the cut: at its exact shape as its rung's first size or on
+            # the executable a repeated pad has bought, else padded
+            # (`shape_policy.block_dispatch`)
             blk = _sp.block_dispatch(
                 fn, col_values, lo_, hi_, _cut,
                 sched.device(bi) if sched is not None else None,

@@ -101,20 +101,25 @@ class FnProgram:
 
 
 class ProgramLedger:
-    """What `shape_policy`'s promotion rule reads and keeps with one
+    """What `shape_policy`'s promotion rules read and keep with one
     cached program (``entry.ledger``, `Executor._instrument`): the
     cache ``key``; the program's own ``jitted`` function; ``compile_
     seconds``, what its last XLA compile took (None until one was
     seen), timed whatever the telemetry and cost-ledger switches say,
     so that turning them off does not change what the program does;
-    and ``shapes``, the policy's own state per exact feed signature."""
+    and the policy's own state: ``rungs``, per rung, trailing shapes
+    and device the first row count asked for (it runs at its exact
+    shape) and whether another has come since, and ``shapes``, per
+    exact feed signature of those others the rent its pads have paid
+    and what it bought."""
 
-    __slots__ = ("key", "jitted", "compile_seconds", "shapes")
+    __slots__ = ("key", "jitted", "compile_seconds", "rungs", "shapes")
 
     def __init__(self, key: Tuple, jitted: Callable):
         self.key = key
         self.jitted = jitted
         self.compile_seconds: Optional[float] = None
+        self.rungs: Dict[Tuple, List] = {}
         self.shapes: OrderedDict = OrderedDict()
 
 

@@ -52,23 +52,48 @@ same rung shape as before, and one small jitted static slice
 dispatches a block, none eager, where there were six. What decides is
 what the input shows, not a knob: a column shorter than the rung (every
 single-block frame off a rung), a host (numpy) column, a column sharded
-over devices or a block already on its rung keeps the cut and the
-replicated pad below, unchanged. The reduce, lazy, stream and mesh
+over devices or a block already on its rung keeps the cut, and off its
+rung the replicated pad (a short resident column from its rung's
+second size on: PROMOTION, below). The reduce, lazy, stream and mesh
 routes call `pad_feeds` and never take a window.
 
 PROMOTION (`block_dispatch`, the same two loops): the ladder trades pad
-work for compiles, and for a shape that keeps coming back the trade
-turns bad. A one-block frame off its rung has no window (its column is
-shorter than the rung), so every call copies the column into a padded
-one, computes up to growth times the rows and copies the valid rows out
-again, to save a compile it stopped needing after the first call. When
-a shape has earned a program of its own is the classic rent-or-buy
-question, and what the policy observes answers it. Per cached program
-and exact feed signature (row count, trailing shapes, dtypes, the
-device) a ledger line on the executor's cache entry (`ProgramLedger`,
-evicted with the entry; at most ``config.executor_cache_entries`` lines
-a program, least recently seen out first) adds up the RENT paid so far:
-for every replicated pad of device-resident feeds, the seconds the pad
+work for compiles: a padded block copies its column into a padded one,
+computes up to growth times the rows and copies the valid rows out
+again, and in return shares its rung's program with every other size
+of the rung. A one-block frame off its rung has no window (its column
+is shorter than the rung), so it is where that trade is made, and when
+a shape has earned a program of its own is what the policy observes,
+not a knob. Two rules, both kept per cached program on the executor's
+cache entry (`ProgramLedger`, evicted with the entry), both for
+device-resident feeds only:
+
+THE FIRST SIZE of a rung is bought at price zero. Per rung, trailing
+shapes, dtypes and device the ledger remembers the first row count it
+was asked for (``ProgramLedger.rungs``), and a dispatch of that size
+runs the program on the cut itself: ``bucket == n``, no ``shape.pad``,
+no ``shape.unpad``, ``shape_bucketing.pad_rows`` 0,
+``shape_bucketing.first_size_dispatch`` 1. The program's own `jax.jit`
+specialises to the exact shape on the calling thread, where and for as
+long as the rung's compile would have stood: a pad at first sight would
+save a compile that is being paid at that very moment anyway. No
+thread, no price and no bandwidth are needed, so the rule holds on a
+device kind `costmodel.DEVICE_PEAKS` does not know too. The pad buys
+something only when a second, different size arrives on the rung
+(``shape_bucketing.rungs_widened`` counts the rungs where one did): that
+size and every later one take the replicated pad, the rung's program
+compiles then, once, and the first size keeps running exact (the jit
+holds its executable). BOUND: per program at most one compile more per
+rung than the ladder alone, the first size's, so compiles stay O(log
+max-block-rows) however sizes drift; for a shape that repeats and is
+its rung's first, the pad is never paid at all. The regret is that one
+compile, and only on a rung that later sees another size.
+
+RENT OR BUY, from a rung's second size on. Per exact feed signature
+(row count, trailing shapes, dtypes, the device) a ledger line
+(``ProgramLedger.shapes``; at most ``config.executor_cache_entries``
+lines a program, least recently seen out first) adds up the RENT paid
+so far: for every replicated pad, the seconds the pad
 cost beyond an exact dispatch, from shapes alone: the bytes of the pad
 copy (read ``n``, write ``bucket`` rows of every feed), of the ``bucket
 - n`` pad rows through the program (feed and output row bytes) and of
@@ -84,22 +109,23 @@ reaches the price the shape is bought: the program's underlying
 on a thread of its own (span ``shape.promote``, kind ``compile``), and
 calls keep taking the pad until the executable is there: the calling
 thread never waits for a compile it did not wait for before. From then
-on the block is dispatched on the cut itself: no ``shape.pad``, no
-``shape.unpad``, ``bucket == n``, ``shape_bucketing.pad_rows`` counts 0
-and ``shape_bucketing.promoted_dispatch`` 1 (beside ``promotions``,
+on the block is dispatched on the cut itself, as a first size is, and
+``shape_bucketing.promoted_dispatch`` counts 1 (beside ``promotions``,
 shapes bought, and ``promotion_failed``: a compile that raised leaves
 the shape on its pad for good). For any sequence of block sizes the
 seconds spent compiling promotions never exceed the seconds already
 lost to pads, so sizes that drift (none repeats: a call's rent each)
 and small blocks (microseconds of pad against a compile) compile
-exactly what the ladder compiles. The measured price may be a fetch
-from the compile cache while the exact compile is cold; that is why
-the compile may not run on the calling thread, and why nothing else in
-the rule needs the price to be right. The promoted trace reads
-``config`` (matmul precision) as any new rung's compile would: when it
-runs. Windows, blocks on their rung, numpy or sharded columns,
-unbucketed callers and the routes that call `pad_feeds` themselves are
-untouched; no knob decides any of it.
+what the ladder compiles and each rung's first size. The measured
+price may be a fetch from the compile cache while the exact compile is
+cold; that is why the compile may not run on the calling thread, and
+why nothing else in the rule needs the price to be right. The promoted
+trace reads ``config`` (matmul precision) as any new rung's compile
+would: when it runs.
+
+Windows, blocks on their rung, numpy or sharded columns, `block_feeds`
+(no program), unbucketed callers and the routes that call `pad_feeds`
+themselves are untouched by both rules; no knob decides any of it.
 
 Exactness: map outputs, min/max, and integer-dtype reductions are
 bit-identical to unbucketed eager execution. Float sum/mean reduce over
@@ -404,7 +430,8 @@ def unpad_block(
 
 
 # ---------------------------------------------------------------------------
-# promotion: a repeated replicated pad buys its exact-shape executable
+# promotion: a rung's first size, and a pad that keeps coming back, run
+# at their exact shape
 # ---------------------------------------------------------------------------
 
 
@@ -444,11 +471,12 @@ def block_dispatch(
     """`block_feeds` for a dispatch of the executor's cached ``program``
     on ``device`` (None: where the columns live), with promotion (module
     docstring): a block that would take the replicated pad of resident
-    columns is looked up in the program's ledger by its exact feed
-    signature. Once the signature's rent has bought its executable the
-    dispatch is that executable on the ``cut()`` itself (``bucket ==
-    n``, nothing to unpad); until then it is the pad, and `unpad`
-    charges the rent."""
+    columns is looked up in the program's ledger. The first size its
+    rung was asked for, and a later one once its rent has bought its
+    executable, is dispatched on the ``cut()`` itself (``bucket == n``,
+    nothing to unpad): the first on ``program``, which compiles for it
+    as it would have for the rung, the bought one on its executable.
+    Any other is the pad, and `unpad` charges the rent."""
     n = hi - lo
     b = bucket_for(n)
     resident = None if b == n else _resident_device(columns)
@@ -469,13 +497,23 @@ def block_dispatch(
     if resident is not None:
         book = _program_ledger(program)
     if book is not None:
-        line = _line(book, columns, n, resident if device is None else device)
-        if line.exact is not None:
+        on = resident if device is None else device
+        trailing = tuple(
+            (tuple(c.shape[1:]), np.dtype(c.dtype)) for c in columns
+        )
+        call = None
+        if _first_size(book, trailing, n, b, on):
+            _count("shape_bucketing.first_size_dispatch")
+            call = program
+        else:
+            line = _line(book, trailing, n, on)
+            if line.exact is not None:
+                _count("shape_bucketing.promoted_dispatch")
+                # handed out as the executor hands its programs out
+                call = _ex.hand_out(line.exact, book.key)
+        if call is not None:
             observe_fill(n, n)
-            _count("shape_bucketing.promoted_dispatch")
             exact_dispatch()
-            # handed out as the executor hands its programs out
-            call = _ex.hand_out(line.exact, book.key)
             return BlockDispatch(cut(), n, None, call, n)
     feeds, b = pad_feeds(cut(), n)
     return BlockDispatch(feeds, b, None, program, n, book, line)
@@ -510,15 +548,31 @@ def _program_ledger(program):
     return None if program is None else program.ledger
 
 
-def _line(book, columns: Sequence, n: int, device) -> "_Line":
-    """The line of the ledger ``book`` for ``n`` rows of ``columns`` on
-    ``device``, made at first sight. A ledger holds as many lines as
-    the executor's cache holds programs
+def _first_size(book, trailing: Tuple, n: int, bucket: int, device) -> bool:
+    """Whether ``n`` rows is the first size the ledger ``book`` was
+    asked for on the rung ``bucket`` of feeds of ``trailing`` (shape
+    past the rows and dtype, each) on ``device``: recorded at first
+    sight, one entry a rung (so no more than a ladder's worth a
+    signature and device). A second size counts its rung as widened,
+    once."""
+    with _ledger_lock:
+        seen = book.rungs.setdefault((bucket, trailing, device), [n, False])
+        if seen[0] == n:
+            return True
+        newly_widened = not seen[1]
+        seen[1] = True
+    if newly_widened:
+        _count("shape_bucketing.rungs_widened")
+    return False
+
+
+def _line(book, trailing: Tuple, n: int, device) -> "_Line":
+    """The line of the ledger ``book`` for ``n`` rows of feeds of
+    ``trailing`` on ``device``, made at first sight. A ledger holds as
+    many lines as the executor's cache holds programs
     (``config.executor_cache_entries``), least recently seen out first,
     so sizes that never come back cost no memory either."""
-    avals = tuple(
-        ((n,) + tuple(c.shape[1:]), np.dtype(c.dtype)) for c in columns
-    )
+    avals = tuple(((n,) + shape, dtype) for shape, dtype in trailing)
     sig = (avals, device)
     with _ledger_lock:
         line = book.shapes.get(sig)

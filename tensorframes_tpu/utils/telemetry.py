@@ -1195,13 +1195,17 @@ def diagnostics_data(executor=None) -> Dict:
                 counters.get("shape_bucketing.window_dispatch", 0)
             ),
             "pad_rows": int(counters.get("shape_bucketing.pad_rows", 0)),
-            # promotion (`shape_policy`): dispatches served by an
-            # exact-shape executable a repeated pad bought, shapes
-            # bought, compiles that failed, shapes on a device with no
-            # known bandwidth to price a pad with
+            # promotion (`shape_policy`): dispatches that ran exact as
+            # their rung's first size, rungs a second size made compile
+            # their padded program, dispatches served by an exact-shape
+            # executable a repeated pad bought, shapes bought, compiles
+            # that failed, shapes on a device with no known bandwidth
+            # to price a pad with
             **{
                 name: int(counters.get("shape_bucketing." + counter, 0))
                 for name, counter in (
+                    ("first_size_dispatches", "first_size_dispatch"),
+                    ("rungs_widened", "rungs_widened"),
                     ("promoted_dispatches", "promoted_dispatch"),
                     ("promotions", "promotions"),
                     ("promotions_failed", "promotion_failed"),
@@ -1491,7 +1495,10 @@ def _render_diagnostics(data: Dict) -> str:
             f"dispatch(es), {bk.get('window_dispatches', 0)} window "
             f"dispatch(es), {bk.get('pad_rows', 0)} pad row(s) "
             "(rows computed beyond the real ones, paid for the bounded "
-            f"compile count); {bk.get('promoted_dispatches', 0)} promoted "
+            f"compile count); {bk.get('first_size_dispatches', 0)} "
+            "first-size dispatch(es) at their exact shape, "
+            f"{bk.get('rungs_widened', 0)} rung(s) widened by a second "
+            f"size; {bk.get('promoted_dispatches', 0)} promoted "
             f"dispatch(es) on {bk.get('promotions', 0)} exact shape(s) "
             f"bought ({bk.get('promotions_failed', 0)} failed, "
             f"{bk.get('promotions_unpriced', 0)} unpriced)"

@@ -174,59 +174,60 @@ def _rows_times_two(df):
 
 
 # case -> (verb, frame, fetch, injected fault, the (shift, n) pairs the
-# unpad program must see, window dispatches, padded dispatches); rung
-# ladder 8, 16, 32, ...
+# unpad program must see, window dispatches, padded dispatches, sizes
+# dispatched exact as their rung's first); rung ladder 8, 16, 32, ...
 _WINDOW_CASES = {
     # every window starts at its block: 3 x 10 rows in rungs of 16, the
     # last block (16 rows) on its rung and so neither windowed nor padded
     "interior-blocks": (
         "map_blocks", lambda: _resident({"x": _ints(46)}, [10, 10, 10, 16]),
-        _times_two, None, {(0, 10)}, 3, 0,
+        _times_two, None, {(0, 10)}, 3, 0, [],
     ),
     # the block at row 90 of 100 has no 16 rows after it: its window
     # starts at 84 and its rows sit 6 into it
     "last-blocks": (
         "map_blocks", lambda: _resident({"x": _ints(100)}, [10] * 10),
-        _times_two, None, {(0, 10), (6, 10)}, 10, 0,
+        _times_two, None, {(0, 10), (6, 10)}, 10, 0, [],
     ),
     "two-feed-columns": (
         "map_blocks",
         lambda: _resident({"x": _ints(60), "y": _ints(60, mod=7)}, [20] * 3),
-        _two_columns, None, {(0, 20), (12, 20)}, 3, 0,
+        _two_columns, None, {(0, 20), (12, 20)}, 3, 0, [],
     ),
     "2d-column-map_rows": (
         "map_rows", lambda: _resident({"x": _ints(60, width=3)}, [20] * 3),
-        _rows_times_two, None, {(0, 20), (12, 20)}, 3, 0,
+        _rows_times_two, None, {(0, 20), (12, 20)}, 3, 0, [],
     ),
     # 64 is on its rung; 55 ends the column, 9 rows short of its rung
     "uneven-blocks": (
         "map_blocks",
         lambda: _resident({"x": _ints(287)}, [3, 9, 17, 31, 64, 101, 7, 55]),
         _times_two, None,
-        {(0, 3), (0, 9), (0, 17), (0, 31), (0, 101), (0, 7), (9, 55)}, 7, 0,
+        {(0, 3), (0, 9), (0, 17), (0, 31), (0, 101), (0, 7), (9, 55)}, 7, 0, [],
     ),
     # block 0's first dispatch runs out of memory: its halves take
     # smaller windows of the same column (50 -> 25 + 25 rows)
     "oom-split-half": (
         "map_blocks", lambda: _resident({"x": _ints(100)}, [50, 50]),
-        _times_two, "resource", {(0, 25), (14, 50)}, 4, 0,
+        _times_two, "resource", {(0, 25), (14, 50)}, 4, 0, [],
     ),
-    # controls: nothing to take a window of
+    # controls: nothing to take a window of. A resident block shorter
+    # than its rung is the rung's first size and runs exact (ISSUE 32)
     "control-one-block-short-of-its-rung": (
         "map_blocks", lambda: _resident({"x": _ints(40)}, [40]),
-        _times_two, None, set(), 0, 1,
+        _times_two, None, set(), 0, 0, [40],
     ),
     "control-numpy-column": (
         "map_blocks",
         lambda: tfs.TensorFrame.from_dict({"x": _ints(40)}, num_blocks=4),
-        _times_two, None, set(), 0, 4,
+        _times_two, None, set(), 0, 4, [],
     ),
     "control-numpy-column-map_rows": (
         "map_rows",
         lambda: tfs.TensorFrame.from_dict(
             {"x": _ints(40, width=3)}, num_blocks=4
         ),
-        _rows_times_two, None, set(), 0, 4,
+        _rows_times_two, None, set(), 0, 4, [],
     ),
 }
 
@@ -237,7 +238,7 @@ class TestBlockWindow:
         from tensorframes_tpu.testing import faults as chaos
         from tensorframes_tpu.utils import telemetry as tele
 
-        verb, make, fetch_of, fault, slices, windows, padded = (
+        verb, make, fetch_of, fault, slices, windows, padded, first = (
             _WINDOW_CASES[case]
         )
         df = make()
@@ -252,21 +253,24 @@ class TestBlockWindow:
             return unpad(shift, n, *outs)
 
         monkeypatch.setattr(sp, "block_unpad", spy)
+        ex = Executor()  # its programs' ledgers have seen no size yet
         if fault:
             with chaos.inject(nth=[0], fault=fault):
-                got = run(fetch, df)["z"].values
+                got = run(fetch, df, executor=ex)["z"].values
         else:
-            got = run(fetch, df)["z"].values
+            got = run(fetch, df, executor=ex)["z"].values
         np.testing.assert_array_equal(np.asarray(got), want)
         assert seen == slices
         c = tele.flat_counters()
         assert c.get("shape_bucketing.window_dispatch", 0) == windows
         assert c.get("shape_bucketing.padded_dispatch", 0) == padded
-        # the same `bucket - n` a dispatch on either path; the split's
-        # failed first attempt of block 0 (50 rows) counted its window too
+        assert c.get("shape_bucketing.first_size_dispatch", 0) == len(first)
+        # the same `bucket - n` a dispatch on either path, none on a
+        # first size; the split's failed first attempt of block 0 (50
+        # rows) counted its window too
         sizes = df.block_sizes() + ([25, 25] if fault else [])
         assert c.get("shape_bucketing.pad_rows", 0) == sum(
-            sp.bucket_for(n) - n for n in sizes
+            sp.bucket_for(n) - n for n in sizes if n not in first
         )
 
     def test_block_feeds_takes_what_the_columns_show(self):
@@ -329,7 +333,8 @@ class TestBlockWindow:
 
 
 # ---------------------------------------------------------------------------
-# promotion (ISSUE 29): a repeated replicated pad buys its exact shape
+# promotion (ISSUE 29): a repeated replicated pad buys its exact shape,
+# from its rung's second size on (ISSUE 32: the first runs exact at once)
 # ---------------------------------------------------------------------------
 
 # bytes one padded call of `_promo_frame()` moves beyond an exact one:
@@ -369,6 +374,14 @@ class _Promo:
     def frame(self, rows=_PROMO_ROWS, sizes=None, **more):
         return _resident({"x": _ints(rows), **more}, sizes or [rows])
 
+    def widen(self, verb="map_blocks", fetch_of=_times_two, **more):
+        """Show rung 1024 of the verb's program a first size, 999 rows,
+        which runs exact (ISSUE 32): `frame()`'s 1,000 rows are then a
+        second size there, the one that pads and pays rent."""
+        self.call(_resident({"x": _ints(999), **more}, [999]), verb, fetch_of)
+        c = self.counters()
+        assert (c["first_size_dispatch"], c["padded_dispatch"]) == (1, 0)
+
     def call(self, df, verb="map_blocks", fetch_of=_times_two, wait=True):
         run, fetch = getattr(tfs, verb), fetch_of(df)
         with tfs.config.override(shape_bucketing=False):
@@ -385,6 +398,7 @@ class _Promo:
         return {
             k: int(c.get("shape_bucketing." + k, 0))
             for k in ("padded_dispatch", "window_dispatch", "pad_rows",
+                      "first_size_dispatch", "rungs_widened",
                       "promoted_dispatch", "promotions", "promotion_failed",
                       "promotion_unpriced")
         }
@@ -399,6 +413,7 @@ class _Promo:
 def _promo_repeated(p):
     """Padded until the rent (1.0 a call) reaches the price (3.0), then
     the exact executable: no pad row, no new jit specialization."""
+    p.widen()
     df = p.frame()
     for _ in range(3):
         p.call(df)
@@ -410,12 +425,15 @@ def _promo_repeated(p):
     c = p.counters()
     assert (c["padded_dispatch"], c["promoted_dispatch"]) == (3, 3)
     assert c["promotions"] == 1 and c["pad_rows"] == 3 * 24
-    assert p.ex.jit_shape_compiles() == 1  # the rung; the bought one is apart
+    # the rung and its first size; the bought one is apart
+    assert p.ex.jit_shape_compiles() == 2
+    assert (c["first_size_dispatch"], c["rungs_widened"]) == (1, 1)
     assert [line.rent for line in p.lines()] == [3.0]
 
 
 def _promo_map_rows(p):
     # three times the row bytes: a call's rent is the price
+    p.widen("map_rows", _rows_times_two, x=_ints(999, width=3))
     df = _resident({"x": _ints(_PROMO_ROWS, width=3)}, [_PROMO_ROWS])
     p.call(df, "map_rows", _rows_times_two)
     p.call(df, "map_rows", _rows_times_two)
@@ -425,6 +443,7 @@ def _promo_map_rows(p):
 
 def _promo_two_columns(p):
     # two feeds, one output: (2024 * 8 + 24 * 12 + 2000 * 4) bytes a call
+    p.widen(fetch_of=_two_columns, y=_ints(999, mod=7))
     df = p.frame(y=_ints(_PROMO_ROWS, mod=7))
     rent = (2024 * 8 + 24 * 12 + 2000 * 4) / _PROMO_CALL_BYTES
     assert 1.5 < rent < 3.0
@@ -438,14 +457,16 @@ def _promo_two_columns(p):
 
 
 def _promo_sizes_never_repeat(p):
-    """Drift: every size seen once pays one call's rent and buys
-    nothing; the compiles stay on the ladder."""
+    """Drift: the rung's first size runs exact, every other size seen
+    once pays one call's rent and buys nothing; the compiles stay on
+    the ladder, and the first size's one beside it."""
     for rows in range(990, 1010):
         p.call(p.frame(rows))
     c = p.counters()
-    assert c["padded_dispatch"] == 20 and c["promotions"] == 0
-    assert c["promoted_dispatch"] == 0
-    assert p.ex.jit_shape_compiles() == 1
+    assert c["padded_dispatch"] == 19 and c["promotions"] == 0
+    assert (c["first_size_dispatch"], c["promoted_dispatch"]) == (1, 0)
+    assert p.ex.jit_shape_compiles() == 2
+    assert len(p.lines()) == 19
     assert all(line.thread is None for line in p.lines())
 
 
@@ -453,6 +474,7 @@ def _promo_rent_under_price(p):
     """A small block that repeats: its pad costs far less than a
     compile, so it stays on its rung."""
     p.price = 1e6
+    p.widen()
     df = p.frame()
     for _ in range(10):
         p.call(df)
@@ -485,6 +507,7 @@ def _promo_compile_fails(p):
         raise RuntimeError("no such shape today")
 
     p.monkeypatch.setattr(sp, "_compile_exact", refuse)
+    p.widen()
     df = p.frame()
     for _ in range(5):
         p.call(df)
@@ -495,6 +518,7 @@ def _promo_compile_fails(p):
 
 def _promo_unknown_device_kind(p):
     p.price = 0.0
+    p.widen()  # needs no bandwidth: the first size has no price
     df = p.frame()
     for _ in range(4):
         p.call(df)
@@ -507,6 +531,7 @@ def _promo_unpriced_program(p):
     """No compile of the program was timed: nothing to weigh a rent
     against, so nothing is bought."""
     p.price = 1e6
+    p.widen()
     df = p.frame()
     p.call(df)
     for entry in p.ex.programs():
@@ -531,6 +556,7 @@ def _promo_pending_compile_never_blocks(p):
         return compile_exact(jitted, avals, device)
 
     p.monkeypatch.setattr(sp, "_compile_exact", held)
+    p.widen()
     df = p.frame()
     try:
         for _ in range(6):
@@ -553,6 +579,7 @@ def _promo_dropped_with_the_cache_entry(p):
     import weakref
 
     p.price = 1.0
+    p.widen()
     df = p.frame()
     p.call(df)
     p.call(df)
@@ -560,19 +587,24 @@ def _promo_dropped_with_the_cache_entry(p):
     bought = weakref.ref(p.lines()[0].exact)
     p.ex.clear()
     gc.collect()  # the last call's `_dispatch_rows` cycle held what it called
-    assert bought() is None and not p.lines()
-    p.call(df)  # a new entry: its ledger starts empty
-    c = p.counters()
-    assert (c["padded_dispatch"], c["promoted_dispatch"]) == (2, 1)
+    assert bought() is None and not p.ex.programs()
+    # a new entry: its ledger starts empty, so the size it meets first
+    # is its rung's first now, and no line is kept for it
     p.call(df)
-    assert p.counters()["promoted_dispatch"] == 2
+    p.call(df)
+    c = p.counters()
+    assert (c["first_size_dispatch"], c["padded_dispatch"]) == (3, 1)
+    assert c["promoted_dispatch"] == 1 and not p.lines()
     # evicted by another program under a one-entry cache: the same
     with tfs.config.override(executor_cache_entries=1):
         p.call(df, fetch_of=lambda d: (tfs.block(d, "x") + 1.0).named("z"))
         assert len(p.ex.programs()) == 1
+        p.call(p.frame(999))
+        p.call(df)
         p.call(df)
     c = p.counters()
-    assert (c["padded_dispatch"], c["promoted_dispatch"]) == (4, 2)
+    assert (c["first_size_dispatch"], c["padded_dispatch"]) == (5, 2)
+    assert (c["promoted_dispatch"], c["promotions"]) == (2, 2)
 
 
 def _promo_ledger_bounded(p):
@@ -589,6 +621,7 @@ def _promo_oom_split_recurses(p):
     from tensorframes_tpu.testing import faults as chaos
 
     p.price = 1.0
+    p.widen()
     df = p.frame()
     p.call(df)
     p.call(df)
@@ -606,14 +639,15 @@ def _promo_failover_leaves_the_device(p):
     from tensorframes_tpu.testing import faults as chaos
 
     p.price = 1.0
+    p.widen()
     df = p.frame()
     p.call(df)
     p.call(df)
-    assert p.ex.jit_shape_compiles() == 1
+    assert p.ex.jit_shape_compiles() == 2  # the first size, the rung
     with chaos.inject(nth=[1], fault="transient"):
         p.call(df)
     assert p.counters()["promoted_dispatch"] == 2
-    assert p.ex.jit_shape_compiles() == 2  # the exact shape, elsewhere
+    assert p.ex.jit_shape_compiles() == 3  # the exact shape, elsewhere
 
 
 _PROMOTION_CASES = {
@@ -643,6 +677,126 @@ class TestPromotion:
         _promo_unknown_device_kind(
             _Promo(monkeypatch, price=3.0, bandwidth=None)
         )
+
+
+# ---------------------------------------------------------------------------
+# the first size of a rung (ISSUE 32): exact at first sight, the pad
+# from the rung's second size on
+# ---------------------------------------------------------------------------
+
+
+def _first_runs_exact(p):
+    """A resident one-block frame off its rung never takes the pad: one
+    compile, at its exact shape, on the calling thread; nothing priced."""
+    from tensorframes_tpu.utils import telemetry as tele
+
+    df = p.frame()
+    for _ in range(3):
+        p.call(df)
+    c = p.counters()
+    assert (c["first_size_dispatch"], c["padded_dispatch"]) == (3, 0)
+    assert c["rungs_widened"] == c["promotion_unpriced"] == 0
+    # counted, as none: not a program that does not count its pad rows
+    assert tele.flat_counters()["shape_bucketing.pad_rows"] == 0
+    assert p.ex.jit_shape_compiles() == 1 and not p.lines()
+    # the bucketed calls' (the unbucketed reference's carry no bucket)
+    blocks = [s for s in tele.spans() if s.name == "map_blocks.block"
+              and s.attrs.get("bucket") is not None]
+    assert len(blocks) == 3
+    assert all(s.attrs["bucket"] == s.attrs["rows"] == 1000 for s in blocks)
+    assert not {s.name for s in tele.spans()} & {
+        "shape.pad", "shape.unpad", "shape.promote"
+    }
+
+
+def _first_second_size_widens(p):
+    """The second size on a rung is what the ladder is for: it pads and
+    compiles the rung's program, once; a third compiles nothing; the
+    first keeps its exact executable."""
+    p.call(p.frame(1000))
+    assert p.ex.jit_shape_compiles() == 1
+    p.call(p.frame(1001))
+    c = p.counters()
+    assert (c["padded_dispatch"], c["rungs_widened"]) == (1, 1)
+    assert c["pad_rows"] == 23 and p.ex.jit_shape_compiles() == 2
+    p.call(p.frame(1002))
+    p.call(p.frame(1001))
+    p.call(p.frame(1000))
+    c = p.counters()
+    assert (c["first_size_dispatch"], c["padded_dispatch"]) == (2, 3)
+    assert c["rungs_widened"] == 1 and c["pad_rows"] == 23 + 22 + 23
+    assert p.ex.jit_shape_compiles() == 2
+    assert sorted(line.rows for line in p.lines()) == [1001, 1002]
+
+
+def _first_drift_compiles_twice_a_rung(p):
+    """The bound: over one-block frames whose sizes drift, a program
+    compiles at most one shape more per rung than the ladder alone."""
+    rng = np.random.RandomState(7)
+    sizes = [int(n) for n in rng.randint(9, 3000, size=40)] + [64, 2048]
+    rungs = {sp.bucket_for(n) for n in sizes}
+    assert len(rungs) < len(set(sizes)) / 3
+    for n in sizes:
+        p.call(p.frame(n))
+    c = p.counters()
+    assert p.ex.jit_shape_compiles() <= 2 * len(rungs)
+    assert c["rungs_widened"] <= len(rungs)
+    assert c["first_size_dispatch"] + c["padded_dispatch"] == len(
+        [n for n in sizes if sp.bucket_for(n) != n]
+    )
+    n_compiles = p.ex.jit_shape_compiles()
+    for n in sizes:
+        p.call(p.frame(n))
+    assert p.ex.jit_shape_compiles() == n_compiles
+
+
+def _first_keyed_as_the_ledger(p):
+    """Trailing shapes, dtypes and the device the scheduler names key a
+    rung's first size as they key a ledger line (`_line`)."""
+    import jax
+
+    d0, d1 = jax.devices()[:2]
+    graph, fetches = dsl.build(_two_columns(p.frame(8, y=_ints(8))))
+    program = p.ex.callable_for(graph, fetches, ["x", "y"])
+    book = program.ledger
+    f32 = np.dtype(np.float32)
+
+    def dispatch(n, device=None, width=None):
+        cols = [jax.device_put(_ints(n), d0),
+                jax.device_put(_ints(n, width=width), d0)]
+        return sp.block_dispatch(program, cols, 0, n, lambda: cols, device)
+
+    assert dispatch(1000).bucket == 1000  # first on (1024, two vectors, d0)
+    assert dispatch(1000, d1).bucket == 1000  # another device: its own first
+    assert dispatch(1001, d1).bucket == 1024  # a second size there
+    assert dispatch(1001, width=3).bucket == 1001  # other trailing shapes
+    assert dispatch(1001).bucket == 1024
+    assert dispatch(1000, d0).bucket == 1000  # d0 named is d0 resident
+    assert set(book.rungs) == {
+        (1024, (((), f32), ((), f32)), d0),
+        (1024, (((), f32), ((), f32)), d1),
+        (1024, (((), f32), ((3,), f32)), d0),
+    }
+    assert {sig[1] for sig in book.shapes} == {d0, d1}
+    c = p.counters()
+    assert (c["first_size_dispatch"], c["padded_dispatch"]) == (4, 2)
+    assert c["rungs_widened"] == 2
+
+
+_FIRST_SIZE_CASES = {
+    "runs-exact-from-the-first-call": _first_runs_exact,
+    "second-size-widens-the-rung": _first_second_size_widens,
+    "drift-compiles-twice-a-rung": _first_drift_compiles_twice_a_rung,
+    "keyed-as-the-ledger": _first_keyed_as_the_ledger,
+}
+
+
+class TestFirstSize:
+    @pytest.mark.parametrize("case", sorted(_FIRST_SIZE_CASES))
+    def test_first_size_of_a_rung_runs_exact(self, case, monkeypatch):
+        # no bandwidth known, no price that rent could reach: the rule
+        # needs neither
+        _FIRST_SIZE_CASES[case](_Promo(monkeypatch, price=1e9, bandwidth=None))
 
 
 class TestBucketedReduce:

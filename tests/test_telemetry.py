@@ -751,10 +751,12 @@ class TestSpansBelowTheVerb:
 
 
 def _promoted(monkeypatch, verb, ex):
-    """A resident one-block frame of 40 rows (rung 64) whose first call
-    pays a rent far over any compile's price (a bandwidth of one byte a
-    second) and so buys the exact shape: ``(call, compiled)``, the next
-    call of the verb and the executable the promotion compiled."""
+    """A resident one-block frame of 40 rows on a rung (64) that has
+    seen another size first (ISSUE 32: a rung's first size runs exact
+    and pays nothing). Its first call pays a rent far over any
+    compile's price (a bandwidth of one byte a second) and so buys the
+    exact shape: ``(call, compiled)``, the next call of the verb and
+    the executable the promotion compiled."""
     import jax
 
     from tensorframes_tpu import shape_policy as sp
@@ -772,6 +774,9 @@ def _promoted(monkeypatch, verb, ex):
     df = tfs.TensorFrame([tfs.Column("x", jax.device_put(x))], [0, 40])
     ph = (tfs.block if verb == "map_blocks" else tfs.row)(df, "x")
     fetch = (ph * 2.0).named("z")
+    first = tfs.TensorFrame([tfs.Column("x", jax.device_put(x[:39]))], [0, 39])
+    getattr(tfs, verb)(fetch, first, executor=ex)
+    tele.reset()
 
     def call():
         out = getattr(tfs, verb)(fetch, df, executor=ex)
@@ -811,7 +816,10 @@ def test_diagnostics_bucketing_line_carries_the_promotion_counters(monkeypatch):
     call()
     tele.counter_inc("shape_bucketing.promotion_failed", 2)
     tele.counter_inc("shape_bucketing.promotion_unpriced", 3)
+    tele.counter_inc("shape_bucketing.first_size_dispatch", 4)
     bk = tfs.diagnostics(format="json")["bucketing"]
+    # the padded call was its rung's second size (`_promoted`)
+    assert (bk["first_size_dispatches"], bk["rungs_widened"]) == (4, 1)
     assert (bk["padded_dispatches"], bk["promoted_dispatches"]) == (1, 1)
     assert (bk["promotions"], bk["promotions_failed"]) == (1, 2)
     assert bk["promotions_unpriced"] == 3
@@ -820,6 +828,8 @@ def test_diagnostics_bucketing_line_carries_the_promotion_counters(monkeypatch):
     assert "1 padded dispatch(es)" in line
     assert "1 promoted dispatch(es) on 1 exact shape(s) bought" in line
     assert "(2 failed, 3 unpriced)" in line
+    assert "4 first-size dispatch(es) at their exact shape" in line
+    assert "1 rung(s) widened by a second size" in line
 
 
 @pytest.mark.parametrize("verb", ["map_blocks", "map_rows"])

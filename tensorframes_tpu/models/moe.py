@@ -3,15 +3,23 @@ result that the experts HELD HERE give.
 
 An expert layer is told which experts it holds, ``held = (first,
 count)``: the router scores and chooses over all ``num_experts`` (its
-published width), the routed rows are grouped by expert (one sort), and a
-grouped matmul (`jax.lax.ragged_dot`: on the TPU one kernel that visits
-each expert's own rows, no expert on a row not routed to it) computes
-what the held experts give. No token is dropped, there is no capacity.
+published width), the routed rows are grouped by expert (one stable sort
+of the rows' keys: its cost does not grow with the number of experts),
+and a grouped matmul (`jax.lax.ragged_dot`: on the TPU one kernel that
+visits each expert's own rows, no expert on a row not routed to it)
+computes what the held experts give. No token is dropped, there is no
+capacity. The layer takes its tokens in parts where the routed rows'
+temporaries would pass `PART_BYTES` (`parts_for`: from the routed rows
+and the experts' widths alone), each part grouped and multiplied by
+itself, so that a long block fits beside the weights; every token is
+still routed over all experts.
 Rows routed to experts held elsewhere add nothing here: their part is
 another holder's, and the parts of all holders add up to the whole layer
 (`apply_ep`: each shard calls the same local function with its own
 ``held`` and one `psum` adds the parts). On one chip that holds every
-expert, ``held = (0, num_experts)`` and the layer is whole.
+expert, ``held = (0, num_experts)`` and the layer is whole. What every
+holder would compute alike, a shared expert, is not in here: the caller
+adds it once, after the parts (`models.lm`).
 
 Router options (a configuration's keys): ``score`` "sigmoid" (scores are
 sigmoids of the router logits; the top-k are chosen by score plus an
@@ -32,7 +40,12 @@ from jax import lax
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-__all__ = ["MoEFFN", "route", "held_experts"]
+__all__ = ["MoEFFN", "route", "held_experts", "parts_for"]
+
+# the most that one part's routed rows may take in temporaries (the
+# gathered rows, the up projection's float32 output and the activation
+# beside it, or the down projection's output and its copy in token order)
+PART_BYTES = 3 << 30
 
 
 def _along_rows(x, idx):
@@ -71,8 +84,37 @@ def route(
         return idx.astype(jnp.int32), w * jnp.float32(scale)
 
 
+def group_rows(key, count):
+    """Routed rows grouped by ``key`` (0..count; ``count`` = held
+    elsewhere, last) in their own order within a group: ``(order, back,
+    sizes)``, the row at each grouped place, each row's grouped place, and
+    the rows of each of the ``count`` groups. One stable sort; int32
+    throughout (under x64 a sum of int32 is int64)."""
+    i32 = jnp.int32
+    n = key.shape[0]
+    places = lax.iota(i32, n)
+    grouped, order = lax.sort((key.astype(i32), places), num_keys=1, is_stable=True)
+    back = jnp.zeros_like(order).at[order].set(places, unique_indices=True)
+    # where each group starts among the sorted keys: a binary search a group
+    starts = jnp.searchsorted(
+        grouped, jnp.arange(count + 1, dtype=i32), side="left", method="scan"
+    ).astype(i32)
+    return order, back, starts[1:] - starts[:-1]
+
+
+def parts_for(rows: int, k: int, d: int, up: int, f: int, itemsize: int) -> int:
+    """In how many equal parts an expert layer takes ``rows`` tokens of
+    ``k`` experts each: the fewest that divide ``rows`` and keep a part's
+    temporaries (a routed row's ``d`` inputs, ``up`` float32 outputs of
+    the up projection and ``f`` activations; or ``f`` activations, ``d``
+    float32 outputs and their copy in token order) within `PART_BYTES`."""
+    row_bytes = max(d * itemsize + 4 * up + f * itemsize, f * itemsize + 8 * d)
+    least = -(-rows * k * row_bytes // PART_BYTES)
+    return next(n for n in range(max(1, least), rows + 1) if rows % n == 0)
+
+
 def held_experts(
-    u, idx, weight, w_up, w_down, held, *, gated: bool = True,
+    u, idx, weight, w_up, w_down, held, *, gated: bool = True, layer=None,
 ):
     """The held experts' part of the layer's output, ``(rows, d)`` float32.
 
@@ -81,26 +123,28 @@ def held_experts(
     (gate and up projections side by side: SwiGLU, ``gated``) or
     ``(count, d, f)`` (GELU), ``w_down`` ``(count, f, d)``; expert ``e``
     of the model is held at ``e - first``. ``first`` may be traced (a
-    shard's index)."""
+    shard's index). With ``layer`` (may be traced) the weights are the
+    stacks of several layers, ``(layers, count, ...)``: the grouped matmul
+    takes every layer's experts as its groups and the other layers' are
+    empty, so no layer's weights are copied out of their stack."""
     first, count = held
     rows, k = idx.shape
-    with jax.named_scope("moe.experts"):
+    d = u.shape[-1]
+    if layer is not None:
+        w_up = w_up.reshape((-1,) + w_up.shape[2:])
+        w_down = w_down.reshape((-1,) + w_down.shape[2:])
+
+    def part(args):
+        u, idx, weight = args
         local = idx.reshape(-1).astype(jnp.int32) - jnp.asarray(first, jnp.int32)
         mine = (local >= 0) & (local < count)
-        key = jnp.where(mine, local, count)  # rows held elsewhere go last
-        # a counting sort (the keys are few): a routed row's place is its
-        # expert's offset plus its rank among that expert's rows
-        # (int32 said everywhere: under x64 a sum of int32 is int64)
-        i32 = jnp.int32
-        hot = (key[:, None] == jnp.arange(count + 1, dtype=i32)).astype(i32)
-        sizes = jnp.sum(hot, axis=0, dtype=i32)
-        rank = jnp.cumsum(hot, axis=0, dtype=i32) - hot
-        offsets = jnp.cumsum(sizes, dtype=i32) - sizes
-        back = jnp.sum(hot * (rank + offsets), axis=1, dtype=i32)
-        order = jnp.zeros_like(back).at[back].set(
-            jnp.arange(back.shape[0], dtype=i32), unique_indices=True
-        )
-        sizes = sizes[:count]
+        # rows held elsewhere go last
+        order, back, sizes = group_rows(jnp.where(mine, local, count), count)
+        if layer is not None:  # this layer's groups among every layer's
+            at = jnp.asarray(layer, jnp.int32) * jnp.int32(count)
+            sizes = lax.dynamic_update_slice(
+                jnp.zeros(w_up.shape[0], jnp.int32), sizes, (at,)
+            )
         xs = jnp.take(u, order // k, axis=0)
         h = lax.ragged_dot(xs, w_up, sizes, preferred_element_type=jnp.float32)
         if gated:
@@ -112,9 +156,18 @@ def held_experts(
             a.astype(u.dtype), w_down, sizes, preferred_element_type=jnp.float32
         )
         # back to (row, choice) order; a row no held expert computed is 0
-        y = jnp.take(y, back, axis=0).reshape(rows, k, -1)
-        w = jnp.where(mine.reshape(rows, k), weight, 0.0)
+        y = jnp.take(y, back, axis=0).reshape(idx.shape[0], k, -1)
+        w = jnp.where(mine.reshape(idx.shape), weight, 0.0)
         return jnp.sum(jnp.where(w[..., None] != 0.0, y * w[..., None], 0.0), axis=1)
+
+    with jax.named_scope("moe.experts"):
+        n = parts_for(
+            rows, k, d, w_up.shape[-1], w_down.shape[-2], u.dtype.itemsize
+        )
+        if n == 1:
+            return part((u, idx, weight))
+        split = lambda a: a.reshape((n, rows // n) + a.shape[1:])
+        return lax.map(part, (split(u), split(idx), split(weight))).reshape(rows, d)
 
 
 class MoEFFN:

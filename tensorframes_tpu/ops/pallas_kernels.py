@@ -9,6 +9,11 @@ k iterations. Q·Kᵀ and P·V ride the MXU in the operands' own dtype with
 float32 accumulation; masking (causal + padded tail) happens on the VPU.
 A query head reads the key/value head of its group (grouped-query
 attention) through the block index, so nothing is repeated in memory.
+``v`` may have a head width of its own (the output and the accumulator
+take it), and the score may have a second part, ``q2 · k2ᵀ`` added to
+``q · kᵀ`` before the scale and the mask, whose keys ``k2`` have heads of
+their own count (latent attention: one rotary key a token for all heads,
+found through the block index like a grouped key head).
 
 This kernel is the single-device building block the ring attention in
 `parallel/ring.py` composes across chips (K/V rotation over ICI); it is
@@ -40,9 +45,11 @@ _NEG_INF = -1e30
 
 
 def _flash_kernel(
-    q_ref, k_ref, v_ref, o_ref, m_sc, l_sc, acc_sc,
-    *, scale: float, causal: bool, seq_len: int, blk_q: int, blk_k: int,
+    q_ref, k_ref, v_ref, *rest,
+    scale: float, causal: bool, seq_len: int, blk_q: int, blk_k: int,
 ):
+    # with a second score part its two blocks come before the output
+    second, (o_ref, m_sc, l_sc, acc_sc) = rest[:-4], rest[-4:]
     i = pl.program_id(2)
     j = pl.program_id(3)
 
@@ -65,7 +72,13 @@ def _flash_kernel(
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        ) * scale
+        )
+        if second:
+            s = s + jax.lax.dot_general(
+                second[0][:], second[1][:], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )
+        s = s * scale
 
         q_pos = i * blk_q + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
         k_pos = j * blk_k + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
@@ -98,6 +111,8 @@ def flash_attention(
     k: jax.Array,
     v: jax.Array,
     *,
+    q2: Optional[jax.Array] = None,
+    k2: Optional[jax.Array] = None,
     causal: bool = False,
     scale: Optional[float] = None,
     block_q: int = 128,
@@ -109,29 +124,39 @@ def flash_attention(
     holding ``kv_heads`` heads, each serving ``heads // kv_heads``
     consecutive query heads (grouped-query attention: the kernel reads a
     kv head's blocks where its query heads ask for them, nothing is
-    repeated in memory)."""
+    repeated in memory). ``v``'s head width may differ from q's and k's:
+    the output has ``v``'s. ``q2`` ``(batch, heads, seq, d2)`` and ``k2``
+    ``(batch, kv2_heads, seq, d2)``, given together, are a second part of
+    the score, ``(q kᵀ + q2 k2ᵀ) * scale``, ``kv2_heads`` dividing
+    ``heads`` as ``kv_heads`` does (the default scale is
+    ``1 / sqrt(head_dim + d2)``)."""
+    if (q2 is None) != (k2 is None):
+        raise ValueError("q2 and k2 are the two sides of one score part: give both")
+    if q2 is not None and (q2.ndim != 4 or q.ndim != 4):
+        raise ValueError("a second score part takes (batch, heads, seq, width) arrays")
     if scale is None:
-        scale = 1.0 / np.sqrt(q.shape[-1])
-    if q.ndim == 4 and q.shape[1] % k.shape[1]:
-        raise ValueError(
-            f"{q.shape[1]} query heads do not divide over {k.shape[1]} "
-            "key/value heads"
-        )
+        scale = 1.0 / np.sqrt(q.shape[-1] + (0 if q2 is None else q2.shape[-1]))
+    for name, keys in (("key/value", k), ("second-part key", k2)):
+        if keys is not None and q.ndim == 4 and q.shape[1] % keys.shape[1]:
+            raise ValueError(
+                f"{q.shape[1]} query heads do not divide over {keys.shape[1]} "
+                f"{name} heads"
+            )
     return _flash(
-        q, k, v, bool(causal), float(scale), block_q, block_k,
-        bool(interpret),
+        q, k, v, None if q2 is None else (q2, k2), bool(causal), float(scale),
+        block_q, block_k, bool(interpret),
     )
 
 
-def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
+def _flash_forward(q, k, v, second, causal, scale, block_q, block_k, interpret):
     if q.ndim == 2:
         out = _flash_forward(
-            q[None, None], k[None, None], v[None, None],
+            q[None, None], k[None, None], v[None, None], second,
             causal, scale, block_q, block_k, interpret,
         )
         return out[0, 0]
     batch, heads, seq, d = q.shape
-    group = heads // k.shape[1]
+    dv = v.shape[-1]
 
     blk_q = min(block_q, max(8, seq))
     blk_k = min(block_k, max(8, seq))
@@ -152,58 +177,78 @@ def _flash_forward(q, k, v, causal, scale, block_q, block_k, interpret):
         blk_q=blk_q,
         blk_k=blk_k,
     )
-    def kv_block(b, h, i, j):
-        # `lax.div`, not `//`: the operands are never negative, and
-        # Mosaic lowers an index map too (a floor division's sign
-        # handling does not lower under x64)
-        if causal:
-            # a block above the diagonal is skipped: ask for the last
-            # one needed again, which is already there, not for a new one
-            j = jnp.minimum(
-                j, jax.lax.div(i * blk_q + (blk_q - 1), jnp.int32(blk_k))
-            )
-        return (b, jax.lax.div(h, jnp.int32(group)), j, jnp.int32(0))
+
+    def q_block(b, h, i, j):
+        return (b, h, i, jnp.int32(0))
+
+    def key_block(keys):
+        group = heads // keys.shape[1]
+
+        def block(b, h, i, j):
+            # `lax.div`, not `//`: the operands are never negative, and
+            # Mosaic lowers an index map too (a floor division's sign
+            # handling does not lower under x64)
+            if causal:
+                # a block above the diagonal is skipped: ask for the last
+                # one needed again, which is already there, not for a new one
+                j = jnp.minimum(
+                    j, jax.lax.div(i * blk_q + (blk_q - 1), jnp.int32(blk_k))
+                )
+            return (b, jax.lax.div(h, jnp.int32(group)), j, jnp.int32(0))
+
+        return block
+
+    operands = [qp, kp, vp]
+    in_specs = [
+        pl.BlockSpec((None, None, blk_q, d), q_block),
+        pl.BlockSpec((None, None, blk_k, d), key_block(kp)),
+        pl.BlockSpec((None, None, blk_k, dv), key_block(vp)),
+    ]
+    if second is not None:
+        q2p, k2p = pad(second[0], pad_q), pad(second[1], pad_k)
+        d2 = q2p.shape[-1]
+        operands += [q2p, k2p]
+        in_specs += [
+            pl.BlockSpec((None, None, blk_q, d2), q_block),
+            pl.BlockSpec((None, None, blk_k, d2), key_block(k2p)),
+        ]
 
     out = pl.pallas_call(
         kernel,
         grid=(batch, heads, nq, nk),
-        in_specs=[
-            pl.BlockSpec((None, None, blk_q, d), lambda b, h, i, j: (b, h, i, jnp.int32(0))),
-            pl.BlockSpec((None, None, blk_k, d), kv_block),
-            pl.BlockSpec((None, None, blk_k, d), kv_block),
-        ],
-        out_specs=pl.BlockSpec(
-            (None, None, blk_q, d), lambda b, h, i, j: (b, h, i, jnp.int32(0))
-        ),
-        out_shape=jax.ShapeDtypeStruct(qp.shape, q.dtype),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((None, None, blk_q, dv), q_block),
+        out_shape=jax.ShapeDtypeStruct(qp.shape[:-1] + (dv,), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((blk_q, 1), jnp.float32),  # running max
             pltpu.VMEM((blk_q, 1), jnp.float32),  # running denominator
-            pltpu.VMEM((blk_q, d), jnp.float32),  # weighted accumulator
+            pltpu.VMEM((blk_q, dv), jnp.float32),  # weighted accumulator
         ],
         interpret=interpret,
-    )(qp, kp, vp)
+    )(*operands)
     return out[:, :, :seq] if pad_q else out
 
 
-_flash = jax.custom_vjp(_flash_forward, nondiff_argnums=(3, 4, 5, 6, 7))
+_flash = jax.custom_vjp(_flash_forward, nondiff_argnums=(4, 5, 6, 7, 8))
 
 
-def _flash_fwd(q, k, v, *static):
-    return _flash_forward(q, k, v, *static), (q, k, v)
+def _flash_fwd(q, k, v, second, *static):
+    return _flash_forward(q, k, v, second, *static), (q, k, v, second)
 
 
 def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
     from ..parallel.ring import full_attention
 
-    def plain(q, k, v):
+    def plain(q, k, v, second):
         if q.ndim == 2:
             return full_attention(q, k, v, causal=causal, scale=scale)
-        group = q.shape[1] // k.shape[1]
+        every = lambda a: jnp.repeat(a, q.shape[1] // a.shape[1], axis=1)
+        k = every(k)
+        if second is not None:  # the score's two parts as one wider dot
+            q = jnp.concatenate([q, second[0]], axis=-1)
+            k = jnp.concatenate([k, every(second[1])], axis=-1)
         one = lambda a, b, c: full_attention(a, b, c, causal=causal, scale=scale)
-        return jax.vmap(jax.vmap(one))(
-            q, jnp.repeat(k, group, axis=1), jnp.repeat(v, group, axis=1)
-        )
+        return jax.vmap(jax.vmap(one))(q, k, every(v))
 
     _, vjp = jax.vjp(plain, *res)
     return vjp(g)

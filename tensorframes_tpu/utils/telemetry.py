@@ -974,6 +974,10 @@ _PROM_HELP: Dict[str, str] = {
         "Token rows routed to experts (tokens x experts per token x "
         "expert layers) by models.lm.score"
     ),
+    "lm.attention_pairs": (
+        "Causal query-key pairs (x heads x attention layers) attended by "
+        "models.lm.score"
+    ),
     "fault_retries": "Classified dispatch retries by fault class",
     "device_evictions": "Failover circuit-breaker device evictions",
     "block_splits": "OOM-triggered block split-retries by verb",

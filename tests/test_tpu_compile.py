@@ -259,3 +259,86 @@ def test_expert_layer_is_a_grouped_matmul_under_the_names_the_benchmark_reads(
     # plain dot on the chip) as large as an expert's matmul over all rows
     assert not [l for l in labels if l.startswith("convolution")
                 and f"[{32768 * 4}," in l]
+
+
+def _joyai_config():
+    """`joyai-llm-flash` as the benchmark's runner hands it to the program:
+    the file's published keys, without the ones `derived` lists."""
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "perf", "configs", "joyai-llm-flash.json")) as f:
+        config = json.load(f)
+    return config, {k: v for k, v in config.items() if k not in config["derived"]}
+
+
+def test_latent_attention_kernel_at_the_cells_shape(one_chip):
+    # one window of 32,768 positions: 32 heads with a score of a per-head
+    # part (128) and a rotary part (64) whose key all heads share, values
+    # of 128, bfloat16, 1,024-blocks: as `models.lm` calls the kernel
+    shapes = [((1, 32, 32768, 128), jnp.bfloat16)] * 3 + [
+        ((1, 32, 32768, 64), jnp.bfloat16), ((1, 1, 32768, 64), jnp.bfloat16)]
+    lowered, compiled = _compile(
+        lambda q, k, v, q2, k2: flash_attention(
+            q, k, v, q2=q2, k2=k2, causal=True, scale=float(1 / np.sqrt(192)),
+            block_q=1024, block_k=1024),
+        one_chip, *shapes,
+    )
+    assert "tpu_custom_call" in lowered.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**29
+
+
+def test_the_latent_scoring_program_at_the_cells_block(one_chip):
+    """`lm.scoring_fn` of `joyai-llm-flash` at the published widths over one
+    block of the cell (one window of 32,768 ids), the weights arguments: it
+    compiles for the chip; its temporaries fit beside 10.35 GiB of weights;
+    the operations that the configuration's `kernel_ops.mla_attention` and
+    `kernel_ops.moe_experts` match are in it under those names (what
+    `mla_attention_roofline` and `moe_expert_roofline` sum in a device
+    trace), the grouped matmuls over whole parts of the window; no layer's
+    expert weights are copied out of their stack; and the program holds no
+    64-bit array (the chip's grouped matmul compiles beside none)."""
+    import re
+    import sys
+
+    from tensorframes_tpu.models import lm
+
+    config, cfg = _joyai_config()
+    shapes = jax.eval_shape(lambda: lm.init_params(cfg, 0))
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), shapes)
+    tokens = jax.ShapeDtypeStruct((1, 32768), jnp.int32, sharding=one_chip)
+    lowered = jax.jit(lm.scoring_fn(cfg)).lower(tokens, params)
+    compiled = lowered.compile()
+    memory = compiled.memory_analysis()
+    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(shapes))
+    assert 11.0e9 < weights < 11.2e9  # 5,558 M parameters in bfloat16
+    assert weights + memory.temp_size_in_bytes < 15.0 * 2**30
+    assert memory.output_size_in_bytes < 8 * 2**20
+
+    root = __import__("os").path.dirname(__import__("os").path.dirname(
+        __import__("os").path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from perf.lib.trace import op_label
+
+    text = compiled.as_text()
+    labels = [
+        op_label(line.strip().removeprefix("ROOT "))
+        for line in text.splitlines() if " = " in line
+    ]
+    attention = sorted({l for l in labels
+                        if re.search(config["kernel_ops"]["mla_attention"], l)})
+    assert attention == [l for l in attention if l.endswith("bf16[1,32,32768,128]")]
+    assert len(attention) == 1, attention
+    experts = sorted({l for l in labels
+                      if re.search(config["kernel_ops"]["moe_experts"], l)})
+    assert len(experts) == 2 and all(l.startswith("ragged-dot-none") for l in experts)
+    widths = {l.split()[1].split(",")[1].rstrip("]") for l in experts}
+    assert widths == {str(2 * 768), "2048"}, experts
+    part = {int(l.split("[")[1].split(",")[0]) for l in experts}
+    assert len(part) == 1 and (32768 * 8) % part.pop() == 0
+    # a layer's 1.2 GB of experts stay where they are bound
+    assert not re.search(r"= bf16\[(1,)?256,2048,1536\]\S* (fusion|copy|dynamic-slice)", text)
+    assert not re.findall(r"\b[sufc](?:64|128)\[", text)

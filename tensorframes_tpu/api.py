@@ -820,19 +820,62 @@ def _run_blocks(
     ``config.oom_split_depth``. ``empty()`` names and shapes the
     outputs of a frame with no rows.
 
-    Per block: classified fault handling (`runtime.faults`: transient
-    errors retry with backoff and fail over under the scheduler; the
-    verb's deadline is checked at every dispatch), a ``<verb>.block``
-    dispatch span per attempt labeled with the device it ran on, and
-    the numerics check. Spans: ``<verb>.blocks`` around the loop,
-    ``frame.cut`` / ``shape.pad`` / ``shape.unpad`` / ``frame.concat``
-    where a block is cut, padded or joined."""
+    A run of equal blocks is ONE dispatch, a group
+    (`shape_policy.group_dispatch`: one program that loops over the
+    run's blocks on the device, each at its exact shape), where the
+    call is ``bucketed`` on one device (no scheduler), nothing is bound
+    or trimmed, the outputs' names are known beforehand (a graph's
+    program gives a sequence, a plain function a dict) and the columns
+    are resident on that device (`shape_policy.block_runs`). A frame
+    that is one run has one part and no concat. Any other block, and a
+    run whose group ran out of memory, goes through the loop one block
+    at a time.
+
+    Per dispatch, a block's or a group's: classified fault handling
+    (`runtime.faults`: transient errors retry with backoff and fail
+    over under the scheduler; the verb's deadline is checked at every
+    dispatch), a ``<verb>.block`` dispatch span per attempt labeled
+    with the device it ran on (a group's carries ``blocks`` and the
+    run's rows), and the numerics check. Spans: ``<verb>.blocks``
+    around the loop, ``frame.cut`` / ``shape.pad`` / ``shape.unpad`` /
+    ``frame.concat`` where a block is cut, padded or joined."""
     from . import shape_policy as _sp
     from .runtime import faults as _flt
 
     fscope = _flt.scope(verb)
     col_names = [n for n in feed_names if n in columns]
     col_values = [columns[n] for n in col_names]
+
+    def _dispatch_group(bi: int, lo_: int, n: int, k: int) -> Optional[List]:
+        """The outputs over the ``k`` blocks of ``n`` rows from block
+        ``bi`` (row ``lo_``) on, from one dispatch; None where the run
+        has no group, or its group ran out of memory."""
+        call = _sp.group_dispatch(fn, col_values, lo_, n, k)
+        if call is None:
+            return None
+
+        def _thunk():
+            with _tele.dispatch_span(
+                f"{verb}.block", program=fp, block=bi, rows=k * n,
+                bucket=k * n, blocks=k,
+            ):
+                return call(*col_values)
+
+        try:
+            return list(fscope.dispatch(
+                _thunk,
+                what=f"{verb} blocks [{bi}:{bi + k}) rows "
+                f"[{lo_}:{lo_ + k * n})",
+            ))
+        except Exception as e:
+            if _flt.classify(e) != _flt.RESOURCE:
+                raise
+            _flt.record_oom(
+                verb, fp, k * n, 0, f"split:{k} blocks of {n} rows, one by one",
+                e, bucket=k * n,
+            )
+            _flt.note_split(verb)
+            return None
 
     def _dispatch_rows(bi: int, lo_: int, hi_: int, depth: int) -> List:
         def _cut() -> List:
@@ -908,15 +951,31 @@ def _run_blocks(
     names: List[str] = list(out_names or [])
     acc: List[List] = []
     out_sizes: List[int] = []
+    runs: Dict[int, Tuple[int, int, int]] = {}
+    if (
+        frame.num_blocks > 1 and bucketed and sched is None
+        and not trim and not bound and names
+    ):
+        runs = _sp.block_runs(col_values, frame.offsets)
     with _tele.span(f"{verb}.blocks", kind="stage"):
-        for bi in range(frame.num_blocks):
+        bi = 0
+        while bi < frame.num_blocks:
             lo, hi = frame.offsets[bi], frame.offsets[bi + 1]
             if lo == hi:
                 out_sizes.append(0)
+                bi += 1
                 continue  # empty block: contributes nothing (the reference's
                 # empty-partition TODO, `DebugRowOps.scala:386-387`)
-            outs = _dispatch_rows(bi, lo, hi, 0)
-            maybe_check_numerics(names, outs, f"{verb} block {bi}")
+            n, k, end = runs.get(bi, (hi - lo, 1, bi + 1))
+            outs = _dispatch_group(bi, lo, n, k) if k > 1 else None
+            if outs is None:  # one block, or of a run with no group
+                k, end = 1, bi + 1
+                outs = _dispatch_rows(bi, lo, hi, 0)
+            hi = lo + k * n
+            maybe_check_numerics(
+                names, outs,
+                f"{verb} block {bi}" if k == 1 else f"{verb} blocks [{bi}:{end})",
+            )
             bsize = None
             for f, o in zip(names, outs):
                 # keep device arrays on device; shape checks are metadata-only
@@ -940,7 +999,8 @@ def _run_blocks(
                             f"{verb}(trim): outputs disagree on row count"
                         )
             acc.append(outs)
-            out_sizes.append(bsize if trim else hi - lo)
+            out_sizes.append(bsize)  # read under `trim` only
+            bi = end
 
     anchor = sched.anchor_device() if sched is not None else None
     if acc:

@@ -111,9 +111,12 @@ class ProgramLedger:
     and device the first row count asked for (it runs at its exact
     shape) and whether another has come since, and ``shapes``, per
     exact feed signature of those others the rent its pads have paid
-    and what it bought."""
+    and what it bought, and ``groups``, per run of equal blocks (their
+    size and count, the columns, the device) the one program that loops
+    over the run."""
 
-    __slots__ = ("key", "jitted", "compile_seconds", "rungs", "shapes")
+    __slots__ = ("key", "jitted", "compile_seconds", "rungs", "shapes",
+                 "groups")
 
     def __init__(self, key: Tuple, jitted: Callable):
         self.key = key
@@ -121,6 +124,7 @@ class ProgramLedger:
         self.compile_seconds: Optional[float] = None
         self.rungs: Dict[Tuple, List] = {}
         self.shapes: OrderedDict = OrderedDict()
+        self.groups: OrderedDict = OrderedDict()
 
 
 class Executor:

@@ -127,6 +127,52 @@ Windows, blocks on their rung, numpy or sharded columns, `block_feeds`
 (no program), unbucketed callers and the routes that call `pad_feeds`
 themselves are untouched by both rules; no knob decides any of it.
 
+BLOCK GROUP (`block_runs` / `group_dispatch`, the same two loops, on one
+device: no scheduler): a frame cut into equal blocks asks the host for
+the same work once a block, and for a small block that work (a window,
+a dispatch, an unpad, their spans and checks: about a millisecond) is
+many times the program's. Where every feed column is a `jax.Array`
+resident on one device, each maximal run of two or more non-empty
+blocks of one size ``n`` (empty blocks between them hold no rows and
+do not end it) is therefore dispatched ONCE: one jitted program that
+loops over the run's ``k`` blocks on the device. Step ``i`` takes
+``dynamic_slice_in_dim(col, lo + i * n, n)`` of every feed column
+(``lo`` a traced scalar, ``n`` and ``k`` static), runs the program's
+own `jax.jit` on it, at the block's exact shape and in block order as
+the per-block loop would, and writes what it gives at rows ``[i * n,
+(i + 1) * n)`` of output columns of ``k * n`` rows carried through the
+loop (uninitialised at its start, `jax.lax.empty`: every row is
+written by exactly one step). Who iterates changes, not what the
+program sees: no window, no pad row (``shape_bucketing.pad_rows``
+counts 0), no unpad, and for a frame that is one run no concat.
+``shape_bucketing.group_dispatch`` counts the groups,
+``shape_bucketing.grouped_blocks`` the blocks they covered. The
+group's function takes the program's function's name, so its XLA
+module is named as the program's is (a graph's: ``jit_fn``). What
+decides is what the input shows: a block whose size no neighbour
+shares, numpy or sharded columns, a column too long for an int32 row
+index, a program with no ledger and a program whose outputs do not
+keep the block's rows run block by block as before, and so does every
+block under a scheduler, a trim or bound values (the caller's side,
+`api._run_blocks`). A RESOURCE fault in a group sends its run back to
+that loop, which may split rows.
+BOUND: a group executable is specific to ``(n, k)``, the columns'
+whole shapes and dtypes and the device, and is compiled on the calling
+thread at first sight, where the per-block program for a new ``n``
+would have compiled at that moment anyway. The lines are kept on the
+program's ledger (``ProgramLedger.groups``: evicted with the cache
+entry, at most ``config.executor_cache_entries`` lines a program,
+least recently seen out first), so a program holds no more group
+executables than that. A ledger that is full has seen that many
+distinct runs: the process drifts, and from then on a new signature
+takes the line of the least recently seen one but must come back
+while it holds it before it is compiled (until then its blocks run
+one by one, on the ladder). So a program compiles, beside the
+ladder's O(log max-block-rows) and each rung's first size, at most
+``config.executor_cache_entries`` groups at first sight and after
+that only groups that have repeated: sizes or counts that never
+repeat stop compiling when the ledger is full.
+
 Exactness: map outputs, min/max, and integer-dtype reductions are
 bit-identical to unbucketed eager execution. Float sum/mean reduce over
 a wider (padded) axis, so XLA's vectorized accumulation may group the
@@ -142,6 +188,7 @@ from __future__ import annotations
 import functools
 import math
 import threading
+import time
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
@@ -169,6 +216,8 @@ __all__ = [
     "unpad_block",
     "BlockDispatch",
     "block_dispatch",
+    "block_runs",
+    "group_dispatch",
     "drain",
     "rowwise_fetches",
     "MaskPlan",
@@ -566,6 +615,14 @@ def _first_size(book, trailing: Tuple, n: int, bucket: int, device) -> bool:
     return False
 
 
+def _ledger_lines() -> int:
+    """How many lines a program's ledger keeps of a kind (``shapes``,
+    ``groups``): as many as the executor's cache keeps programs."""
+    from . import config as _config
+
+    return max(1, int(_config.get().executor_cache_entries))
+
+
 def _line(book, trailing: Tuple, n: int, device) -> "_Line":
     """The line of the ledger ``book`` for ``n`` rows of feeds of
     ``trailing`` on ``device``, made at first sight. A ledger holds as
@@ -579,11 +636,8 @@ def _line(book, trailing: Tuple, n: int, device) -> "_Line":
         if line is not None:
             book.shapes.move_to_end(sig)
             return line
-        from . import config as _config
-
         line = book.shapes[sig] = _Line(n, avals, device)
-        limit = max(1, int(_config.get().executor_cache_entries))
-        while len(book.shapes) > limit:
+        while len(book.shapes) > _ledger_lines():
             book.shapes.popitem(last=False)
     return line
 
@@ -701,6 +755,162 @@ def drain(executor=None, timeout: Optional[float] = None) -> bool:
     for t in threads:
         t.join(timeout)
     return not any(t.is_alive() for t in threads)
+
+
+# ---------------------------------------------------------------------------
+# the block group: a run of equal blocks is one loop inside one program
+# ---------------------------------------------------------------------------
+
+
+def block_runs(
+    columns: Sequence, offsets: Sequence[int]
+) -> Dict[int, Tuple[int, int, int]]:
+    """The runs of equal blocks a group can take (module docstring):
+    ``{first block: (n, k, end)}`` for each maximal run of ``k >= 2``
+    non-empty blocks of ``n`` rows each among the blocks ``offsets``
+    cuts, ``end`` one past its last block. Empty blocks inside a run
+    belong to it. Nothing unless every column is a `jax.Array` resident
+    on one device and short enough for an int32 row index."""
+    runs: Dict[int, Tuple[int, int, int]] = {}
+    if _resident_device(columns) is None or (
+        max(c.shape[0] for c in columns) > _INT32_MAX
+    ):
+        return runs
+    first = end = n = k = 0
+    for bi, (lo, hi) in enumerate(zip(offsets, offsets[1:])):
+        if hi == lo:
+            continue
+        if hi - lo == n:
+            k, end = k + 1, bi + 1
+            continue
+        if k >= 2:
+            runs[first] = (n, k, end)
+        first, end, n, k = bi, bi + 1, hi - lo, 1
+    if k >= 2:
+        runs[first] = (n, k, end)
+    return runs
+
+
+def group_dispatch(
+    program: Optional[Callable], columns: Sequence, lo: int, n: int, k: int
+) -> Optional[Callable]:
+    """What to call on the feed ``columns`` themselves to run the
+    executor's cached ``program`` over the ``k`` blocks of ``n`` rows
+    from row ``lo`` on as one group: it returns the program's outputs
+    over those ``k * n`` rows. None where the run has no group executable
+    (a program with no ledger, outputs that do not keep the block's rows,
+    a compile that raised, a signature a full ledger has seen once): the
+    caller dispatches the run block by block."""
+    book = _program_ledger(program)
+    if book is None:
+        return None
+    sig = (
+        n, k,
+        tuple((tuple(c.shape), np.dtype(c.dtype)) for c in columns),
+        _resident_device(columns),
+    )
+    call = _group(book, sig)
+    if call is None:
+        return None
+    observe_fill(k * n, k * n)
+    _count("shape_bucketing.group_dispatch")
+    _count("shape_bucketing.grouped_blocks", k)
+    exact_dispatch()
+    # handed out as the executor hands its programs out
+    return functools.partial(_ex.hand_out(call, book.key), np.int32(lo))
+
+
+class _Group:
+    """One line of ``ProgramLedger.groups``: the executable of one run
+    signature (``call``, None until compiled); ``closed`` once it is
+    known that there will be none."""
+
+    __slots__ = ("call", "closed")
+
+    def __init__(self):
+        self.call = None
+        self.closed = False
+
+
+def _group(book, sig: Tuple) -> Optional[Callable]:
+    """The group executable of the ledger ``book`` for the run signature
+    ``sig`` (``n``, ``k``, the columns' shapes and dtypes, the device),
+    compiled here at first sight while the ledger has room, and once it
+    is full at second sight (module docstring, BOUND)."""
+    limit = _ledger_lines()
+    with _ledger_lock:
+        line = book.groups.get(sig)
+        if line is not None:
+            book.groups.move_to_end(sig)
+            build = line.call is None and not line.closed  # it came back
+        else:
+            build = len(book.groups) < limit
+            line = book.groups[sig] = _Group()
+            while len(book.groups) > limit:
+                book.groups.popitem(last=False)
+    if build:
+        try:
+            line.call = _compile_group(book, *sig)
+        except Exception as e:
+            from .utils.log import get_logger
+
+            get_logger("shape_policy").warning(
+                "no group executable for program %s/%s over %d blocks of "
+                "%d rows, the run is dispatched block by block: %s: %s",
+                book.key[0], str(book.key[1])[:12], sig[1], sig[0],
+                type(e).__name__, e,
+            )
+        line.closed = line.call is None
+    return line.call
+
+
+def _compile_group(
+    book, n: int, k: int, avals: Sequence, device
+) -> Optional[Callable]:
+    """Lower and compile the loop of ``k`` steps of the ledger's program
+    over blocks of ``n`` rows of columns of ``avals`` (``(shape, dtype)``
+    each) on ``device``, ahead of time. None where an output of the
+    program does not keep the block's rows."""
+    jitted, key = book.jitted, book.key
+    blocks = jax.eval_shape(jitted, *[
+        jax.ShapeDtypeStruct((n,) + shape[1:], dtype) for shape, dtype in avals
+    ])
+    if not isinstance(blocks, (tuple, list)) or not all(
+        getattr(o, "ndim", 0) and o.shape[0] == n for o in blocks
+    ):
+        return None
+
+    def fn(lo, *columns):
+        def step(i, outs):
+            got = jitted(*[
+                jax.lax.dynamic_slice_in_dim(c, lo + i * n, n) for c in columns
+            ])
+            return tuple(
+                jax.lax.dynamic_update_slice_in_dim(o, v, i * n, 0)
+                for o, v in zip(outs, got)
+            )
+
+        # int32 bounds: under x64 the counter, and with it every row
+        # index, would be 64-bit
+        return jax.lax.fori_loop(np.int32(0), np.int32(k), step, tuple(
+            jax.lax.empty((k * n,) + o.shape[1:], o.dtype) for o in blocks
+        ))
+
+    # the XLA module is named after the function: as the program's own
+    fn.__name__ = fn.__qualname__ = getattr(jitted, "__name__", "fn")
+    t0 = time.perf_counter()
+    compiled = _compile_exact(
+        jax.jit(fn), [((), np.dtype(np.int32)), *avals], device
+    )
+    t1 = time.perf_counter()
+    _tele.record_compile(key[1], key[0], t1 - t0, "xla", t0, t1)
+
+    def call(lo, *columns):
+        out = compiled(lo, *columns)
+        _cm.note_exec(key, columns, out)  # as `_instrument` counts one
+        return out
+
+    return call
 
 
 # ---------------------------------------------------------------------------

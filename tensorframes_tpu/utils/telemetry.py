@@ -1204,7 +1204,8 @@ def diagnostics_data(executor=None) -> Dict:
             # their padded program, dispatches served by an exact-shape
             # executable a repeated pad bought, shapes bought, compiles
             # that failed, shapes on a device with no known bandwidth
-            # to price a pad with
+            # to price a pad with; runs of equal blocks dispatched as
+            # one group, and the blocks those covered
             **{
                 name: int(counters.get("shape_bucketing." + counter, 0))
                 for name, counter in (
@@ -1214,6 +1215,8 @@ def diagnostics_data(executor=None) -> Dict:
                     ("promotions", "promotions"),
                     ("promotions_failed", "promotion_failed"),
                     ("promotions_unpriced", "promotion_unpriced"),
+                    ("group_dispatches", "group_dispatch"),
+                    ("grouped_blocks", "grouped_blocks"),
                 )
             },
             "fill": {
@@ -1505,7 +1508,9 @@ def _render_diagnostics(data: Dict) -> str:
             f"size; {bk.get('promoted_dispatches', 0)} promoted "
             f"dispatch(es) on {bk.get('promotions', 0)} exact shape(s) "
             f"bought ({bk.get('promotions_failed', 0)} failed, "
-            f"{bk.get('promotions_unpriced', 0)} unpriced)"
+            f"{bk.get('promotions_unpriced', 0)} unpriced); "
+            f"{bk.get('group_dispatches', 0)} group dispatch(es) over "
+            f"{bk.get('grouped_blocks', 0)} equal block(s)"
         )
         for verb, f in bk.get("fill", {}).items():
             lines.append(

@@ -176,27 +176,33 @@ def _rows_times_two(df):
 # case -> (verb, frame, fetch, injected fault, the (shift, n) pairs the
 # unpad program must see, window dispatches, padded dispatches, sizes
 # dispatched exact as their rung's first); rung ladder 8, 16, 32, ...
+# No two neighbouring blocks share a size: a run of equal blocks on one
+# device is one group (ISSUE 34, `TestBlockGroup`), not windows.
 _WINDOW_CASES = {
-    # every window starts at its block: 3 x 10 rows in rungs of 16, the
-    # last block (16 rows) on its rung and so neither windowed nor padded
+    # every window starts at its block: 10, 11, 10 rows in rungs of 16,
+    # the last block (16 rows) on its rung and so neither windowed nor
+    # padded
     "interior-blocks": (
-        "map_blocks", lambda: _resident({"x": _ints(46)}, [10, 10, 10, 16]),
-        _times_two, None, {(0, 10)}, 3, 0, [],
+        "map_blocks", lambda: _resident({"x": _ints(47)}, [10, 11, 10, 16]),
+        _times_two, None, {(0, 10), (0, 11)}, 3, 0, [],
     ),
-    # the block at row 90 of 100 has no 16 rows after it: its window
-    # starts at 84 and its rows sit 6 into it
+    # the block at row 86 of 95 has no 16 rows after it: its window
+    # starts at 79 and its rows sit 7 into it
     "last-blocks": (
-        "map_blocks", lambda: _resident({"x": _ints(100)}, [10] * 10),
-        _times_two, None, {(0, 10), (6, 10)}, 10, 0, [],
+        "map_blocks", lambda: _resident({"x": _ints(95)}, [10, 9] * 5),
+        _times_two, None, {(0, 10), (0, 9), (7, 9)}, 10, 0, [],
     ),
     "two-feed-columns": (
         "map_blocks",
-        lambda: _resident({"x": _ints(60), "y": _ints(60, mod=7)}, [20] * 3),
-        _two_columns, None, {(0, 20), (12, 20)}, 3, 0, [],
+        lambda: _resident(
+            {"x": _ints(60), "y": _ints(60, mod=7)}, [20, 19, 21]
+        ),
+        _two_columns, None, {(0, 20), (0, 19), (11, 21)}, 3, 0, [],
     ),
     "2d-column-map_rows": (
-        "map_rows", lambda: _resident({"x": _ints(60, width=3)}, [20] * 3),
-        _rows_times_two, None, {(0, 20), (12, 20)}, 3, 0, [],
+        "map_rows",
+        lambda: _resident({"x": _ints(60, width=3)}, [20, 19, 21]),
+        _rows_times_two, None, {(0, 20), (0, 19), (11, 21)}, 3, 0, [],
     ),
     # 64 is on its rung; 55 ends the column, 9 rows short of its rung
     "uneven-blocks": (
@@ -208,8 +214,8 @@ _WINDOW_CASES = {
     # block 0's first dispatch runs out of memory: its halves take
     # smaller windows of the same column (50 -> 25 + 25 rows)
     "oom-split-half": (
-        "map_blocks", lambda: _resident({"x": _ints(100)}, [50, 50]),
-        _times_two, "resource", {(0, 25), (14, 50)}, 4, 0, [],
+        "map_blocks", lambda: _resident({"x": _ints(99)}, [50, 49]),
+        _times_two, "resource", {(0, 25), (15, 49)}, 4, 0, [],
     ),
     # controls: nothing to take a window of. A resident block shorter
     # than its rung is the rung's first size and runs exact (ISSUE 32)
@@ -330,6 +336,285 @@ class TestBlockWindow:
             assert ex.jit_shape_compiles() == n_compiles
             assert sp.block_window._cache_size() == w1
             assert sp.block_unpad._cache_size() == u1
+
+
+# ---------------------------------------------------------------------------
+# the block group (ISSUE 34): a run of equal blocks of resident columns is
+# one dispatch, a loop over the run's blocks inside one program
+# ---------------------------------------------------------------------------
+
+
+def _two_fetches(df):
+    x, y = tfs.block(df, "x"), tfs.block(df, "y")
+    return [(x * 2.0 + y).named("z"), (x - y).named("w")]
+
+
+def _group_counters():
+    from tensorframes_tpu.utils import telemetry as tele
+
+    c = tele.flat_counters()
+    return {
+        k: int(c[f"shape_bucketing.{k}"]) if f"shape_bucketing.{k}" in c else None
+        for k in ("group_dispatch", "grouped_blocks", "window_dispatch",
+                  "padded_dispatch", "first_size_dispatch", "pad_rows")
+    }
+
+
+# case -> (verb, frame, fetches, scheduler, groups, blocks they cover,
+# windows, padded dispatches, first-size dispatches, pad rows); rungs 8,
+# 16, 32, ...
+_GROUP_CASES = {
+    "200-equal-blocks": (
+        "map_blocks", lambda: _resident({"x": _ints(2000)}, [10] * 200),
+        _times_two, "off", 1, 200, 0, 0, 0, 0,
+    ),
+    # blocks on their rung are a run like any other
+    "equal-blocks-on-their-rung": (
+        "map_blocks", lambda: _resident({"x": _ints(64)}, [16] * 4),
+        _times_two, "off", 1, 4, 0, 0, 0, 0,
+    ),
+    # the remainder is a window of the same column
+    "equal-blocks-and-a-remainder": (
+        "map_blocks", lambda: _resident({"x": _ints(57)}, [10] * 5 + [7]),
+        _times_two, "off", 1, 5, 1, 0, 0, 1,
+    ),
+    # an empty block holds no rows and ends no run: 10 10 10 | 7 | 4 4
+    "empty-blocks-inside-and-between-runs": (
+        "map_blocks",
+        lambda: _resident(
+            {"x": _ints(45)}, [0, 10, 0, 10, 10, 0, 7, 0, 4, 0, 0, 4, 0]
+        ),
+        _times_two, "off", 2, 5, 1, 0, 0, 1,
+    ),
+    # a run, a lone block (off its rung: a window), the first size again
+    "runs-of-one-size-apart": (
+        "map_blocks",
+        lambda: _resident({"x": _ints(53)}, [10, 10, 13, 10, 10]),
+        _times_two, "off", 2, 4, 1, 0, 0, 3,
+    ),
+    "two-feed-columns-two-fetches": (
+        "map_blocks",
+        lambda: _resident(
+            {"x": _ints(80), "y": _ints(80, mod=7)}, [20] * 4
+        ),
+        _two_fetches, "off", 1, 4, 0, 0, 0, 0,
+    ),
+    "map_rows-dense-route": (
+        "map_rows", lambda: _resident({"x": _ints(60, width=3)}, [20] * 3),
+        _rows_times_two, "off", 1, 3, 0, 0, 0, 0,
+    ),
+    # controls: no run of two, no resident column, more than one device
+    "control-one-block": (
+        "map_blocks", lambda: _resident({"x": _ints(40)}, [40]),
+        _times_two, "off", 0, 0, 0, 0, 1, 0,
+    ),
+    "control-no-neighbour-shares-a-size": (
+        "map_blocks", lambda: _resident({"x": _ints(38)}, [10, 9, 10, 9]),
+        _times_two, "off", 0, 0, 4, 0, 0, 26,
+    ),
+    "control-numpy-column": (
+        "map_blocks",
+        lambda: tfs.TensorFrame.from_dict({"x": _ints(40)}, num_blocks=4),
+        _times_two, "off", 0, 0, 0, 4, 0, 24,
+    ),
+    "control-numpy-column-beside-a-resident-one": (
+        "map_blocks",
+        lambda: tfs.TensorFrame(
+            [_resident({"x": _ints(40)}, [40])["x"],
+             tfs.Column("y", _ints(40, mod=7))],
+            [0, 10, 20, 30, 40],
+        ),
+        _two_fetches, "off", 0, 0, 0, 4, 0, 24,
+    ),
+    "control-scheduler-over-devices": (
+        "map_blocks", lambda: _resident({"x": _ints(100)}, [10] * 10),
+        _times_two, "auto", 0, 0, 10, 0, 0, 60,
+    ),
+}
+
+
+def _run_columns(verb, fetch, df, **kw):
+    out = getattr(tfs, verb)(fetch, df, **kw)
+    names = ["z", "w"] if isinstance(fetch, list) else ["z"]
+    return {n: np.asarray(out[n].values) for n in names}, out
+
+
+class TestBlockGroup:
+    @pytest.mark.parametrize("case", sorted(_GROUP_CASES))
+    def test_bit_identical_to_the_block_loop(self, case):
+        from tensorframes_tpu.utils import telemetry as tele
+
+        (verb, make, fetch_of, scheduler, groups, covered, windows, padded,
+         first, pad_rows) = _GROUP_CASES[case]
+        df = make()
+        fetch = fetch_of(df)
+        # the block loop, unbucketed, and the block loop on the ladder
+        # (a scheduler keeps it): both ways the program sees each block
+        with tfs.config.override(shape_bucketing=False):
+            want, _ = _run_columns(verb, fetch, df)
+        with tfs.config.override(block_scheduler="on"):
+            laddered, _ = _run_columns(verb, fetch, df, executor=Executor())
+        tele.reset()
+        with tfs.config.override(block_scheduler=scheduler):
+            got, out = _run_columns(verb, fetch, df, executor=Executor())
+        for name in want:
+            np.testing.assert_array_equal(got[name], want[name])
+            np.testing.assert_array_equal(got[name], laddered[name])
+        assert out.offsets == df.offsets
+        c = _group_counters()
+        assert (c["group_dispatch"] or 0, c["grouped_blocks"] or 0) == (
+            groups, covered
+        )
+        assert (c["window_dispatch"] or 0, c["padded_dispatch"] or 0) == (
+            windows, padded
+        )
+        assert (c["first_size_dispatch"] or 0) == first
+        # a group counts its pad rows too, as none: a frame that is one
+        # run reads 0, not nothing (`pad_rows_pct`)
+        assert c["pad_rows"] == pad_rows
+
+    def test_one_dispatch_one_span_one_part(self):
+        """A frame that is one run: one dispatch span with the run's
+        blocks and rows under the stage span, no pad, unpad, cut or
+        concat, and `pad_rows` counted as 0."""
+        from tensorframes_tpu.utils import telemetry as tele
+
+        df = _resident({"x": _ints(2000)}, [10] * 200)
+        with tfs.config.override(block_scheduler="off"):
+            tfs.map_blocks(_times_two(df), df, executor=Executor())
+        ss = tele.spans()
+        (block,) = [s for s in ss if s.name == "map_blocks.block"]
+        (stage,) = [s for s in ss if s.name == "map_blocks.blocks"]
+        assert block.kind == "dispatch" and block.parent_id == stage.span_id
+        assert (block.attrs["blocks"], block.attrs["rows"]) == (200, 2000)
+        assert block.attrs["bucket"] == 2000 and block.attrs["block"] == 0
+        assert not {s.name for s in ss} & {
+            "shape.pad", "shape.unpad", "frame.cut", "frame.concat"
+        }
+        assert tele.flat_counters()["shape_bucketing.pad_rows"] == 0
+        bk = tfs.diagnostics(format="json")["bucketing"]
+        assert (bk["group_dispatches"], bk["grouped_blocks"]) == (1, 200)
+        (line,) = [l for l in tfs.diagnostics().splitlines()
+                   if l.startswith("bucketing:")]
+        assert "1 group dispatch(es) over 200 equal block(s)" in line
+
+    def test_group_keeps_the_module_name(self, monkeypatch):
+        """The group's program is the verb's program in a loop: in a
+        device trace it is still `jit_fn`, so the benchmark's
+        `program_roofline` reads it and `copy_device_pct` does not."""
+        import re
+
+        made, compile_exact = [], sp._compile_exact
+
+        def spy(*args):
+            made.append(compile_exact(*args))
+            return made[-1]
+
+        monkeypatch.setattr(sp, "_compile_exact", spy)
+        with tfs.config.override(block_scheduler="off"):
+            for verb, fetch_of, width in (
+                ("map_blocks", _times_two, None),
+                ("map_rows", _rows_times_two, 3),
+            ):
+                df = _resident({"x": _ints(40, width=width)}, [10] * 4)
+                getattr(tfs, verb)(fetch_of(df), df, executor=Executor())
+        assert len(made) == 2
+        for compiled in made:
+            text = compiled.as_text()
+            assert re.search(r"HloModule (\w+)", text).group(1) == "jit_fn"
+            assert " while(" in text  # one loop over the blocks
+
+    def test_compiles_bounded_over_drifting_runs(self, monkeypatch):
+        """Frames whose equal blocks keep changing in size and count: a
+        program holds no more group executables than its ledger has
+        lines, compiles at first sight only while the ledger has room,
+        and after that only a run that came back."""
+        made, compile_group = [], sp._compile_group
+
+        def spy(book, n, k, avals, device):
+            made.append((n, k))
+            return compile_group(book, n, k, avals, device)
+
+        monkeypatch.setattr(sp, "_compile_group", spy)
+        ex = Executor()
+
+        def call(n, k, tail=()):
+            df = _resident({"x": _ints(n * k + sum(tail))}, [n] * k + list(tail))
+            with tfs.config.override(shape_bucketing=False):
+                want = np.asarray(tfs.map_blocks(_times_two(df), df)["z"].values)
+            got = tfs.map_blocks(_times_two(df), df, executor=ex)["z"].values
+            np.testing.assert_array_equal(np.asarray(got), want)
+
+        with tfs.config.override(
+            block_scheduler="off", executor_cache_entries=3
+        ):
+            for n, k in [(10, 4), (11, 4), (10, 5)]:
+                call(n, k)
+            assert made == [(10, 4), (11, 4), (10, 5)]
+            call(10, 4)  # held: nothing compiles
+            call(10, 4, tail=(3,))  # the same run in a longer column
+            assert made[3:] == []  # ... a fourth signature: the ledger is full
+            (book,) = [e.ledger for e in ex.programs()]
+            assert len(book.groups) == 3
+            # drift: sizes and counts that never repeat compile nothing more
+            shapes0 = ex.jit_shape_compiles()
+            for n, k in [(12, 3), (13, 6), (14, 2), (15, 7), (9, 9), (17, 3)]:
+                call(n, k)
+            assert made[3:] == [] and len(book.groups) == 3
+            # ... and ran block by block on the ladder (rungs 16 and 32)
+            assert ex.jit_shape_compiles() - shapes0 <= 2
+            c = _group_counters()
+            assert c["group_dispatch"] == 4 and c["window_dispatch"] == (
+                (4 + 1) + 3 + 6 + 2 + 7 + 9 + 3  # the tail's block too
+            )
+            # a run that comes back while its line is held earns its program
+            call(17, 3)
+            assert made[3:] == [(17, 3)]
+            call(17, 3)
+            assert made[3:] == [(17, 3)] and len(book.groups) == 3
+            assert _group_counters()["group_dispatch"] == 6
+        # the lines go with the cache entry
+        ex.clear()
+        assert not ex.programs()
+
+    @pytest.mark.parametrize("fault", ["resource", "transient"])
+    def test_faults_keep_their_meaning(self, fault):
+        """A transient fault retries the group; a group that runs out of
+        memory hands its run back to the block loop, which may split."""
+        from tensorframes_tpu.testing import faults as chaos
+
+        df = _resident({"x": _ints(50)}, [10] * 5)
+        with tfs.config.override(shape_bucketing=False):
+            want = np.asarray(tfs.map_blocks(_times_two(df), df)["z"].values)
+        with tfs.config.override(block_scheduler="off"):
+            with chaos.inject(nth=[0], fault=fault) as plan:
+                got = tfs.map_blocks(_times_two(df), df, executor=Executor())
+        np.testing.assert_array_equal(np.asarray(got["z"].values), want)
+        assert plan.faulted_ordinals == [0]
+        c = _group_counters()
+        stats = executor_stats()["faults"]
+        if fault == "transient":
+            assert plan.dispatches == 2  # the group, and the group again
+            assert (c["group_dispatch"], c["window_dispatch"]) == (1, None)
+            assert not stats["forensics"]
+        else:
+            assert plan.dispatches == 1 + 5  # the group, then its blocks
+            assert (c["group_dispatch"], c["window_dispatch"]) == (1, 5)
+            (snap,) = stats["forensics"]
+            assert snap["decision"] == "split:5 blocks of 10 rows, one by one"
+            assert (snap["rows"], snap["depth"]) == (50, 0)
+            assert stats["splits"] == 1
+
+    def test_numerics_and_row_checks_see_the_group(self):
+        """`check_numerics` names the run's blocks, and the outputs a
+        group gives are held to the run's rows."""
+        df = _resident(
+            {"x": np.array([1.0] * 10 + [0.0] * 10, np.float32)}, [5] * 4
+        )
+        fetch = (1.0 / tfs.block(df, "x")).named("z")
+        with tfs.config.override(block_scheduler="off", check_numerics=True):
+            with pytest.raises(Exception, match=r"map_blocks blocks \[0:4\)"):
+                tfs.map_blocks(fetch, df, executor=Executor())
 
 
 # ---------------------------------------------------------------------------
@@ -485,7 +770,7 @@ def _promo_rent_under_price(p):
 
 def _promo_multi_block_keeps_window(p):
     p.price = 0.0
-    df = p.frame(100, sizes=[10] * 10)
+    df = p.frame(95, sizes=[10, 9] * 5)
     for _ in range(3):
         p.call(df)
     c = p.counters()

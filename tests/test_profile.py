@@ -456,16 +456,24 @@ class TestBucketFill:
 
     def test_diagnostics_pad_waste_line(self):
         ex = Executor()
-        df = _frame(rows=4100, blocks=8)
+        whole = _frame(rows=4106, blocks=1)
+        df = tfs.TensorFrame(
+            [whole["x"]],
+            [int(v) for v in np.cumsum(
+                [0, 513, 512, 514, 512, 515, 512, 516, 512]
+            )],
+        )
         tfs.map_blocks(
             (tfs.block(df, "x") * float(next(_UNIQ) + 2)).named("y"),
             df, executor=ex,
         )
         data = tfs.diagnostics(format="json")
         bk = data["bucketing"]
-        # a resident frame of several blocks: each block off its rung
-        # (513 rows; the 512-row ones are on theirs) is a window of the
-        # column (`shape_policy.block_feeds`), none a replicated pad
+        # a resident frame of several blocks, no two neighbours of one
+        # size (a run of equal blocks is one group, not windows): each
+        # block off its rung (513 to 516 rows; the 512-row ones are on
+        # theirs) is a window of the column
+        # (`shape_policy.block_feeds`), none a replicated pad
         assert bk["window_dispatches"] == 4 and bk["padded_dispatches"] == 0
         assert bk["pad_rows"] > 0
         assert 0.0 < bk["fill"]["map_blocks"]["mean"] <= 1.0

@@ -5,10 +5,11 @@ The configuration is a dict with a published ``config.json``'s keys
 ``num_attention_heads``, ``num_key_value_heads``, ``intermediate_size``,
 ``moe_intermediate_size``, ``num_experts``, ``num_experts_per_tok``,
 ``conv_L_cache``, ``rope_theta``, ``norm_eps``, ``vocab_size``, the
-router's options), under either of two families' names for them
+router's options), under any of three families' names for them
 (`family_keys`: ``first_k_dense_replace``, ``n_routed_experts``,
-``rms_norm_eps``, ``scoring_func``, ``topk_method``): there is no class
-per model. A layer is ``r = h + Op(RMSNorm(h))``,
+``rms_norm_eps``, ``scoring_func``, ``topk_method``,
+``hybrid_override_pattern``): there is no class per model. A layer is
+``r = h + Op(RMSNorm(h))``,
 ``h' = r + FFN(RMSNorm(r))`` with ``Op`` by ``layer_types[i]`` — "conv",
 the gated short convolution; "full_attention", grouped-query attention
 with RoPE and per-head q/k norms; or "latent_attention" (every layer of a
@@ -21,6 +22,15 @@ for all heads, values of ``v_head_dim``. Both attentions run on
 first ``num_dense_layers`` layers, the expert layer of `models.moe` in the
 others, with ``n_shared_experts`` shared experts (one SwiGLU of their
 summed width) added for every token where the configuration has them.
+A configuration with ``hybrid_override_pattern`` has layers of ONE normed
+mixer, ``h' = h + Op(RMSNorm(h))`` with no FFN half: "M" the Mamba-2 mixer
+("ssm": in projection to ``[z | x B C | dt]``, a causal depthwise
+convolution and SiLU over ``x B C``, the state-space scan of
+`ops.pallas_kernels.ssd_scan`, a gate, a grouped RMSNorm, out projection),
+"*" attention (without q/k norms or RoPE in that family), "E" the expert
+layer as the layer's operator ("experts": relu² experts of two matrices in
+a ``moe_latent_size`` latent the input is projected into and their sum
+out of, beside a shared expert of its own width on the residual width).
 
 Parameters are one pytree of arrays stacked by kind (every conv part's
 ``w_in`` in one array, every expert layer's ``w_up`` in one, ...), and all
@@ -53,15 +63,18 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..ops.pallas_kernels import flash_attention
+from ..ops.pallas_kernels import flash_attention, ssd_scan
 from . import moe
 
 __all__ = [
     "init_params", "scoring_fn", "score", "layer_plan", "held_all", "family_keys",
 ]
 
-OPS = ("conv", "full_attention", "latent_attention")
+OPS = ("conv", "full_attention", "latent_attention", "ssm", "experts")
 ATTENTION = (1, 2)  # the operator kinds that attend
+SSM, EXPERTS = OPS.index("ssm"), OPS.index("experts")
+NO_FFN = -1  # a layer of one mixer: no FFN half
+PATTERN = {"M": "ssm", "*": "full_attention", "E": "experts"}
 HEAD_CHUNK = 2048  # tokens whose logits exist at one time
 
 
@@ -71,22 +84,69 @@ def family_keys(config) -> dict:
     ``n_routed_experts`` ``num_experts``, ``rms_norm_eps`` ``norm_eps``,
     ``scoring_func`` ``router_score``, ``topk_method: noaux_tc`` a
     per-expert bias in the choice; without ``layer_types`` every one of
-    ``num_hidden_layers`` is latent attention. What this module does not
-    compute raises, by its key: a group-limited expert choice, RoPE
-    length scaling."""
+    ``num_hidden_layers`` is latent attention. ``hybrid_override_pattern``
+    (a character a layer: "M", "*", "E") gives layers of one mixer
+    (``layer_types`` "ssm", "full_attention", "experts"; ``one_mixer``),
+    attention without q/k norms or RoPE (``qk_norm``, ``rope``), a
+    per-expert bias in the choice, ``layer_norm_epsilon`` as ``norm_eps``
+    and ``mlp_hidden_act: relu2`` as the FFNs' activation (``ffn_act``).
+    What this module does not compute raises, by its key: a
+    group-limited expert choice, RoPE length scaling, a "-" layer, a
+    bias in a projection, an activation it does not know."""
     c = dict(config)
+    hybrid = "hybrid_override_pattern" in c
+    if hybrid:
+        pattern = str(c["hybrid_override_pattern"])
+        unknown = sorted(set(pattern) - set(PATTERN))
+        if unknown:
+            raise ValueError(
+                f"hybrid_override_pattern has {unknown}: layers are 'M', '*' or 'E' "
+                "here ('-', a dense FFN as a layer of its own, is not computed)")
+        if int(c.get("num_hidden_layers", len(pattern))) != len(pattern):
+            raise ValueError(
+                f"num_hidden_layers = {c['num_hidden_layers']}: "
+                f"hybrid_override_pattern has {len(pattern)} layers")
+        c["layer_types"] = [PATTERN[ch] for ch in pattern]
+        c["num_dense_layers"] = 0
+        for key, value in (("one_mixer", True), ("qk_norm", False), ("rope", False),
+                           ("use_expert_bias", True)):
+            c.setdefault(key, value)
     if "layer_types" not in c:
         if "kv_lora_rank" not in c:
-            raise ValueError("a configuration gives layer_types or kv_lora_rank")
+            raise ValueError(
+                "a configuration gives layer_types, kv_lora_rank or "
+                "hybrid_override_pattern")
         c["layer_types"] = ["latent_attention"] * int(c["num_hidden_layers"])
+    eps = {k: float(c[k]) for k in ("norm_eps", "rms_norm_eps", "layer_norm_epsilon")
+           if k in c}
+    if len(set(eps.values())) > 1:
+        raise ValueError(f"{eps}: the norms have one epsilon here")
     for ours, theirs in (("num_dense_layers", "first_k_dense_replace"),
                          ("num_experts", "n_routed_experts"),
                          ("norm_eps", "rms_norm_eps"),
+                         ("norm_eps", "layer_norm_epsilon"),
                          ("router_score", "scoring_func")):
         if ours not in c and theirs in c:
             c[ours] = c[theirs]
     if "use_expert_bias" not in c and "topk_method" in c:
         c["use_expert_bias"] = c["topk_method"] == "noaux_tc"
+    if "ffn_act" not in c and "mlp_hidden_act" in c:
+        act = c["mlp_hidden_act"]
+        if act not in ("silu", "relu2"):
+            raise ValueError(f"mlp_hidden_act = {act!r}: 'silu' (SwiGLU) or 'relu2' here")
+        c["ffn_act"] = "relu2" if act == "relu2" else "swiglu"
+    for key in ("use_bias", "mlp_bias", "attention_bias", "mamba_proj_bias"):
+        if c.get(key):
+            raise ValueError(f"{key} = {c[key]!r}: a bias in a projection is not computed here")
+    if hybrid:
+        for key, want in (("mamba_hidden_act", "silu"), ("use_conv_bias", True),
+                          ("sliding_window", None)):
+            if c.get(key, want) != want:
+                raise ValueError(f"{key} = {c[key]!r}: only {want!r} is computed here")
+        inner = int(c["mamba_num_heads"]) * int(c["mamba_head_dim"])
+        if "expand" in c and inner != int(c["expand"]) * int(c["hidden_size"]):
+            raise ValueError(
+                f"expand = {c['expand']}: mamba_num_heads x mamba_head_dim is {inner}")
     for key in ("n_group", "topk_group"):
         if int(c.get(key) or 1) != 1:
             raise ValueError(
@@ -105,7 +165,9 @@ def held_all(config) -> Tuple[int, int]:
 
 def layer_plan(config):
     """Per layer: (operator kind, index in that kind's stack, 1 if the FFN
-    is the expert layer, index in that FFN kind's stack), as int32 rows."""
+    is the expert layer, index in that FFN kind's stack), as int32 rows;
+    a layer of one mixer has `NO_FFN` for its FFN's kind (its expert
+    layers are operators, kind `EXPERTS`)."""
     config = family_keys(config)
     types = list(config["layer_types"])
     dense = int(config["num_dense_layers"])
@@ -113,9 +175,15 @@ def layer_plan(config):
     rows = []
     for i, t in enumerate(types):
         is_moe = int(i >= dense)
-        rows.append((OPS.index(t), seen[t], is_moe, i - dense if is_moe else i))
+        ffn = (NO_FFN, 0) if config.get("one_mixer") else (is_moe, i - dense if is_moe else i)
+        rows.append((OPS.index(t), seen[t]) + ffn)
         seen[t] += 1
     return np.asarray(rows, dtype=np.int32).reshape(len(types), 4)
+
+
+def _expert_layers(plan):
+    """The layers with experts, as operator or as FFN."""
+    return np.flatnonzero((plan[:, 0] == EXPERTS) | (plan[:, 2] == 1)).astype(np.int32)
 
 
 def _head_dim(config) -> int:
@@ -141,7 +209,31 @@ def _latent_shapes(config) -> Dict[str, Tuple[int, ...]]:
 
 
 def _shared_width(config) -> int:
-    return int(config.get("n_shared_experts") or 0) * int(config["moe_intermediate_size"])
+    if not int(config.get("n_shared_experts") or 0):
+        return 0
+    return int(config.get("moe_shared_expert_intermediate_size") or
+               config["n_shared_experts"] * config["moe_intermediate_size"])
+
+
+def _ssm_sizes(config):
+    """(heads, head width, groups, state, kernel) of the Mamba-2 mixer."""
+    heads, width = int(config["mamba_num_heads"]), int(config["mamba_head_dim"])
+    return (heads, width, int(config["n_groups"]), int(config["ssm_state_size"]),
+            int(config["conv_kernel"]))
+
+
+def _ssm_shapes(config, std) -> Dict[str, Tuple]:
+    """One Mamba-2 mixer's arrays and how each is drawn: ``w_in`` holds
+    ``[z | x B C | dt]`` side by side, as the family's checkpoints do."""
+    d = int(config["hidden_size"])
+    heads, width, groups, state, k = _ssm_sizes(config)
+    inner, conv = heads * width, heads * width + 2 * groups * state
+    return {
+        "w_in": ((d, inner + conv + heads), std),
+        "conv_w": ((k, conv), float(1.0 / np.sqrt(k))), "conv_b": ((conv,), std),
+        "dt_bias": ((heads,), "dt_bias"), "A_log": ((heads,), "A_log"),
+        "D": ((heads,), "one"), "norm": ((inner,), None), "w_out": ((inner, d), std),
+    }
 
 
 def init_params(config, seed: int, held: Optional[Tuple[int, int]] = None):
@@ -150,9 +242,11 @@ def init_params(config, seed: int, held: Optional[Tuple[int, int]] = None):
     convolution's taps normal(0, 1/sqrt(kernel)), the router bias
     normal(0, ``router_bias_range``), the experts' down projections
     normal(0, ``expert_out_range``) and the latent queries' up projection
-    normal(0, ``query_out_range``) where the configuration gives one.
-    ``held = (first, count)`` makes only those experts' weights (the
-    router keeps its full width)."""
+    normal(0, ``query_out_range``) where the configuration gives one; of a
+    Mamba-2 mixer ``A_log`` the log of uniform(1, 16), ``dt_bias`` the
+    inverse softplus of log-uniform(``time_step_min``, ``time_step_max``)
+    floored at ``time_step_floor``, ``D`` 1. ``held = (first, count)``
+    makes only those experts' weights (the router keeps its full width)."""
     config = family_keys(config)
     d, v = int(config["hidden_size"]), int(config["vocab_size"])
     heads, kv = int(config["num_attention_heads"]), int(config["num_key_value_heads"])
@@ -164,11 +258,19 @@ def init_params(config, seed: int, held: Optional[Tuple[int, int]] = None):
     n_conv = int(np.sum(plan[:, 0] == 0))
     n_attn = int(np.sum(plan[:, 0] == 1))
     n_mla = int(np.sum(plan[:, 0] == 2))
-    n_moe = int(np.sum(plan[:, 2]))
-    n_dense = len(plan) - n_moe
+    n_ssm = int(np.sum(plan[:, 0] == SSM))
+    n_moe = len(_expert_layers(plan))
+    n_dense = int(np.sum(plan[:, 2] == 0))
     dtype = jnp.dtype(config.get("dtype", "bfloat16"))
     std = float(config.get("initializer_range", 0.02))
     bias_std = float(config.get("router_bias_range", 0.1))
+    # matrices side by side in an up projection; the experts' input width
+    side = 2 if config.get("ffn_act", "swiglu") == "swiglu" else 1
+    latent = int(config.get("moe_latent_size") or 0)
+    de = latent or d
+    qkv_std = std
+    if "query_out_range" in config and not n_mla:  # the queries' columns apart
+        qkv_std = ((heads * hd, float(config["query_out_range"])), (2 * kv * hd, std))
 
     shapes = {
         "embed": ((v, d), std), "head": ((d, v), std),
@@ -177,17 +279,22 @@ def init_params(config, seed: int, held: Optional[Tuple[int, int]] = None):
         "conv": {"w_in": ((n_conv, d, 3 * d), std),
                  "taps": ((n_conv, k, d), float(1.0 / np.sqrt(k))),
                  "w_out": ((n_conv, d, d), std)},
-        "attn": {"w_qkv": ((n_attn, d, (heads + 2 * kv) * hd), std),
+        "attn": {"w_qkv": ((n_attn, d, (heads + 2 * kv) * hd), qkv_std),
                  "q_norm": ((n_attn, hd), None), "k_norm": ((n_attn, hd), None),
                  "w_o": ((n_attn, heads * hd, d), std)},
-        "dense": {"w_up": ((n_dense, d, 2 * f), std),
+        "dense": {"w_up": ((n_dense, d, side * f), std),
                   "w_down": ((n_dense, f, d), std)},
         "moe": {"router": ((n_moe, d, e), std),
                 "bias": ((n_moe, e), bias_std),
-                "w_up": ((n_moe, count, d, 2 * fe), std),
-                "w_down": ((n_moe, count, fe, d),
+                "w_up": ((n_moe, count, de, side * fe), std),
+                "w_down": ((n_moe, count, fe, de),
                            float(config.get("expert_out_range", std)))},
     }
+    if not config.get("qk_norm", True):
+        del shapes["attn"]["q_norm"], shapes["attn"]["k_norm"]
+    if latent:
+        shapes["moe"]["latent_in"] = ((n_moe, d, latent), std)
+        shapes["moe"]["latent_out"] = ((n_moe, latent, d), std)
     if n_mla:
         scale = {"q_norm": None, "kv_norm": None,
                  "w_qb": float(config.get("query_out_range", std))}
@@ -195,20 +302,43 @@ def init_params(config, seed: int, held: Optional[Tuple[int, int]] = None):
             name: ((n_mla,) + shape, scale.get(name, std))
             for name, shape in _latent_shapes(config).items()
         }
+    if n_ssm:
+        shapes["ssm"] = {
+            name: ((n_ssm,) + shape, scale)
+            for name, (shape, scale) in _ssm_shapes(config, std).items()
+        }
+    if n_mla or config.get("one_mixer"):
         for kind, n in (("conv", n_conv), ("attn", n_attn)):
             if not n:  # this family has the stacks of the operators it has
                 del shapes[kind]
+    if config.get("one_mixer"):  # no FFN half: its norm and the dense FFN go
+        del shapes["ffn_norm"], shapes["dense"]
     fs = _shared_width(config)
     if fs:
-        shapes["moe"]["shared_up"] = ((n_moe, d, 2 * fs), std)
+        shapes["moe"]["shared_up"] = ((n_moe, d, side * fs), std)
         shapes["moe"]["shared_down"] = ((n_moe, fs, d), std)
     leaves, treedef = jax.tree_util.tree_flatten(
         shapes, is_leaf=lambda x: isinstance(x, tuple)
     )
+    step = tuple(float(config.get(key, value)) for key, value in (
+        ("time_step_min", 0.001), ("time_step_max", 0.1), ("time_step_floor", 1e-4)))
 
     def make(key, shape, scale):
         if scale is None:  # a norm's gain
             x = 1.0 + 0.05 * jax.random.normal(key, shape, jnp.float32)
+        elif isinstance(scale, tuple):  # (columns, scale) side by side
+            by_column = np.concatenate([np.full(n, s_, np.float32) for n, s_ in scale])
+            x = by_column * jax.random.normal(key, shape, jnp.float32)
+        elif scale == "one":
+            x = jnp.ones(shape, jnp.float32)
+        elif scale == "A_log":
+            x = jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
+        elif scale == "dt_bias":
+            lo, hi, floor = step
+            dt = jnp.exp(jax.random.uniform(
+                key, shape, jnp.float32, float(np.log(lo)), float(np.log(hi))))
+            dt = jnp.maximum(dt, jnp.float32(floor))
+            x = dt + jnp.log(-jnp.expm1(-dt))  # softplus(x) = dt
         else:
             x = jnp.float32(scale) * jax.random.normal(key, shape, jnp.float32)
         return x.astype(dtype)
@@ -279,11 +409,14 @@ def _attention_op(config, p, u, interpret):
         qkv = _matmul(u, p["w_qkv"]).reshape(rows, seq, heads + 2 * kv, hd)
         qkv = jnp.swapaxes(qkv, 1, 2)  # (rows, heads + 2 kv, seq, hd)
         q, k, v = qkv[:, :heads], qkv[:, heads:heads + kv], qkv[:, heads + kv:]
-        theta = float(config["rope_theta"])
-        q = _rope(_rms_norm(q, p["q_norm"], eps), theta)
-        k = _rope(_rms_norm(k, p["k_norm"], eps), theta)
+        if config.get("qk_norm", True):
+            q, k = _rms_norm(q, p["q_norm"], eps), _rms_norm(k, p["k_norm"], eps)
+        if config.get("rope", True):
+            theta = float(config["rope_theta"])
+            q, k = _rope(q, theta), _rope(k, theta)
         dtype = p["w_qkv"].dtype
-        block = min(512, max(8, seq))
+        # 1,024-blocks from 16,384 positions on, as the latent kernel's
+        block = min(1024 if seq >= 16384 else 512, max(8, seq))
         att = flash_attention(
             q.astype(dtype), k.astype(dtype), v.astype(dtype), causal=True,
             scale=float(1.0 / np.sqrt(hd)), block_q=block, block_k=block,
@@ -335,16 +468,59 @@ def _latent_attention_op(config, p, u, interpret):
             return _matmul(att, p["w_o"])
 
 
-def _dense_ffn(p, u):
-    """SwiGLU over the tokens ``u`` (..., d), in as many parts as keep the
-    up projection's float32 output and the activation within
-    `moe.PART_BYTES` (`moe.parts_for`, one expert a token)."""
+def _ssm_op(config, p, u, interpret):
+    """The Mamba-2 mixer: ``[z | x B C | dt] = u W_in``; a causal depthwise
+    convolution, its bias and SiLU over ``x B C``; ``Δ = softplus(dt +
+    dt_bias)``, ``A = -exp(A_log)``; the state-space scan (with the ``D``
+    skip) on `ssd_scan`; the gate ``y silu(z)``, then an RMSNorm over each
+    of the groups' shares of the inner width; ``W_out``."""
+    with jax.named_scope("lm.ssm"):
+        rows, seq, _ = u.shape
+        heads, width, groups, state, k = _ssm_sizes(config)
+        inner, conv = heads * width, heads * width + 2 * groups * state
+        dtype, f32 = p["w_in"].dtype, jnp.float32
+        with jax.named_scope("ssm.project"):
+            # the three column blocks apart: each leaves its matmul alone
+            z = _matmul(u, p["w_in"][:, :inner])
+            xbc = _matmul(u, p["w_in"][:, inner:inner + conv])
+            dt = _matmul(u, p["w_in"][:, inner + conv:])
+        with jax.named_scope("ssm.conv"):
+            # tap j weighs position t - (k - 1 - j): zeros to the left
+            padded = jnp.pad(xbc, ((0, 0), (k - 1, 0), (0, 0)))
+            xbc = sum(p["conv_w"][j].astype(f32) * padded[:, j:j + seq] for j in range(k))
+            xbc = jax.nn.silu(xbc + p["conv_b"].astype(f32)).astype(dtype)
+        dt = jax.nn.softplus(dt + p["dt_bias"].astype(f32))
+        with jax.named_scope("lm.ssd"):
+            y = ssd_scan(
+                xbc[..., :inner].reshape(rows, seq, heads, width), dt,
+                -jnp.exp(p["A_log"].astype(f32)),
+                xbc[..., inner:inner + groups * state].reshape(rows, seq, groups, state),
+                xbc[..., inner + groups * state:].reshape(rows, seq, groups, state),
+                p["D"].astype(f32), chunk=int(config["chunk_size"]), interpret=interpret,
+            )
+        g = y.reshape(rows, seq, inner).astype(f32) * jax.nn.silu(z)
+        # a group's share of the inner width at a time: slices along the
+        # lanes, where a (groups, width) reshape is a relayout copy each way
+        # (6.5 ms a layer at 32,768 x 8,192: my chip run, PR 35)
+        per, eps = inner // groups, float(config["norm_eps"])
+        shares = [g[..., j * per:(j + 1) * per] for j in range(groups)]
+        g = jnp.concatenate(
+            [s * lax.rsqrt(jnp.mean(s * s, axis=-1, keepdims=True) + eps) for s in shares],
+            axis=-1) * p["norm"].astype(f32)
+        with jax.named_scope("ssm.project"):
+            return _matmul(g, p["w_out"])
+
+
+def _dense_ffn(p, u, act: str = "swiglu"):
+    """An FFN of two or (SwiGLU) three matrices over the tokens ``u`` (...,
+    d), in as many parts as keep the up projection's float32 output and
+    the activation within `moe.PART_BYTES` (`moe.parts_for`, one expert a
+    token)."""
     d, up = p["w_up"].shape
-    f = up // 2
+    f = p["w_down"].shape[0]
 
     def part(x):
-        h = _matmul(x, p["w_up"])
-        return _matmul(jax.nn.silu(h[..., :f]) * h[..., f:], p["w_down"])
+        return _matmul(moe.activation(act, _matmul(x, p["w_up"])), p["w_down"])
 
     flat = u.reshape(-1, d)
     n = moe.parts_for(flat.shape[0], 1, d, up, f, p["w_up"].dtype.itemsize)
@@ -360,6 +536,7 @@ def _moe_ffn(config, stacks, i, u, held):
     p = _at({k: v for k, v in stacks.items() if k not in big}, i)
     rows, seq, d = u.shape
     e, top_k = int(config["num_experts"]), int(config["num_experts_per_tok"])
+    act = config.get("ffn_act", "swiglu")
     flat = u.reshape(rows * seq, d)
     idx, w = moe.route(
         flat, p["router"], p["bias"] if config.get("use_expert_bias") else None,
@@ -367,13 +544,21 @@ def _moe_ffn(config, stacks, i, u, held):
         norm_topk=bool(config.get("norm_topk_prob", True)),
         scale=float(config.get("routed_scaling_factor", 1.0)),
     )
+    x = flat  # the router reads the input; so do the experts, or a latent of it
+    if "latent_in" in p:
+        with jax.named_scope("moe.latent"):
+            x = _matmul(flat, p["latent_in"])
     y = moe.held_experts(
-        flat.astype(stacks["w_up"].dtype), idx, w, stacks["w_up"], stacks["w_down"],
-        held, layer=i,
+        x.astype(stacks["w_up"].dtype), idx, w, stacks["w_up"], stacks["w_down"],
+        held, act=act, layer=i, experts=e,
     )
+    if "latent_out" in p:
+        with jax.named_scope("moe.latent"):
+            y = _matmul(y, p["latent_out"])
     if "shared_up" in p:  # once for every token, whatever is held here
         with jax.named_scope("moe.shared"):
-            y = y + _dense_ffn({"w_up": p["shared_up"], "w_down": p["shared_down"]}, flat)
+            y = y + _dense_ffn(
+                {"w_up": p["shared_up"], "w_down": p["shared_down"]}, flat, act)
     load = jnp.sum(
         idx.reshape(rows, seq * top_k, 1) == jnp.arange(e, dtype=jnp.int32),
         axis=1, dtype=jnp.int32,
@@ -406,7 +591,8 @@ def _head(config, params, h, tokens):
 
 
 def _pick(branches: Dict, present, which, *args):
-    """`lax.switch` over the kinds a model has; one kind needs none."""
+    """`lax.switch` over the kinds a model has (``which`` counts among
+    ``present``); one kind needs none."""
     if len(present) == 1:
         return branches[present[0]](*args)
     return lax.switch(which, [branches[k] for k in present], *args)
@@ -418,8 +604,8 @@ def scoring_fn(
     """``fn(tokens, params) -> {"token_logprob", "expert_load",
     "expert_choice"}`` over a
     block of ``(rows, seq)`` token ids (the verb feeds the column named
-    as the parameter, ``tokens``). The attention kernel compiles for the
-    TPU; ``interpret=True`` (a CPU test, an example) interprets it, and
+    as the parameter, ``tokens``). The kernels compile for the
+    TPU; ``interpret=True`` (a CPU test, an example) interprets them, and
     nothing chooses that from the backend: a run on the chip is never an
     interpreted one without saying so."""
     config = family_keys(config)
@@ -427,9 +613,18 @@ def scoring_fn(
     plan = layer_plan(config)
     eps = float(config["norm_eps"])
     e, top_k = int(config["num_experts"]), int(config["num_experts_per_tok"])
+    act = config.get("ffn_act", "swiglu")
     ops_present = sorted(set(plan[:, 0].tolist()))
-    ffn_present = sorted(set(plan[:, 2].tolist()))
-    moe_layers = np.flatnonzero(plan[:, 2]).astype(np.int32)
+    ffn_present = sorted(set(plan[:, 2].tolist()) - {NO_FFN})
+    moe_layers = _expert_layers(plan)
+    # where a layer's kind stands among the kinds present: the switches' index
+    which = np.stack(
+        [np.searchsorted(ops_present, plan[:, 0]), plan[:, 1],
+         np.searchsorted(ffn_present or [0], plan[:, 2]), plan[:, 3]], axis=1,
+    ).astype(np.int32)
+    # an expert layer as a layer's operator: every operator then answers
+    # with the routing too (none, for the others)
+    routed_ops = EXPERTS in ops_present
 
     def lm_score(tokens, params):
         tokens = tokens.astype(jnp.int32)
@@ -437,30 +632,39 @@ def scoring_fn(
         with jax.named_scope("lm.embed"):
             h = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
 
+        def unrouted(y):
+            return (y, jnp.zeros((rows, e), jnp.int32),
+                    jnp.zeros((rows, seq, top_k), jnp.int32))
+
         ops = {
             0: lambda u, i: _conv_op(config, _at(params["conv"], i), u),
             1: lambda u, i: _attention_op(config, _at(params["attn"], i), u, bool(interpret)),
             2: lambda u, i: _latent_attention_op(
                 config, _at(params["mla"], i), u, bool(interpret)),
+            SSM: lambda u, i: _ssm_op(config, _at(params["ssm"], i), u, bool(interpret)),
         }
+        if routed_ops:
+            ops = {kind: (lambda u, i, op=op: unrouted(op(u, i))) for kind, op in ops.items()}
+            ops[EXPERTS] = lambda u, i: _moe_ffn(config, params["moe"], i, u, held)
         ffns = {
-            0: lambda u, i: (_dense_ffn(_at(params["dense"], i), u),
-                             jnp.zeros((rows, e), jnp.int32),
-                             jnp.zeros((rows, seq, top_k), jnp.int32)),
+            0: lambda u, i: unrouted(_dense_ffn(_at(params["dense"], i), u, act)),
             1: lambda u, i: _moe_ffn(config, params["moe"], i, u, held),
         }
 
         def layer(h, xs):
-            row, op_gain, ffn_gain = xs
-            r = h + _pick(ops, ops_present, row[0], _rms_norm(h, op_gain, eps), row[1])
-            y, load, choice = _pick(
-                ffns, ffn_present, row[2], _rms_norm(r, ffn_gain, eps), row[3]
-            )
-            return r + y, (load, choice)
+            row, gains = xs
+            y = _pick(ops, ops_present, row[0], _rms_norm(h, gains[0], eps), row[1])
+            if routed_ops:
+                y, *routed = y
+            h = h + y
+            if ffn_present:  # a layer of one mixer has no FFN half
+                y, *routed = _pick(
+                    ffns, ffn_present, row[2], _rms_norm(h, gains[1], eps), row[3])
+                h = h + y
+            return h, tuple(routed)
 
-        h, (loads, choices) = lax.scan(
-            layer, h, (jnp.asarray(plan), params["op_norm"], params["ffn_norm"])
-        )
+        gains = (params["op_norm"],) + ((params["ffn_norm"],) if ffn_present else ())
+        h, (loads, choices) = lax.scan(layer, h, (jnp.asarray(which), gains))
         return {
             "token_logprob": _head(config, params, h, tokens),
             "expert_load": jnp.swapaxes(loads[moe_layers], 0, 1),
@@ -473,19 +677,25 @@ def scoring_fn(
 def score(fn: Callable, frame, params, config, **verb_args):
     """``tfs.map_blocks(fn, frame, bindings={"params": params})`` with the
     model's counters: ``lm.tokens`` (rows x seq of the frame),
-    ``moe.routed_rows`` (tokens x experts per token x expert layers) and
-    ``lm.attention_pairs`` (causal query-key pairs x heads x attention
-    layers), all known on the host before the dispatch."""
+    ``moe.routed_rows`` (tokens x experts per token x expert layers),
+    ``moe.held_rows_expected`` (the routed rows x the share of the experts
+    whose weights ``params`` holds: what this holder's experts are expected
+    to compute), ``lm.attention_pairs`` (causal query-key pairs x heads x
+    attention layers) and ``lm.ssm_steps`` (tokens x state-space layers),
+    all known on the host before the dispatch."""
     from .. import api
     from ..utils import telemetry
 
+    config = family_keys(config)
     seq = int(frame.column("tokens").values.shape[1])
     tokens = frame.nrows * seq
     plan = layer_plan(config)
+    routed = tokens * int(config["num_experts_per_tok"]) * len(_expert_layers(plan))
     telemetry.counter_inc("lm.tokens", float(tokens))
+    telemetry.counter_inc("moe.routed_rows", float(routed))
     telemetry.counter_inc(
-        "moe.routed_rows",
-        float(tokens * int(config["num_experts_per_tok"]) * int(np.sum(plan[:, 2]))),
+        "moe.held_rows_expected",
+        float(routed * int(params["moe"]["w_up"].shape[1]) / int(config["num_experts"])),
     )
     telemetry.counter_inc(
         "lm.attention_pairs",
@@ -493,4 +703,5 @@ def score(fn: Callable, frame, params, config, **verb_args):
               * int(config["num_attention_heads"])
               * int(np.isin(plan[:, 0], ATTENTION).sum())),
     )
+    telemetry.counter_inc("lm.ssm_steps", float(tokens * int(np.sum(plan[:, 0] == SSM))))
     return api.map_blocks(fn, frame, bindings={"params": params}, **verb_args)

@@ -12,7 +12,13 @@ capacity. The layer takes its tokens in parts where the routed rows'
 temporaries would pass `PART_BYTES` (`parts_for`: from the routed rows
 and the experts' widths alone), each part grouped and multiplied by
 itself, so that a long block fits beside the weights; every token is
-still routed over all experts.
+still routed over all experts. A layer that holds a SHARE of the experts
+(``experts``, the router's width, above the held count) does its gathers
+and matmuls over the rows routed to its own experts alone: the sort puts
+the rows held elsewhere last, and a loop whose trip count is the held
+rows' takes the sorted order `STEP_ROWS` at a time and adds each row's
+weighted output to its token's (no place is kept for a routed row, so the
+layer is not taken in parts).
 Rows routed to experts held elsewhere add nothing here: their part is
 another holder's, and the parts of all holders add up to the whole layer
 (`apply_ep`: each shard calls the same local function with its own
@@ -40,12 +46,15 @@ from jax import lax
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
-__all__ = ["MoEFFN", "route", "held_experts", "parts_for"]
+__all__ = ["MoEFFN", "route", "held_experts", "parts_for", "activation"]
 
 # the most that one part's routed rows may take in temporaries (the
 # gathered rows, the up projection's float32 output and the activation
 # beside it, or the down projection's output and its copy in token order)
 PART_BYTES = 3 << 30
+# sorted rows a step of a held share's loop multiplies (the last step may
+# reach into the rows held elsewhere: they are in no group)
+STEP_ROWS = 8192
 
 
 def _along_rows(x, idx):
@@ -105,62 +114,119 @@ def group_rows(key, count):
 def parts_for(rows: int, k: int, d: int, up: int, f: int, itemsize: int) -> int:
     """In how many equal parts an expert layer takes ``rows`` tokens of
     ``k`` experts each: the fewest that divide ``rows`` and keep a part's
-    temporaries (a routed row's ``d`` inputs, ``up`` float32 outputs of
-    the up projection and ``f`` activations; or ``f`` activations, ``d``
-    float32 outputs and their copy in token order) within `PART_BYTES`."""
+    temporaries (a routed row's ``d`` inputs, the experts' input width,
+    ``up`` float32 outputs of the up projection and ``f`` activations; or
+    ``f`` activations, ``d`` float32 outputs and their copy in token
+    order) within `PART_BYTES`."""
     row_bytes = max(d * itemsize + 4 * up + f * itemsize, f * itemsize + 8 * d)
     least = -(-rows * k * row_bytes // PART_BYTES)
     return next(n for n in range(max(1, least), rows + 1) if rows % n == 0)
 
 
+def activation(act: str, h):
+    """An FFN's activation over its up projection ``h``: "swiglu" (gate
+    and up side by side, ``silu(gate) * up``), "relu2" (``relu(h)**2``) or
+    "gelu", the last two over one matrix's output."""
+    if act == "swiglu":
+        f = h.shape[-1] // 2
+        return jax.nn.silu(h[..., :f]) * h[..., f:]
+    if act == "relu2":
+        return jnp.square(jax.nn.relu(h))
+    if act == "gelu":
+        return jax.nn.gelu(h)
+    raise ValueError(f"activation {act!r}: 'swiglu', 'relu2' or 'gelu'")
+
+
 def held_experts(
-    u, idx, weight, w_up, w_down, held, *, gated: bool = True, layer=None,
+    u, idx, weight, w_up, w_down, held, *, act: str = "swiglu", layer=None,
+    experts: Optional[int] = None,
 ):
     """The held experts' part of the layer's output, ``(rows, d)`` float32.
 
-    ``u`` (rows, d) are the normed inputs, ``idx`` / ``weight`` the
+    ``u`` (rows, d) are the experts' inputs, ``idx`` / ``weight`` the
     router's choice over ALL experts. ``w_up`` is ``(count, d, 2f)``
-    (gate and up projections side by side: SwiGLU, ``gated``) or
-    ``(count, d, f)`` (GELU), ``w_down`` ``(count, f, d)``; expert ``e``
-    of the model is held at ``e - first``. ``first`` may be traced (a
-    shard's index). With ``layer`` (may be traced) the weights are the
+    (gate and up projections side by side: ``act`` "swiglu") or
+    ``(count, d, f)`` ("relu2", "gelu"), ``w_down`` ``(count, f, d)``;
+    expert ``e`` of the model is held at ``e - first``. ``first`` may be
+    traced (a shard's index). ``experts``, the router's width, says that
+    ``count`` below it is a share: the gathers and matmuls then run over
+    the rows routed to the held experts alone (the sorted order
+    `STEP_ROWS` at a time, as many steps as those rows take, their outputs
+    added to their tokens'); without it
+    the layer may hold everything and multiplies every routed row's
+    place. With ``layer`` (may be traced) the weights are the
     stacks of several layers, ``(layers, count, ...)``: the grouped matmul
     takes every layer's experts as its groups and the other layers' are
     empty, so no layer's weights are copied out of their stack."""
     first, count = held
     rows, k = idx.shape
     d = u.shape[-1]
+    share = experts is not None and count < experts
     if layer is not None:
         w_up = w_up.reshape((-1,) + w_up.shape[2:])
         w_down = w_down.reshape((-1,) + w_down.shape[2:])
 
-    def part(args):
-        u, idx, weight = args
+    def grouped(idx):
+        """(which routed rows are held here, `group_rows` of them by held
+        expert): the rows held elsewhere go last."""
         local = idx.reshape(-1).astype(jnp.int32) - jnp.asarray(first, jnp.int32)
         mine = (local >= 0) & (local < count)
-        # rows held elsewhere go last
-        order, back, sizes = group_rows(jnp.where(mine, local, count), count)
+        return (mine,) + tuple(group_rows(jnp.where(mine, local, count), count))
+
+    def multiply(u, places, sizes):
+        """The two grouped matmuls over the sorted rows at ``places``."""
         if layer is not None:  # this layer's groups among every layer's
             at = jnp.asarray(layer, jnp.int32) * jnp.int32(count)
             sizes = lax.dynamic_update_slice(
                 jnp.zeros(w_up.shape[0], jnp.int32), sizes, (at,)
             )
-        xs = jnp.take(u, order // k, axis=0)
+        xs = jnp.take(u, places // k, axis=0)
         h = lax.ragged_dot(xs, w_up, sizes, preferred_element_type=jnp.float32)
-        if gated:
-            f = w_up.shape[-1] // 2
-            a = jax.nn.silu(h[:, :f]) * h[:, f:]
-        else:
-            a = jax.nn.gelu(h)
-        y = lax.ragged_dot(
-            a.astype(u.dtype), w_down, sizes, preferred_element_type=jnp.float32
+        return lax.ragged_dot(
+            activation(act, h).astype(u.dtype), w_down, sizes,
+            preferred_element_type=jnp.float32,
         )
+
+    def held_rows(u, idx, weight):
+        """The part of the held experts, over their rows alone: the first
+        of the sorted order, `STEP_ROWS` places a step, each step's groups
+        the part of every expert's rows that lies in it, each row's
+        weighted output added to its token's. No place is kept for a
+        routed row: the layer needs no parts."""
+        i32 = jnp.int32
+        _, order, _, sizes = grouped(idx)
+        n = order.shape[0]
+        step = min(STEP_ROWS, n)
+        order = jnp.pad(order, (0, (-n) % step))
+        ends = jnp.cumsum(sizes, dtype=i32)
+        starts = ends - sizes
+        flat = weight.reshape(-1).astype(jnp.float32)
+
+        def one(c, out):
+            lo = c * i32(step)
+            within = jnp.clip(ends, lo, lo + step) - jnp.clip(starts, lo, lo + step)
+            places = lax.dynamic_slice(order, (lo,), (step,))
+            y = multiply(u, places, within)
+            # the last step reaches into the rows held elsewhere: in no group
+            w = jnp.where(lo + lax.iota(i32, step) < ends[-1], jnp.take(flat, places), 0.0)
+            return out.at[places // k].add(jnp.where(w[:, None] != 0.0, y * w[:, None], 0.0))
+
+        steps = lax.div(ends[-1] + i32(step - 1), i32(step))
+        return lax.fori_loop(
+            i32(0), steps, one, jnp.zeros((rows, w_down.shape[-1]), jnp.float32))
+
+    def part(args):
+        u, idx, weight = args
+        mine, order, back, sizes = grouped(idx)
+        y = multiply(u, order, sizes)
         # back to (row, choice) order; a row no held expert computed is 0
         y = jnp.take(y, back, axis=0).reshape(idx.shape[0], k, -1)
         w = jnp.where(mine.reshape(idx.shape), weight, 0.0)
         return jnp.sum(jnp.where(w[..., None] != 0.0, y * w[..., None], 0.0), axis=1)
 
     with jax.named_scope("moe.experts"):
+        if share:
+            return held_rows(u, idx, weight)
         n = parts_for(
             rows, k, d, w_up.shape[-1], w_down.shape[-2], u.dtype.itemsize
         )
@@ -214,7 +280,7 @@ class MoEFFN:
         idx, w = self._choose(params["gate"], x)
         return held_experts(
             x, idx, w, params["w1"], params["w2"],
-            held or (0, self.num_experts), gated=False,
+            held or (0, self.num_experts), act="gelu",
         )
 
     def apply_ep(self, params, x, mesh: Mesh, axis: str = "model"):

@@ -23,7 +23,15 @@ It compiles for the TPU unless the caller passes ``interpret=True``
 itself, so a chip run cannot be an interpreted one without saying so.
 Production CPU paths use `parallel.ring.full_attention`.
 
-Differentiation: the forward pass is the kernel; the backward pass is
+`ssd_scan`: a state-space scan (Mamba-2's recurrence ``S_t = exp(Δ_t A)
+S_{t-1} + Δ_t x_t ⊗ B_t``, ``y_t = S_t C_t + D x_t``) computed a chunk of
+positions at a time: inside a chunk the recurrence is three matmuls (the
+scores ``C Bᵀ`` under the decay between two positions, the carried state's
+part, the state handed on), and the state, float32, stays in VMEM scratch
+from one chunk of a sequence to the next. The grid runs rows × groups in
+parallel and a sequence's chunks in order. No backward pass.
+
+Differentiation (`flash_attention`): the forward pass is the kernel; the backward pass is
 `full_attention`'s VJP, recomputed from q/k/v (`jax.custom_vjp` — a
 `pallas_call` has no transpose rule of its own). A fused backward
 kernel is ROADMAP Queue 2 item 3.
@@ -39,7 +47,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-__all__ = ["flash_attention"]
+__all__ = ["flash_attention", "ssd_scan"]
 
 _NEG_INF = -1e30
 
@@ -255,3 +263,136 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def _ssd_kernel(
+    x_ref, dt_ref, acol_ref, arow_ref, bt_ref, c_ref, d_ref, y_ref, state,
+    *, heads: int, width: int,
+):
+    """One chunk of one (row, group): ``heads`` heads of ``width`` side by
+    side in ``x_ref`` (chunk, heads * width). ``acol`` / ``arow`` hold the
+    chunk's running sum of Δ·A, positions down (chunk, heads) and across
+    (heads, chunk); ``bt`` is B transposed (state, chunk)."""
+    f32 = jnp.float32
+
+    @pl.when(pl.program_id(2) == 0)
+    def _start():
+        state[:] = jnp.zeros_like(state)
+
+    c, bt = c_ref[:], bt_ref[:]
+    chunk = c.shape[0]
+    # C_t · B_s, shared by the group's heads
+    scores = jnp.dot(c, bt, preferred_element_type=f32)
+    t = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    s = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    causal = t >= s
+    last = jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1) == chunk - 1
+    for h in range(heads):
+        lo = h * width
+        x = x_ref[:, lo:lo + width]
+        dtype = x.dtype
+        a_col = acol_ref[:, h:h + 1]  # (chunk, 1): sum of Δ·A up to t
+        a_row = arow_ref[h:h + 1, :]  # (1, chunk): the same, up to s
+        # differences are <= 0 where t >= s: nothing overflows
+        decay = jnp.exp(jnp.where(causal, a_col - a_row, f32(_NEG_INF)))
+        xdt = (dt_ref[:, h:h + 1] * x.astype(f32)).astype(dtype)
+        held = state[h]  # (state, width) float32
+        y = jnp.dot((scores * decay).astype(dtype), xdt, preferred_element_type=f32)
+        y = y + jnp.exp(a_col) * jnp.dot(
+            c, held.astype(dtype), preferred_element_type=f32)
+        y = y + d_ref[:, h:h + 1] * x.astype(f32)
+        y_ref[:, lo:lo + width] = y.astype(y_ref.dtype)
+        # the chunk's sum, (1, 1): a masked sum (a one-lane slice does not
+        # broadcast over sublanes and lanes on the chip)
+        a_end = jnp.sum(jnp.where(last, a_row, f32(0.0)), axis=-1, keepdims=True)
+        after = jnp.exp(a_end - a_row)  # the decay from s to the chunk's end
+        state[h] = jnp.exp(a_end) * held + jnp.dot(
+            (bt.astype(f32) * after).astype(dtype), xdt, preferred_element_type=f32)
+
+
+def _ssd_operands(x, dt, A, B, C, chunk):
+    """Padded to whole chunks (Δ = 0: a padded position decays nothing and
+    adds nothing) and laid out a group at a time: ``x`` (rows, seq, heads
+    * width), Δ and the running sum of Δ·A inside each chunk (rows, groups,
+    seq, heads a group), B and C (rows, seq, groups, state)."""
+    rows, seq, heads, width = x.shape
+    groups = B.shape[2]
+    if heads % groups:
+        raise ValueError(f"{heads} heads do not divide over {groups} groups")
+    per = heads // groups
+    pad = (-seq) % chunk
+    if pad:
+        grow = lambda a: jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        x, dt, B, C = grow(x), grow(dt), grow(B), grow(C)
+    n = (seq + pad) // chunk
+    dt = dt.astype(jnp.float32)
+    da = dt * A.astype(jnp.float32)
+    run = jnp.cumsum(da.reshape(rows, n, chunk, heads), axis=2).reshape(dt.shape)
+    by_group = lambda a: jnp.swapaxes(a.reshape(rows, seq + pad, groups, per), 1, 2)
+    return x.reshape(rows, seq + pad, heads * width), by_group(dt), by_group(run), B, C
+
+
+def ssd_scan(
+    x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array, C: jax.Array,
+    D: jax.Array, *, chunk: int = 128, interpret: bool = False,
+) -> jax.Array:
+    """The state-space scan ``S_t = exp(Δ_t A) S_{t-1} + Δ_t x_t ⊗ B_t``,
+    ``y_t = S_t C_t + D x_t`` from ``S_0 = 0``, a chunk of ``chunk``
+    positions at a time. ``x`` (rows, seq, heads, width); ``dt`` (rows,
+    seq, heads) float32, Δ after its softplus; ``A``, ``D`` (heads,), A
+    negative; ``B``, ``C`` (rows, seq, groups, state), head ``j`` using
+    group ``j // (heads // groups)``. ``y`` has ``x``'s shape and dtype.
+    Matmul operands are in ``x``'s dtype, sums, decays and the state in
+    float32. On the chip ``chunk`` is a multiple of 128 (or the whole
+    sequence); the function refuses to be differentiated."""
+    return _ssd(x, dt, A, B, C, D, int(chunk), bool(interpret))
+
+
+def _ssd_forward(x, dt, A, B, C, D, chunk, interpret):
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, seq, heads, width = x.shape
+    groups, size = B.shape[2], B.shape[3]
+    per = heads // groups
+    xg, dtg, run, B, C = _ssd_operands(x, dt, A, B, C, chunk)
+    padded = xg.shape[1]
+    # int32 zeros made inside the index maps (x64 would make a plain 0 int64)
+    across = pl.BlockSpec(
+        (None, None, chunk, per), lambda r, g, c: (r, g, c, jnp.int32(0)))
+    out = pl.pallas_call(
+        functools.partial(_ssd_kernel, heads=per, width=width),
+        grid=(rows, groups, padded // chunk),
+        in_specs=[
+            pl.BlockSpec((None, chunk, per * width), lambda r, g, c: (r, c, g)),
+            across, across,
+            pl.BlockSpec((None, None, per, chunk), lambda r, g, c: (r, g, jnp.int32(0), c)),
+            pl.BlockSpec((None, None, size, chunk), lambda r, g, c: (r, g, jnp.int32(0), c)),
+            pl.BlockSpec((None, chunk, size), lambda r, g, c: (r, c, g)),
+            pl.BlockSpec((None, 1, per), lambda r, g, c: (g, jnp.int32(0), jnp.int32(0))),
+        ],
+        out_specs=pl.BlockSpec((None, chunk, per * width), lambda r, g, c: (r, c, g)),
+        out_shape=jax.ShapeDtypeStruct(xg.shape, x.dtype),
+        scratch_shapes=[pltpu.VMEM((per, size, width), jnp.float32)],  # the state
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(
+        xg, dtg, run, jnp.swapaxes(run, 2, 3),
+        jnp.transpose(B, (0, 2, 3, 1)).astype(x.dtype),
+        C.reshape(rows, padded, groups * size).astype(x.dtype),
+        D.astype(jnp.float32).reshape(groups, 1, per),
+    )
+    return out[:, :seq].reshape(x.shape)
+
+
+_ssd = jax.custom_vjp(_ssd_forward, nondiff_argnums=(6, 7))
+
+
+def _ssd_fwd(*args):
+    raise NotImplementedError(
+        "ssd_scan has no backward pass: the chunked scan is a forward kernel "
+        "(scoring)"
+    )
+
+
+_ssd.defvjp(_ssd_fwd, lambda *a: None)

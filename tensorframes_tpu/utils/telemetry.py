@@ -974,8 +974,16 @@ _PROM_HELP: Dict[str, str] = {
         "Token rows routed to experts (tokens x experts per token x "
         "expert layers) by models.lm.score"
     ),
+    "moe.held_rows_expected": (
+        "Routed rows expected for the experts held here (routed rows x "
+        "held experts / experts) by models.lm.score"
+    ),
     "lm.attention_pairs": (
         "Causal query-key pairs (x heads x attention layers) attended by "
+        "models.lm.score"
+    ),
+    "lm.ssm_steps": (
+        "State-space scan steps (tokens x state-space layers) of "
         "models.lm.score"
     ),
     "fault_retries": "Classified dispatch retries by fault class",

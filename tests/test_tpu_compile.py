@@ -342,3 +342,83 @@ def test_the_latent_scoring_program_at_the_cells_block(one_chip):
     # a layer's 1.2 GB of experts stay where they are bound
     assert not re.search(r"= bf16\[(1,)?256,2048,1536\]\S* (fusion|copy|dynamic-slice)", text)
     assert not re.findall(r"\b[sufc](?:64|128)\[", text)
+
+
+def _nemotron_config():
+    """`nemotron-3-super-120b-a12b` as the benchmark's runner hands it to
+    the program: the router's published width and the share held here."""
+    import json
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from perf.runners.map_blocks_lm_hybrid import model_config
+
+    with open(os.path.join(root, "perf", "configs", "nemotron-3-super-120b-a12b.json")) as f:
+        config = json.load(f)
+    return (config,) + model_config(config, False)
+
+
+def test_state_space_scan_kernel_at_the_cells_shape(one_chip):
+    # one window of 32,768 positions: 128 heads of 64 in 8 groups, state
+    # 128, chunks of 128, bfloat16: as `models.lm` calls the kernel
+    from tensorframes_tpu.ops.pallas_kernels import ssd_scan
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    lowered, compiled = _compile(
+        lambda *a: ssd_scan(*a, chunk=128), one_chip,
+        ((1, 32768, 128, 64), bf16), ((1, 32768, 128), f32), ((128,), f32),
+        ((1, 32768, 8, 128), bf16), ((1, 32768, 8, 128), bf16), ((128,), f32),
+    )
+    assert "tpu_custom_call" in lowered.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
+
+
+def test_the_hybrid_scoring_program_at_the_cells_block(one_chip):
+    """`lm.scoring_fn` of `nemotron-3-super-120b-a12b` at the published
+    widths over one block of the cell (one window of 32,768 ids), experts
+    0-127 of 512 held, the weights arguments: it compiles for the chip; its
+    temporaries fit beside 8.66 GiB of weights; the operations that the
+    configuration's `kernel_ops.ssd_scan` and `kernel_ops.moe_experts`
+    match are in it under those names, the grouped matmuls over a step of
+    the held rows' loop and not over every routed row's place; no layer's
+    expert weights are copied out of their stack; and the program holds no
+    64-bit array."""
+    import re
+
+    from tensorframes_tpu.models import lm, moe
+
+    config, cfg, held = _nemotron_config()
+    assert held == (0, 128) and cfg["n_routed_experts"] == 512
+    shapes = jax.eval_shape(lambda: lm.init_params(cfg, 0, held))
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), shapes)
+    tokens = jax.ShapeDtypeStruct((1, 32768), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(lm.scoring_fn(cfg, held=held)).lower(tokens, params).compile()
+    memory = compiled.memory_analysis()
+    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(shapes))
+    assert 9.25e9 < weights < 9.35e9  # 4,648 M parameters in bfloat16: 8.66 GiB
+    assert weights + memory.temp_size_in_bytes < 15.0 * 2**30
+    assert memory.output_size_in_bytes < 32 * 2**20
+
+    from perf.lib.trace import op_label
+
+    text = compiled.as_text()
+    labels = [
+        op_label(line.strip().removeprefix("ROOT "))
+        for line in text.splitlines() if " = " in line
+    ]
+    scan = sorted({l for l in labels if re.search(config["kernel_ops"]["ssd_scan"], l)})
+    assert len(scan) == 1 and scan[0].endswith("bf16[1,32768,8192]"), scan
+    experts = sorted({l for l in labels
+                      if re.search(config["kernel_ops"]["moe_experts"], l)})
+    assert len(experts) == 2 and all(l.startswith("ragged-dot-none") for l in experts)
+    assert {l.split()[1] for l in experts} == {
+        f"f32[{moe.STEP_ROWS},2688]", f"f32[{moe.STEP_ROWS},1024]"}, experts
+    attention = [l for l in set(labels) if l.startswith("lm.attention")]
+    assert len(attention) == 1 and attention[0].endswith("bf16[1,32,32768,128]")
+    # a layer's 1.4 GB of held experts stay where they are bound
+    assert not re.search(r"= bf16\[(1,)?128,1024,2688\]\S* (fusion|copy|dynamic-slice)", text)
+    assert not re.findall(r"\b[sufc](?:64|128)\[", text)

@@ -821,15 +821,17 @@ def _run_blocks(
     outputs of a frame with no rows.
 
     A run of equal blocks is ONE dispatch, a group
-    (`shape_policy.group_dispatch`: one program that loops over the
-    run's blocks on the device, each at its exact shape), where the
-    call is ``bucketed`` on one device (no scheduler), nothing is bound
-    or trimmed, the outputs' names are known beforehand (a graph's
+    (`shape_policy.group_dispatch`: one pass of the program over the
+    run's rows, which is what its blocks give row for row because
+    ``bucketed`` programs are row-local), where the call is
+    ``bucketed`` on one device (no scheduler), nothing is bound or
+    trimmed, the outputs' names are known beforehand (a graph's
     program gives a sequence, a plain function a dict) and the columns
     are resident on that device (`shape_policy.block_runs`). A frame
     that is one run has one part and no concat. Any other block, and a
-    run whose group ran out of memory, goes through the loop one block
-    at a time.
+    run whose group ran out of memory (one pass holds the program's
+    temporaries at the run's rows), goes through the loop one block at
+    a time.
 
     Per dispatch, a block's or a group's: classified fault handling
     (`runtime.faults`: transient errors retry with backoff and fail

@@ -111,9 +111,9 @@ class ProgramLedger:
     and device the first row count asked for (it runs at its exact
     shape) and whether another has come since, and ``shapes``, per
     exact feed signature of those others the rent its pads have paid
-    and what it bought, and ``groups``, per run of equal blocks (their
-    size and count, the columns, the device) the one program that loops
-    over the run."""
+    and what it bought, and ``groups``, per run of equal blocks (its
+    rows, the columns, the device) the one pass of the program over the
+    run's rows."""
 
     __slots__ = ("key", "jitted", "compile_seconds", "rungs", "shapes",
                  "groups")

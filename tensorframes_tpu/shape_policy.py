@@ -134,44 +134,47 @@ a dispatch, an unpad, their spans and checks: about a millisecond) is
 many times the program's. Where every feed column is a `jax.Array`
 resident on one device, each maximal run of two or more non-empty
 blocks of one size ``n`` (empty blocks between them hold no rows and
-do not end it) is therefore dispatched ONCE: one jitted program that
-loops over the run's ``k`` blocks on the device. Step ``i`` takes
-``dynamic_slice_in_dim(col, lo + i * n, n)`` of every feed column
-(``lo`` a traced scalar, ``n`` and ``k`` static), runs the program's
-own `jax.jit` on it, at the block's exact shape and in block order as
-the per-block loop would, and writes what it gives at rows ``[i * n,
-(i + 1) * n)`` of output columns of ``k * n`` rows carried through the
-loop (uninitialised at its start, `jax.lax.empty`: every row is
-written by exactly one step). Who iterates changes, not what the
-program sees: no window, no pad row (``shape_bucketing.pad_rows``
-counts 0), no unpad, and for a frame that is one run no concat.
-``shape_bucketing.group_dispatch`` counts the groups,
-``shape_bucketing.grouped_blocks`` the blocks they covered. The
-group's function takes the program's function's name, so its XLA
+do not end it) is therefore dispatched ONCE, as ONE PASS of the
+program's own `jax.jit` over the run's rows:
+``dynamic_slice_in_dim(col, lo, k * n)`` of every feed column (``lo`` a
+traced scalar, so a run anywhere in a longer column shares the
+executable; where the run is the whole column XLA drops the slice),
+and the outputs are the program's at ``k * n`` rows. The proof is the
+one the ladder's pad rests on: both callers group only programs proven
+row-local (output row i depends on input row i alone), so the run's
+``k`` blocks give, row for row, what one pass over rows ``[lo, lo + k *
+n)`` gives; block boundaries are something such a program cannot see,
+as it cannot see pad rows, and the output frame keeps the input's
+offsets. No window, no pad row (``shape_bucketing.pad_rows`` counts 0),
+no unpad, no loop and no carried output on the device, and for a frame
+that is one run no concat. ``shape_bucketing.group_dispatch`` counts
+the groups, ``shape_bucketing.grouped_blocks`` the blocks they covered.
+The group's function takes the program's function's name, so its XLA
 module is named as the program's is (a graph's: ``jit_fn``). What
 decides is what the input shows: a block whose size no neighbour
 shares, numpy or sharded columns, a column too long for an int32 row
 index, a program with no ledger and a program whose outputs do not
-keep the block's rows run block by block as before, and so does every
+keep the run's rows run block by block as before, and so does every
 block under a scheduler, a trim or bound values (the caller's side,
-`api._run_blocks`). A RESOURCE fault in a group sends its run back to
-that loop, which may split rows.
-BOUND: a group executable is specific to ``(n, k)``, the columns'
-whole shapes and dtypes and the device, and is compiled on the calling
-thread at first sight, where the per-block program for a new ``n``
-would have compiled at that moment anyway. The lines are kept on the
-program's ledger (``ProgramLedger.groups``: evicted with the cache
-entry, at most ``config.executor_cache_entries`` lines a program,
-least recently seen out first), so a program holds no more group
-executables than that. A ledger that is full has seen that many
-distinct runs: the process drifts, and from then on a new signature
-takes the line of the least recently seen one but must come back
-while it holds it before it is compiled (until then its blocks run
-one by one, on the ladder). So a program compiles, beside the
-ladder's O(log max-block-rows) and each rung's first size, at most
-``config.executor_cache_entries`` groups at first sight and after
-that only groups that have repeated: sizes or counts that never
-repeat stop compiling when the ledger is full.
+`api._run_blocks`). One pass holds the program's temporaries at ``k *
+n`` rows where a block holds them at ``n``: a RESOURCE fault in a group
+sends its run back to the block loop, which may split rows.
+BOUND: a group executable is specific to the run's rows ``k * n`` (4 x
+10 and 5 x 8 share one), the columns' whole shapes and dtypes and the
+device, and is compiled on the calling thread at first sight, where
+the per-block program for a new ``n`` would have compiled at that
+moment anyway. The lines are kept on the program's ledger
+(``ProgramLedger.groups``: evicted with the cache entry, at most
+``config.executor_cache_entries`` lines a program, least recently seen
+out first), so a program holds no more group executables than that. A
+ledger that is full has seen that many distinct runs: the process
+drifts, and from then on a new signature takes the line of the least
+recently seen one but must come back while it holds it before it is
+compiled (until then its blocks run one by one, on the ladder). So a
+program compiles, beside the ladder's O(log max-block-rows) and each
+rung's first size, at most ``config.executor_cache_entries`` groups at
+first sight and after that only groups that have repeated: runs that
+never repeat stop compiling when the ledger is full.
 
 Exactness: map outputs, min/max, and integer-dtype reductions are
 bit-identical to unbucketed eager execution. Float sum/mean reduce over
@@ -758,7 +761,7 @@ def drain(executor=None, timeout: Optional[float] = None) -> bool:
 
 
 # ---------------------------------------------------------------------------
-# the block group: a run of equal blocks is one loop inside one program
+# the block group: a run of equal blocks is one pass over the run's rows
 # ---------------------------------------------------------------------------
 
 
@@ -797,15 +800,15 @@ def group_dispatch(
     """What to call on the feed ``columns`` themselves to run the
     executor's cached ``program`` over the ``k`` blocks of ``n`` rows
     from row ``lo`` on as one group: it returns the program's outputs
-    over those ``k * n`` rows. None where the run has no group executable
-    (a program with no ledger, outputs that do not keep the block's rows,
-    a compile that raised, a signature a full ledger has seen once): the
-    caller dispatches the run block by block."""
+    over those ``k * n`` rows, from one pass. None where the run has no
+    group executable (a program with no ledger, outputs that do not keep
+    the run's rows, a compile that raised, a signature a full ledger has
+    seen once): the caller dispatches the run block by block."""
     book = _program_ledger(program)
     if book is None:
         return None
     sig = (
-        n, k,
+        k * n,
         tuple((tuple(c.shape), np.dtype(c.dtype)) for c in columns),
         _resident_device(columns),
     )
@@ -834,7 +837,7 @@ class _Group:
 
 def _group(book, sig: Tuple) -> Optional[Callable]:
     """The group executable of the ledger ``book`` for the run signature
-    ``sig`` (``n``, ``k``, the columns' shapes and dtypes, the device),
+    ``sig`` (the run's rows, the columns' shapes and dtypes, the device),
     compiled here at first sight while the ledger has room, and once it
     is full at second sight (module docstring, BOUND)."""
     limit = _ledger_lines()
@@ -855,9 +858,9 @@ def _group(book, sig: Tuple) -> Optional[Callable]:
             from .utils.log import get_logger
 
             get_logger("shape_policy").warning(
-                "no group executable for program %s/%s over %d blocks of "
-                "%d rows, the run is dispatched block by block: %s: %s",
-                book.key[0], str(book.key[1])[:12], sig[1], sig[0],
+                "no group executable for program %s/%s over a run of %d "
+                "rows, the run is dispatched block by block: %s: %s",
+                book.key[0], str(book.key[1])[:12], sig[0],
                 type(e).__name__, e,
             )
         line.closed = line.call is None
@@ -865,43 +868,31 @@ def _group(book, sig: Tuple) -> Optional[Callable]:
 
 
 def _compile_group(
-    book, n: int, k: int, avals: Sequence, device
+    book, rows: int, avals: Sequence, device
 ) -> Optional[Callable]:
-    """Lower and compile the loop of ``k`` steps of the ledger's program
-    over blocks of ``n`` rows of columns of ``avals`` (``(shape, dtype)``
-    each) on ``device``, ahead of time. None where an output of the
-    program does not keep the block's rows."""
+    """Lower and compile one pass of the ledger's program over ``rows``
+    rows, from a traced start on, of columns of ``avals`` (``(shape,
+    dtype)`` each) on ``device``, ahead of time. None where an output of
+    the program does not keep the run's rows."""
     jitted, key = book.jitted, book.key
-    blocks = jax.eval_shape(jitted, *[
-        jax.ShapeDtypeStruct((n,) + shape[1:], dtype) for shape, dtype in avals
-    ])
-    if not isinstance(blocks, (tuple, list)) or not all(
-        getattr(o, "ndim", 0) and o.shape[0] == n for o in blocks
-    ):
-        return None
 
     def fn(lo, *columns):
-        def step(i, outs):
-            got = jitted(*[
-                jax.lax.dynamic_slice_in_dim(c, lo + i * n, n) for c in columns
-            ])
-            return tuple(
-                jax.lax.dynamic_update_slice_in_dim(o, v, i * n, 0)
-                for o, v in zip(outs, got)
-            )
-
-        # int32 bounds: under x64 the counter, and with it every row
-        # index, would be 64-bit
-        return jax.lax.fori_loop(np.int32(0), np.int32(k), step, tuple(
-            jax.lax.empty((k * n,) + o.shape[1:], o.dtype) for o in blocks
-        ))
+        return jitted(*[
+            jax.lax.dynamic_slice_in_dim(c, lo, rows) for c in columns
+        ])
 
     # the XLA module is named after the function: as the program's own
     fn.__name__ = fn.__qualname__ = getattr(jitted, "__name__", "fn")
-    t0 = time.perf_counter()
-    compiled = _compile_exact(
-        jax.jit(fn), [((), np.dtype(np.int32)), *avals], device
+    feeds = [((), np.dtype(np.int32)), *avals]
+    outs = jax.eval_shape(
+        fn, *[jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in feeds]
     )
+    if not isinstance(outs, (tuple, list)) or not all(
+        getattr(o, "ndim", 0) and o.shape[0] == rows for o in outs
+    ):
+        return None
+    t0 = time.perf_counter()
+    compiled = _compile_exact(jax.jit(fn), feeds, device)
     t1 = time.perf_counter()
     _tele.record_compile(key[1], key[0], t1 - t0, "xla", t0, t1)
 

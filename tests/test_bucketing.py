@@ -339,8 +339,8 @@ class TestBlockWindow:
 
 
 # ---------------------------------------------------------------------------
-# the block group (ISSUE 34): a run of equal blocks of resident columns is
-# one dispatch, a loop over the run's blocks inside one program
+# the block group (ISSUE 34, 36): a run of equal blocks of resident columns
+# is one dispatch, one pass of the program over the run's rows
 # ---------------------------------------------------------------------------
 
 
@@ -392,6 +392,13 @@ _GROUP_CASES = {
         lambda: _resident({"x": _ints(53)}, [10, 10, 13, 10, 10]),
         _times_two, "off", 2, 4, 1, 0, 0, 3,
     ),
+    # a strict part of its column: rows before it (a window of 7 in a
+    # rung of 8) and after it (5 in 8)
+    "run-inside-its-column": (
+        "map_blocks",
+        lambda: _resident({"x": _ints(42)}, [7, 10, 10, 10, 5]),
+        _times_two, "off", 1, 3, 2, 0, 0, 4,
+    ),
     "two-feed-columns-two-fetches": (
         "map_blocks",
         lambda: _resident(
@@ -431,6 +438,19 @@ _GROUP_CASES = {
         _times_two, "auto", 0, 0, 10, 0, 0, 60,
     ),
 }
+
+
+def _spy_group_compiles(monkeypatch):
+    """The rows of every run `shape_policy._compile_group` is asked to
+    compile from here on, in order."""
+    made, compile_group = [], sp._compile_group
+
+    def spy(book, rows, avals, device):
+        made.append(rows)
+        return compile_group(book, rows, avals, device)
+
+    monkeypatch.setattr(sp, "_compile_group", spy)
+    return made
 
 
 def _run_columns(verb, fetch, df, **kw):
@@ -499,9 +519,10 @@ class TestBlockGroup:
         assert "1 group dispatch(es) over 200 equal block(s)" in line
 
     def test_group_keeps_the_module_name(self, monkeypatch):
-        """The group's program is the verb's program in a loop: in a
-        device trace it is still `jit_fn`, so the benchmark's
-        `program_roofline` reads it and `copy_device_pct` does not."""
+        """The group's program is the verb's program over the run's
+        rows: in a device trace it is still `jit_fn`, so the benchmark's
+        `program_roofline` reads it and `copy_device_pct` does not, and
+        it holds no loop over the blocks and no carried output."""
         import re
 
         made, compile_exact = [], sp._compile_exact
@@ -522,20 +543,16 @@ class TestBlockGroup:
         for compiled in made:
             text = compiled.as_text()
             assert re.search(r"HloModule (\w+)", text).group(1) == "jit_fn"
-            assert " while(" in text  # one loop over the blocks
+            assert " while(" not in text
+            assert "dynamic-update-slice" not in text
 
     def test_compiles_bounded_over_drifting_runs(self, monkeypatch):
         """Frames whose equal blocks keep changing in size and count: a
         program holds no more group executables than its ledger has
-        lines, compiles at first sight only while the ledger has room,
-        and after that only a run that came back."""
-        made, compile_group = [], sp._compile_group
-
-        def spy(book, n, k, avals, device):
-            made.append((n, k))
-            return compile_group(book, n, k, avals, device)
-
-        monkeypatch.setattr(sp, "_compile_group", spy)
+        lines (one a run's rows, columns and device), compiles at first
+        sight only while the ledger has room, and after that only a run
+        that came back."""
+        made = _spy_group_compiles(monkeypatch)
         ex = Executor()
 
         def call(n, k, tail=()):
@@ -550,7 +567,7 @@ class TestBlockGroup:
         ):
             for n, k in [(10, 4), (11, 4), (10, 5)]:
                 call(n, k)
-            assert made == [(10, 4), (11, 4), (10, 5)]
+            assert made == [40, 44, 50]
             call(10, 4)  # held: nothing compiles
             call(10, 4, tail=(3,))  # the same run in a longer column
             assert made[3:] == []  # ... a fourth signature: the ledger is full
@@ -569,13 +586,35 @@ class TestBlockGroup:
             )
             # a run that comes back while its line is held earns its program
             call(17, 3)
-            assert made[3:] == [(17, 3)]
+            assert made[3:] == [51]
             call(17, 3)
-            assert made[3:] == [(17, 3)] and len(book.groups) == 3
+            assert made[3:] == [51] and len(book.groups) == 3
             assert _group_counters()["group_dispatch"] == 6
         # the lines go with the cache entry
         ex.clear()
         assert not ex.programs()
+
+    def test_splits_of_the_same_rows_share_one_executable(self, monkeypatch):
+        """What a run executes depends on its rows, not on how they are
+        cut: 4 x 10 and 5 x 8 of one column are one ledger line and one
+        compile, and each still counts its own blocks."""
+        made = _spy_group_compiles(monkeypatch)
+        ex = Executor()
+        with tfs.config.override(block_scheduler="off"):
+            for sizes in ([10] * 4, [8] * 5):
+                df = _resident({"x": _ints(40)}, sizes)
+                with tfs.config.override(shape_bucketing=False):
+                    want, _ = _run_columns("map_blocks", _times_two(df), df)
+                got, out = _run_columns(
+                    "map_blocks", _times_two(df), df, executor=ex
+                )
+                np.testing.assert_array_equal(got["z"], want["z"])
+                assert out.offsets == df.offsets
+        assert made == [40]
+        (book,) = [e.ledger for e in ex.programs()]
+        assert len(book.groups) == 1
+        c = _group_counters()
+        assert (c["group_dispatch"], c["grouped_blocks"]) == (2, 9)
 
     @pytest.mark.parametrize("fault", ["resource", "transient"])
     def test_faults_keep_their_meaning(self, fault):

@@ -96,6 +96,49 @@ def test_x_plus_3_at_the_200m_row_rung(one_chip):
     )
 
 
+@pytest.mark.parametrize("column_rows", [400_000_000, 400_000_007])
+def test_a_run_of_equal_blocks_is_one_fusion_over_its_rows(
+    topo, monkeypatch, column_rows
+):
+    # `map_chain_200blocks`: x + 3 over 200 blocks of 2,000,000 rows as
+    # the block group compiles it (`shape_policy._compile_group`): one
+    # pass over the run's rows, whole column or a part of a longer one.
+    # What the cell's traced `breakdown` would show, at no chip time.
+    import re
+
+    from tensorframes_tpu import shape_policy as sp
+    from tensorframes_tpu.runtime.executor import ProgramLedger
+
+    rows = 200 * 2_000_000
+    df = tfs.TensorFrame.from_dict({"x": np.zeros(4, np.float32)})
+    graph, fetches = dsl.build((tfs.block(df, "x") + 3.0).named("z"))
+    book = ProgramLedger(
+        ("map_blocks", "x_plus_3"),
+        jax.jit(build_callable(graph, fetches, ["x"])),
+    )
+    made, compile_exact = [], sp._compile_exact
+
+    def spy(*args):
+        made.append(compile_exact(*args))
+        return made[-1]
+
+    monkeypatch.setattr(sp, "_compile_exact", spy)
+    call = sp._compile_group(
+        book, rows, [((column_rows,), np.dtype(np.float32))],
+        topo.devices[0],
+    )
+    assert call is not None
+    (compiled,) = made
+    text = compiled.as_text()
+    assert re.search(r"HloModule (\w+)", text).group(1) == "jit_fn"
+    assert " while(" not in text and "dynamic-update-slice" not in text
+    (fusion,) = re.findall(r"= (\S+) fusion\(", text)
+    assert fusion.startswith("f32[400000000]")
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes == 0
+    assert mem.output_size_in_bytes == rows * 4
+
+
 class _Captured(Exception):
     pass
 

@@ -5,7 +5,14 @@ each by a table (`OWN_TESTS`) that cannot be added to without an edit. This
 conftest, at the repository's root and so loaded for `perf/tests` too, does
 the same for the runner that came after those, with `perf/conftest.py`'s
 own look-up. It touches nothing outside those two files: the tier-1 tests
-under `tests/` collect as they did."""
+under `tests/` collect as they did.
+
+`test_span_reduce.py` also rehearses every cell in its own process, on one
+device, where `map_chain_200blocks_4chips` has no scheduler and is
+`map_chain_200blocks`' group (for which the test has failed since PR 34: it
+expects a dispatch span a block, PERF.md Open question 9): that cell is
+skipped there. What its spans show on four devices is pinned in
+`tests/test_scheduler.py` and `perf/tests/test_four_chips_cell.py`."""
 
 import os
 
@@ -14,6 +21,7 @@ import pytest
 from perf.conftest import TABLED, _runner_of
 
 OWN_TESTS = {"map_blocks_lm_hybrid": "test_lm_hybrid_cell.py"}  # runner -> its own file
+ON_FOUR_DEVICES = {"map_chain_200blocks_4chips": "test_four_chips_cell.py"}  # cell -> its file
 PERF_TESTS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "perf", "tests")
 
 
@@ -24,6 +32,8 @@ def pytest_collection_modifyitems(config, items):
             continue
         params = getattr(getattr(item, "callspec", None), "params", {})
         own = OWN_TESTS.get(_runner_of(params.get("cell")))
+        if own is None and os.path.basename(path) == "test_span_reduce.py":
+            own = ON_FOUR_DEVICES.get(params.get("cell"))
         if own:
             item.add_marker(pytest.mark.skip(
                 reason=f"{params['cell']}: the same checks are in perf/tests/{own}"

@@ -33,6 +33,7 @@ reduces, `DebugRowOps.scala:80-262`).
 from __future__ import annotations
 
 import threading
+import time
 from typing import Callable, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
@@ -554,7 +555,11 @@ def _colocate_parts(parts: List, anchor=None) -> List:
     single jnp op can consume them (jax refuses committed arrays from
     different devices in one computation). The block scheduler's map
     outputs and stream partials hit this; everything is `device_put`
-    (async D2D/H2D) — no host sync.
+    (async D2D/H2D) — no host sync. The puts are ONE span a call,
+    ``frame.gather`` (kind ``transfer``: the anchor, the parts, how many
+    moved and their bytes), and the counters ``scheduler.bytes_back`` /
+    ``scheduler.gather_seconds``; parts already on one device open
+    nothing.
 
     ``anchor`` (a jax device) is the scheduler's anchor: scheduled verbs
     MUST pass it so every call over the same device set commits its
@@ -586,10 +591,30 @@ def _colocate_parts(parts: List, anchor=None) -> List:
         return list(parts)
     if anchor is None:
         anchor = max(weight.items(), key=lambda kv: kv[1])[0]
-    return [
-        p if d is anchor else jax.device_put(p, anchor)
-        for p, d in zip(parts, devs)
-    ]
+    from .runtime.scheduler import device_label
+
+    moved = [p for p, d in zip(parts, devs) if d is not anchor]
+    nbytes = sum(getattr(p, "nbytes", 0) for p in moved)
+    # ONE span a gather, however many parts: the way back's host time
+    # and bytes, apart from the concatenate that follows
+    gather = _tele.span(
+        "frame.gather", kind="transfer", anchor=device_label(anchor),
+        parts=len(parts), moved_parts=len(moved), bytes=nbytes,
+    )
+    t0 = time.perf_counter()
+    with gather:
+        out = [
+            p if d is anchor else jax.device_put(p, anchor)
+            for p, d in zip(parts, devs)
+        ]
+    # the span's own clock, so the counter and the span agree; the
+    # counters are live with telemetry off, when there is no span
+    seconds = getattr(gather, "seconds", None)
+    if seconds is None:
+        seconds = time.perf_counter() - t0
+    _tele.counter_inc("scheduler.bytes_back", float(nbytes))
+    _tele.counter_inc("scheduler.gather_seconds", seconds)
+    return out
 
 
 def _concat_parts(parts: List, anchor=None) -> "np.ndarray":
@@ -959,50 +984,57 @@ def _run_blocks(
         and not trim and not bound and names
     ):
         runs = _sp.block_runs(col_values, frame.offsets)
-    with _tele.span(f"{verb}.blocks", kind="stage"):
-        bi = 0
-        while bi < frame.num_blocks:
-            lo, hi = frame.offsets[bi], frame.offsets[bi + 1]
-            if lo == hi:
-                out_sizes.append(0)
-                bi += 1
-                continue  # empty block: contributes nothing (the reference's
-                # empty-partition TODO, `DebugRowOps.scala:386-387`)
-            n, k, end = runs.get(bi, (hi - lo, 1, bi + 1))
-            outs = _dispatch_group(bi, lo, n, k) if k > 1 else None
-            if outs is None:  # one block, or of a run with no group
-                k, end = 1, bi + 1
-                outs = _dispatch_rows(bi, lo, hi, 0)
-            hi = lo + k * n
-            maybe_check_numerics(
-                names, outs,
-                f"{verb} block {bi}" if k == 1 else f"{verb} blocks [{bi}:{end})",
-            )
-            bsize = None
-            for f, o in zip(names, outs):
-                # keep device arrays on device; shape checks are metadata-only
-                if not trim and (o.ndim == 0 or o.shape[0] != hi - lo):
-                    raise ValueError(
-                        f"{verb}: output {f!r} has lead dim "
-                        f"{o.shape[0] if o.ndim else '<scalar>'} but the block "
-                        f"has {hi - lo} rows"
-                        + ("; use trim=True for row-count-changing maps"
-                           if verb == "map_blocks" else "")
-                    )
-                if trim:
-                    if o.ndim == 0:
+    try:
+        with _tele.span(f"{verb}.blocks", kind="stage"):
+            bi = 0
+            while bi < frame.num_blocks:
+                lo, hi = frame.offsets[bi], frame.offsets[bi + 1]
+                if lo == hi:
+                    out_sizes.append(0)
+                    bi += 1
+                    continue  # empty block: contributes nothing (the reference's
+                    # empty-partition TODO, `DebugRowOps.scala:386-387`)
+                n, k, end = runs.get(bi, (hi - lo, 1, bi + 1))
+                outs = _dispatch_group(bi, lo, n, k) if k > 1 else None
+                if outs is None:  # one block, or of a run with no group
+                    k, end = 1, bi + 1
+                    outs = _dispatch_rows(bi, lo, hi, 0)
+                hi = lo + k * n
+                maybe_check_numerics(
+                    names, outs,
+                    f"{verb} block {bi}" if k == 1 else f"{verb} blocks [{bi}:{end})",
+                )
+                bsize = None
+                for f, o in zip(names, outs):
+                    # keep device arrays on device; shape checks are metadata-only
+                    if not trim and (o.ndim == 0 or o.shape[0] != hi - lo):
                         raise ValueError(
-                            f"{verb}(trim): output {f!r} must have a lead dim"
+                            f"{verb}: output {f!r} has lead dim "
+                            f"{o.shape[0] if o.ndim else '<scalar>'} but the block "
+                            f"has {hi - lo} rows"
+                            + ("; use trim=True for row-count-changing maps"
+                               if verb == "map_blocks" else "")
                         )
-                    if bsize is None:
-                        bsize = o.shape[0]
-                    elif o.shape[0] != bsize:
-                        raise ValueError(
-                            f"{verb}(trim): outputs disagree on row count"
-                        )
-            acc.append(outs)
-            out_sizes.append(bsize)  # read under `trim` only
-            bi = end
+                    if trim:
+                        if o.ndim == 0:
+                            raise ValueError(
+                                f"{verb}(trim): output {f!r} must have a lead dim"
+                            )
+                        if bsize is None:
+                            bsize = o.shape[0]
+                        elif o.shape[0] != bsize:
+                            raise ValueError(
+                                f"{verb}(trim): outputs disagree on row count"
+                            )
+                acc.append(outs)
+                out_sizes.append(bsize)  # read under `trim` only
+                bi = end
+    finally:
+        # a call that ends early (a raised block, a deadline) leaves its
+        # books and what it never issued with the counters and the gauge;
+        # a whole call's last dispatch has handed them over already
+        if sched is not None:
+            sched.flush()
 
     anchor = sched.anchor_device() if sched is not None else None
     if acc:

@@ -468,13 +468,19 @@ class BlockSchedule:
     ``bind(i, fn)`` returns the dispatch callable for item ``i``: it
     `device_put`s the feeds onto the assigned device, invokes ``fn``
     (committed inputs place the execution), and keeps the per-device
-    dispatch/compile ledgers on the executor plus the per-device
-    queue-depth gauge. ``put(i, feeds)`` is the feeds-only half for
-    callers that invoke the program themselves."""
+    compile ledger on the executor. ``put(i, feeds)`` is the feeds-only
+    half for callers that invoke the program themselves.
+
+    The schedule keeps its BOOKS as plain numbers a device (dispatches
+    and rows issued, seconds the host spent in the feeds' `device_put`,
+    bytes of the feeds that changed device or came from the host) and
+    hands them over ONCE a call (`flush`): nothing is counted, gauged or
+    locked a block beyond the one locked section `_note_dispatch` has."""
 
     __slots__ = (
         "devices", "labels", "assignment", "executor", "weights",
-        "_issued", "_remaining", "_lock",
+        "_issued", "_remaining", "_lock", "_rows_known", "_books",
+        "_handed", "_dirty",
     )
 
     def __init__(self, devices: Tuple, assignment: List[Optional[int]],
@@ -491,12 +497,22 @@ class BlockSchedule:
             if weights is None
             else [int(w) for w in weights]
         )
+        # every caller that gives weights plans by rows (`schedule_for`:
+        # the blocks' sizes); without them no row count is known
+        self._rows_known = weights is not None
         self._issued = [False] * len(self.assignment)
         self._remaining = [0] * len(self.devices)
         for s in self.assignment:
             if s is not None:
                 self._remaining[s] += 1
         self._lock = threading.Lock()
+        # the books, a list a device each: dispatches, rows, put
+        # seconds, bytes in; `_handed` is what `flush` last handed over
+        self._books = [[0] * self.ndev, [0] * self.ndev,
+                       [0.0] * self.ndev, [0] * self.ndev]
+        self._handed = [list(b) for b in self._books]
+        # a schedule that never dispatched still has its plan to show
+        self._dirty = True
 
     @property
     def ndev(self) -> int:
@@ -520,16 +536,13 @@ class BlockSchedule:
 
     # -- dispatch ------------------------------------------------------
     def put(self, i: int, feeds: Sequence) -> List:
-        """`device_put` the feeds onto item ``i``'s device (async) and
-        account the dispatch (per-device ledger + queue-depth gauge)."""
-        import jax
-
+        """The feeds on item ``i``'s device (`_place_feeds`: async) and
+        the dispatch entered in the books."""
         s = self.assignment[i]
         if s is None:
             return list(feeds)
-        dev = self.devices[s]
-        out = [jax.device_put(f, dev) for f in feeds]
-        self._note_dispatch(i, s)
+        out, secs, moved = _place_feeds(feeds, self.devices[s])
+        self._note_dispatch(i, s, secs, moved)
         # put-path verbs (reduce_rows folds, chunked aggregation) are
         # the only dispatches some workloads ever issue — a successful
         # transfer onto the device must close its half-open circuit
@@ -550,15 +563,12 @@ class BlockSchedule:
         the device-health registry (closes a half-open circuit)."""
 
         def call(*feeds):
-            import jax
-
             s = self.assignment[i]
             if s is None:
                 return fn(*feeds) if valid is None else fn(
                     np.int32(valid), *feeds
                 )
-            dev = self.devices[s]
-            put = [jax.device_put(f, dev) for f in feeds]
+            put, secs, moved = _place_feeds(feeds, self.devices[s])
             sizer = getattr(fn, "_cache_size", None)
             n0 = None
             if callable(sizer):
@@ -578,7 +588,7 @@ class BlockSchedule:
                 if n1 is not None and n1 > n0:
                     _bump(self.executor, "device_compiles",
                           self.labels[s], n1 - n0)
-            self._note_dispatch(i, s)
+            self._note_dispatch(i, s, secs, moved)
             _health.mark_success(self.labels[s])
             return out
 
@@ -656,20 +666,91 @@ class BlockSchedule:
                     self._remaining[slot] += 1
         return label
 
-    def _note_dispatch(self, i: int, s: int) -> None:
-        _bump(self.executor, "device_dispatches", self.labels[s], 1)
+    def _note_dispatch(
+        self, i: int, s: int, put_seconds: float, bytes_in: int
+    ) -> None:
+        """Item ``i`` went out to slot ``s``: into the books, under the
+        one lock a dispatch takes. The plan's last dispatch hands the
+        books over (`flush`)."""
+        dispatches, rows, seconds, moved = self._books
+        with self._lock:
+            if not self._issued[i]:
+                # a block split after running out of memory goes out
+                # twice: its rows are booked once
+                self._issued[i] = True
+                if self._rows_known:
+                    rows[s] += self.weights[i]
+            self._remaining[s] = max(0, self._remaining[s] - 1)
+            dispatches[s] += 1
+            seconds[s] += put_seconds
+            moved[s] += bytes_in
+            self._dirty = True
+            done = not any(self._remaining)
+        if done:
+            self.flush()
+
+    def flush(self) -> None:
+        """Hand the books over, once a call: to the counters
+        ``scheduler.dispatches`` / ``scheduler.rows`` /
+        ``scheduler.put_seconds`` / ``scheduler.bytes_in`` (each
+        ``{device=}``) and the executor's ``device_dispatches`` ledger
+        what was entered since the last flush, and set the gauge
+        ``scheduler_queue_depth{device=}`` to the planned dispatches
+        that never went out (0 after a whole call). Runs by itself when
+        the plan's last dispatch is issued; a caller whose call may end
+        early (`api._run_blocks`) calls it in a ``finally``. With
+        nothing entered since the last flush it hands over nothing."""
         from ..utils import telemetry as _tele
 
         with self._lock:
-            self._issued[i] = True
-            self._remaining[s] = max(0, self._remaining[s] - 1)
-            depth = self._remaining[s]
-        if _tele.enabled():
-            # host-side dispatch queue: how many planned dispatches for
-            # this device have not been issued yet this verb call
-            _tele.gauge_set(
-                "scheduler_queue_depth", depth, device=self.labels[s]
-            )
+            if not self._dirty:
+                return
+            self._dirty = False
+            now = [list(b) for b in self._books]
+            was, self._handed = self._handed, now
+            depth = list(self._remaining)
+        gauge = _tele.enabled()
+        names = ("scheduler.dispatches", "scheduler.rows",
+                 "scheduler.put_seconds", "scheduler.bytes_in")
+        for s, label in enumerate(self.labels):
+            since = [new[s] - old[s] for new, old in zip(now, was)]
+            # a device that was planned nothing reads 0, not nothing:
+            # what a balance over the devices has to see
+            for name, d in zip(names, since):
+                _tele.counter_inc(name, float(d), device=label)
+            if since[0]:
+                _bump(self.executor, "device_dispatches", label, since[0])
+            if gauge:
+                _tele.gauge_set(
+                    "scheduler_queue_depth", depth[s], device=label
+                )
+
+
+def _place_feeds(feeds: Sequence, dev) -> Tuple[List, float, int]:
+    """``feeds`` on ``dev`` (`jax.device_put`: async), the seconds the
+    host spent in the puts, and the bytes that changed device or came
+    from the host (a numpy feed counts whole). An array already
+    committed to ``dev`` is passed on as it is: no put, no seconds. A
+    bound tree's leaves were placed, and counted, before the loop
+    (`runtime.bindings`): its put finds them there and books nothing."""
+    import jax
+
+    out: List = []
+    moved = 0
+    t0 = None
+    for f in feeds:
+        here = None  # whether an array feed lives on `dev` alone
+        if isinstance(f, jax.Array):
+            here = f.devices() == {dev}
+            if here and f.committed:
+                out.append(f)
+                continue
+        if t0 is None:
+            t0 = time.perf_counter()
+        if not here:
+            moved += getattr(f, "nbytes", 0)
+        out.append(jax.device_put(f, dev))
+    return out, (0.0 if t0 is None else time.perf_counter() - t0), moved
 
 
 def _bump(ex, attr: str, label: str, n: int) -> None:

@@ -782,7 +782,7 @@ def span_aggregates(span_list: Optional[List[Span]] = None) -> Dict:
     by_kind: Dict[str, Dict[str, float]] = {}
     by_program: Dict[str, Dict[str, float]] = {}
     dev_intervals: Dict[str, List[Tuple[float, float]]] = {}
-    dev_counts: Dict[str, int] = {}
+    dev_rows: Dict[str, float] = {}
     # self time: a span's duration less the union of its direct
     # children's intervals (clipped to it: a cross-thread child may
     # outlast the region that owns it)
@@ -835,17 +835,21 @@ def span_aggregates(span_list: Optional[List[Span]] = None) -> Dict:
         if s.kind == "dispatch":
             dev = s.attrs.get("device")
             if dev:
-                # per-device busy-span ledger (block-scheduler labels):
+                # per-device issue ledger (block-scheduler labels):
                 # dispatch spans measure async ISSUE windows, so the
-                # union is "this device had work being dispatched to
-                # it" time, not device occupancy — still the honest
-                # utilization skew signal across devices
+                # union is the time the host spent dispatching to this
+                # device, NOT the device's occupancy (a chip that waits
+                # for the host 99% of the time reads high here); with
+                # the rows, the skew signal across devices
                 dev_intervals.setdefault(str(dev), []).append((s.t0, s.t1))
-                dev_counts[str(dev)] = dev_counts.get(str(dev), 0) + 1
+                dev_rows[str(dev)] = dev_rows.get(str(dev), 0.0) + float(
+                    s.attrs.get("rows") or 0
+                )
     by_device = {
         d: {
-            "busy_s": _union_seconds(iv),
-            "dispatches": dev_counts[d],
+            "issue_s": _union_seconds(iv),
+            "dispatches": len(iv),
+            "rows": dev_rows[d],
         }
         for d, iv in dev_intervals.items()
     }
@@ -1027,7 +1031,25 @@ _PROM_HELP: Dict[str, str] = {
     "live_buffer_bytes": "Live jax buffer bytes committed per device",
     "device_bytes_in_use": "Backend memory_stats bytes_in_use per device",
     "device_peak_bytes": "Backend memory_stats peak_bytes_in_use per device",
-    "scheduler_queue_depth": "Planned dispatches not yet issued per device",
+    "scheduler_queue_depth": (
+        "Planned dispatches a verb call never issued per device (0 after "
+        "a whole call)"
+    ),
+    "scheduler.dispatches": "Block-scheduler dispatches issued per device",
+    "scheduler.rows": "Rows of the blocks the scheduler issued per device",
+    "scheduler.put_seconds": (
+        "Host seconds in device_put of scheduled feeds per device"
+    ),
+    "scheduler.bytes_in": (
+        "Bytes of scheduled feeds that changed device or came from the "
+        "host, per receiving device"
+    ),
+    "scheduler.bytes_back": (
+        "Bytes of parts copied to the anchor device before a concat or stack"
+    ),
+    "scheduler.gather_seconds": (
+        "Host seconds in the copies of parts to the anchor device"
+    ),
     "stream_queue_depth": "Decoded chunks ready ahead of the consumer",
     "ingest_queue_depth": "Ingest stage input-queue occupancy",
     "ingest_chunks": "Items through each ingest stage",
@@ -1140,6 +1162,24 @@ def _fmt_rate(v, unit: str) -> str:
     return f"{v:.2f} {unit}"
 
 
+def _device_lines(by_device: Dict[str, Dict]) -> Dict[str, Dict]:
+    """`span_aggregates`' device lines (issue seconds, dispatches and
+    rows, from the ring's dispatch spans) beside what the block
+    scheduler's books have handed to the counters since the last reset
+    (`BlockSchedule.flush`): bytes that arrived on the device and the
+    seconds the host spent putting them there."""
+    out = {d: dict(v) for d, v in by_device.items()}
+    keys = {"scheduler.bytes_in": "bytes_in", "scheduler.put_seconds": "put_s"}
+    for (name, labels), v in labeled_counters().items():
+        dev = dict(labels).get("device")
+        if name in keys and dev is not None:
+            line = out.setdefault(
+                dev, {"issue_s": 0.0, "dispatches": 0, "rows": 0.0}
+            )
+            line[keys[name]] = v
+    return out
+
+
 def diagnostics_data(executor=None) -> Dict:
     """The machine-readable diagnostics payload (what
     ``tfs.diagnostics(format="json")`` and the /diagnostics endpoint
@@ -1161,7 +1201,7 @@ def diagnostics_data(executor=None) -> Dict:
         },
         "verbs": agg["by_verb"],
         "phases": agg["by_name"],
-        "devices": agg["by_device"],
+        "devices": _device_lines(agg["by_device"]),
         "programs": agg["by_program"],
     }
 
@@ -1392,15 +1432,17 @@ def _render_diagnostics(data: Dict) -> str:
     if data.get("devices"):
         lines.append("")
         lines.append(
-            "devices (block-scheduler dispatch labels; busy = union of "
-            "dispatch-issue spans, not device occupancy):"
+            "devices (block-scheduler dispatch labels; issue = union of "
+            "the host's dispatch-issue spans, not device occupancy; in = "
+            "feeds that changed device or came from the host, put = the "
+            "host's seconds in their device_put):"
         )
-        window = max(w["window"], 1e-12)
         for dev, d in sorted(data["devices"].items()):
             lines.append(
                 f"  {dev:<10} dispatches={d['dispatches']:<5} "
-                f"busy={d['busy_s']:.4f}s "
-                f"({min(1.0, d['busy_s'] / window) * 100:.1f}% of window)"
+                f"rows={d['rows']:<10.0f} issue={d['issue_s']:.4f}s "
+                f"in={_fmt_bytes(d.get('bytes_in', 0))} "
+                f"put={d.get('put_s', 0.0):.4f}s"
             )
     if data["programs"]:
         lines.append("")

@@ -535,3 +535,327 @@ class TestHostSyncDiscipline:
         res = tfs.reduce_blocks(g, mid)
         assert stats().get("host_sync", 0) == 0  # nothing fetched yet
         assert isinstance(res, jax.Array)
+
+
+# ---------------------------------------------------------------------------
+# the schedule's books (ISSUE 38): kept on the schedule a block, handed to
+# the counters once a call; the way back is one `frame.gather` a column
+# ---------------------------------------------------------------------------
+
+FOUR = jax.local_devices()[:4]
+four_devices = pytest.mark.skipif(NDEV < 4, reason="needs 4 (virtual) devices")
+_BOOKS = ("dispatches", "rows", "put_seconds", "bytes_in")
+
+
+def _resident(k, n=10, device=None):
+    """k equal blocks of n float32 rows, off their rung (16 for 10), of
+    a column resident on ``device`` (None: uncommitted on the first)."""
+    import jax.numpy as jnp
+
+    x = jnp.arange(float(k * n), dtype=jnp.float32)
+    if device is not None:
+        x = jax.device_put(x, device)
+    return tfs.TensorFrame(
+        [tfs.Column("x", x)], [n * i for i in range(k + 1)]
+    )
+
+
+def _books():
+    """{book: {device label: value}} and the two unlabelled counters."""
+    flat = telemetry.flat_counters()
+    out = {b: {} for b in _BOOKS}
+    for key, v in flat.items():
+        for b in _BOOKS:
+            head = f"scheduler.{b}{{device="
+            if key.startswith(head):
+                out[b][key[len(head):-1]] = v
+    out["bytes_back"] = flat.get("scheduler.bytes_back", 0.0)
+    out["gather_seconds"] = flat.get("scheduler.gather_seconds", 0.0)
+    return out
+
+
+def _queue_depths():
+    _, gauges, _ = telemetry.metrics_snapshot()
+    return {
+        dict(labels)["device"]: v for (name, labels), v in gauges.items()
+        if name == "scheduler_queue_depth"
+    }
+
+
+@four_devices
+class TestBooks:
+    @pytest.mark.parametrize("k", [8, 16])
+    @pytest.mark.parametrize("outputs", [1, 2])
+    def test_the_rule_of_the_block_loop(self, k, outputs, monkeypatch):
+        """A scheduled `map_blocks` records, a call, the span names it
+        recorded before ISSUE 38 and `frame.gather`, nothing else: one
+        gather an output column, and NO span a block beyond the three
+        that were there. What it hands the registry under `scheduler.*`
+        and the queue-depth gauge is the same number of writes for 8
+        blocks as for 16: once a call, not once a block."""
+        df = _resident(k)
+        x = tfs.block(df, "x")
+        fetch = [(x + 3.0).named("z"), (x * 2.0).named("w")][:outputs]
+        tfs.map_blocks(fetch, df, devices=FOUR)  # compiles
+        telemetry.reset()
+        writes = {"counter": 0, "gauge": 0}
+        reg = telemetry._registry
+        real_inc, real_set = reg.counter_inc, reg.gauge_set
+
+        def counter_inc(name, value=1.0, **labels):
+            writes["counter"] += name.startswith("scheduler.")
+            return real_inc(name, value, **labels)
+
+        def gauge_set(name, value, **labels):
+            writes["gauge"] += name == "scheduler_queue_depth"
+            return real_set(name, value, **labels)
+
+        monkeypatch.setattr(reg, "counter_inc", counter_inc)
+        monkeypatch.setattr(reg, "gauge_set", gauge_set)
+        tfs.map_blocks(fetch, df, devices=FOUR)
+        names = [s.name for s in telemetry.spans()]
+        once = ["map_blocks", "map_blocks.plan", "graph.analyze",
+                "frame.match", "executor.lookup", "shape.classify",
+                "scheduler.plan", "map_blocks.blocks"]
+        want = {n: 1 for n in once}
+        want.update({"shape.pad": k, "map_blocks.block": k, "shape.unpad": k,
+                     "frame.concat": outputs, "frame.gather": outputs})
+        assert {n: names.count(n) for n in set(names)} == want
+        # the parent's count plus one an output column
+        assert len(names) == len(once) + 3 * k + outputs + outputs
+        assert writes == {"counter": 4 * len(FOUR) + 2 * outputs,
+                          "gauge": len(FOUR)}
+        by_id = {s.span_id: s for s in telemetry.spans()}
+        for s in telemetry.spans():
+            if s.name == "frame.gather":
+                assert s.kind == "transfer"
+                assert by_id[s.parent_id].name == "frame.concat"
+                assert s.attrs["anchor"] == rs.device_label(FOUR[0])
+                assert s.attrs["parts"] == k
+                assert s.attrs["moved_parts"] == k - k // 4
+
+    def test_the_books_by_arithmetic(self):
+        k, n, rung = 8, 10, 16
+        df = _resident(k, n)
+        z = (tfs.block(df, "x") + 3.0).named("z")
+        ex = Executor()
+        out = tfs.map_blocks(z, df, devices=FOUR, executor=ex)
+        np.testing.assert_array_equal(
+            np.asarray(out["z"].values), np.arange(k * n, dtype=np.float32) + 3
+        )
+        labels = [rs.device_label(d) for d in FOUR]
+        b = _books()
+        # two blocks a device; the windows of three devices leave the
+        # first (a rung's rows of float32 each), their parts come back
+        assert b["rows"] == {lab: 2.0 * n for lab in labels}
+        assert b["dispatches"] == {lab: 2.0 for lab in labels}
+        assert b["bytes_in"] == {
+            lab: (0.0 if lab == labels[0] else 2.0 * rung * 4) for lab in labels
+        }
+        assert b["bytes_back"] == 6 * n * 4
+        assert sum(b["dispatches"].values()) == len(
+            _dispatch_devices("map_blocks.block")
+        )
+        assert all(v > 0 for v in b["put_seconds"].values())
+        (gather,) = [s for s in telemetry.spans() if s.name == "frame.gather"]
+        assert gather.attrs["bytes"] == b["bytes_back"]
+        assert b["gather_seconds"] == pytest.approx(gather.seconds)
+        assert executor_stats(ex)["device_dispatches"] == {
+            lab: 2 for lab in labels
+        }
+        assert _queue_depths() == {lab: 0.0 for lab in labels}
+        # the device lines: issue time, not "busy"; the books beside it
+        devices = tfs.diagnostics(format="json")["devices"]
+        assert sorted(devices) == sorted(labels)
+        for lab in labels:
+            assert set(devices[lab]) == {
+                "issue_s", "dispatches", "rows", "bytes_in", "put_s"}
+            assert devices[lab]["rows"] == 2 * n
+            assert devices[lab]["bytes_in"] == b["bytes_in"][lab]
+        assert " issue=" in tfs.diagnostics() and "busy=" not in tfs.diagnostics()
+
+    @pytest.mark.parametrize("mode", ["pinned", "on"])
+    def test_one_device_books_no_bytes_and_no_seconds(self, mode):
+        """A column committed to the schedule's one device: every feed
+        is in place, nothing is put, nothing comes back."""
+        dev = FOUR[1]
+        df = _resident(4, device=dev)
+        z = (tfs.block(df, "x") + 3.0).named("z")
+        if mode == "pinned":
+            out = tfs.map_blocks(z, df, devices=[dev])
+        else:
+            with tfs.config.override(block_scheduler="on"):
+                out = tfs.map_blocks(z, df, devices=[dev])
+        assert out["z"].values.devices() == {dev}
+        b, lab = _books(), rs.device_label(dev)
+        assert b["dispatches"] == {lab: 4.0} and b["rows"] == {lab: 40.0}
+        assert b["bytes_in"] == {lab: 0.0} and b["put_seconds"] == {lab: 0.0}
+        assert b["bytes_back"] == 0.0 and b["gather_seconds"] == 0.0
+        assert "frame.gather" not in [s.name for s in telemetry.spans()]
+
+    @pytest.mark.parametrize(
+        "feed", ["committed-here", "uncommitted-here", "elsewhere", "numpy"]
+    )
+    def test_a_feed_in_place_is_passed_on_and_others_count(self, feed):
+        import jax.numpy as jnp
+
+        dev = FOUR[2]
+        host = np.arange(6, dtype=np.float32)
+        if feed == "numpy":
+            x = host
+        elif feed == "uncommitted-here":
+            with jax.default_device(dev):
+                x = jnp.arange(6, dtype=jnp.float32)
+            assert not x.committed and x.devices() == {dev}
+        else:
+            x = jax.device_put(host, dev if feed == "committed-here" else FOUR[0])
+        sched = rs.BlockSchedule((dev,), [0], weights=[6])
+        seen = []
+
+        def fn(a):
+            seen.append(a)
+            return a
+
+        sched.bind(0, fn)(x)
+        (got,) = seen
+        assert got.devices() == {dev} and got.committed
+        b, lab = _books(), rs.device_label(dev)
+        assert b["dispatches"] == {lab: 1.0} and b["rows"] == {lab: 6.0}
+        if feed == "committed-here":
+            assert got is x  # not put again, not copied
+            assert b["bytes_in"] == {lab: 0.0}
+            assert b["put_seconds"] == {lab: 0.0}
+        else:
+            assert got is not x and b["put_seconds"][lab] > 0
+            moved = feed in ("elsewhere", "numpy")  # a numpy feed whole
+            assert b["bytes_in"] == {lab: 24.0 if moved else 0.0}
+        np.testing.assert_array_equal(np.asarray(got), host)
+
+    def test_flush_twice_hands_over_nothing_twice(self):
+        dev0, dev1 = FOUR[:2]
+        sched = rs.BlockSchedule((dev0, dev1), [0, 1, 0], weights=[4, 3, 2])
+        feed = np.ones(4, np.float32)
+        sched.put(0, [feed])
+        sched.flush()  # a part of the plan: what went out so far
+        lab0, lab1 = rs.device_label(dev0), rs.device_label(dev1)
+        b = _books()
+        assert b["dispatches"] == {lab0: 1.0, lab1: 0.0}
+        assert b["rows"] == {lab0: 4.0, lab1: 0.0}
+        assert _queue_depths() == {lab0: 1.0, lab1: 1.0}
+        sched.flush()
+        assert _books() == b
+        sched.put(1, [feed])
+        sched.put(2, [feed])  # the plan's last dispatch flushes by itself
+        b = _books()
+        assert b["dispatches"] == {lab0: 2.0, lab1: 1.0}
+        assert b["rows"] == {lab0: 6.0, lab1: 3.0}
+        assert b["bytes_in"] == {lab0: 32.0, lab1: 16.0}
+        assert _queue_depths() == {lab0: 0.0, lab1: 0.0}
+        sched.flush()
+        sched.flush()
+        assert _books() == b
+
+    def test_rows_are_not_booked_where_the_plan_has_none(self):
+        sched = rs.BlockSchedule((FOUR[0],), [0, 0])  # no weights given
+        sched.put(0, [np.ones(2, np.float32)])
+        sched.put(1, [np.ones(2, np.float32)])
+        lab = rs.device_label(FOUR[0])
+        assert _books()["rows"] == {lab: 0.0}
+        assert _books()["dispatches"] == {lab: 2.0}
+
+    def test_a_call_that_raises_leaves_what_was_issued_and_what_was_not(self):
+        from tensorframes_tpu.testing import faults as chaos
+
+        k, j = 8, 5
+        df = _resident(k)
+        z = (tfs.block(df, "x") + 3.0).named("z")
+        ex = Executor()
+        tfs.map_blocks(z, df, devices=FOUR, executor=ex)  # compiles
+        telemetry.reset()
+        with chaos.inject(nth=[j], fault="deterministic", kind="block"):
+            with pytest.raises(Exception):
+                tfs.map_blocks(z, df, devices=FOUR, executor=ex)
+        plan = rs.plan(df.block_sizes(), len(FOUR))
+        labels = [rs.device_label(d) for d in FOUR]
+        issued = {lab: 0.0 for lab in labels}
+        left = {lab: 0.0 for lab in labels}
+        for i, s in enumerate(plan):
+            (issued if i < j else left)[labels[s]] += 1
+        b = _books()
+        assert b["dispatches"] == issued
+        assert b["rows"] == {lab: 10 * v for lab, v in issued.items()}
+        assert _queue_depths() == left and sum(left.values()) == k - j
+        assert b["bytes_back"] == 0.0  # it never came to the concat
+
+    @pytest.mark.parametrize("verb", ["reduce_rows", "aggregate"])
+    def test_a_put_path_verb_flushes_by_itself(self, verb):
+        if verb == "reduce_rows":
+            from tensorframes_tpu.schema import ScalarType, Shape
+
+            x = (np.arange(40) % 7).astype(np.float32)
+            base = tfs.TensorFrame.from_dict({"x": x})
+            df = tfs.TensorFrame([base["x"]], [0, 10, 20, 21, 40])
+            x1 = dsl.placeholder(ScalarType.float32, Shape(()), name="x_1")
+            x2 = dsl.placeholder(ScalarType.float32, Shape(()), name="x_2")
+            got = tfs.reduce_rows((x1 + x2).named("x"), df, devices=FOUR)
+            assert float(got) == x.sum()
+            dispatches, rows = 3, 39  # the one-row block is never put
+        else:
+            # the chunked plan (`sched.put` of each chunk size's feeds):
+            # more distinct group sizes than the exact plan takes
+            keys = np.repeat(np.arange(6), [5, 5, 5, 3, 3, 2]).astype(np.int64)
+            vals = np.arange(23, dtype=np.float32)
+            df = tfs.TensorFrame.from_dict({"k": keys, "x": vals})
+            g = dsl.reduce_sum(
+                tfs.block(df, "x", tf_name="x_input"), axes=[0]
+            ).named("x")
+            with tfs.config.override(
+                aggregate_segment_fast=False, aggregate_exact_size_limit=1
+            ):
+                out = tfs.aggregate(g, tfs.group_by(df, "k"), devices=FOUR)
+            assert stats()["aggregate.plan.chunk"] == 1
+            np.testing.assert_allclose(
+                np.asarray(out["x"].values), np.bincount(keys, weights=vals)
+            )
+            issued = [
+                s for s in telemetry.spans()
+                if s.kind == "dispatch" and s.attrs.get("device")
+            ]
+            assert {s.name for s in issued} == {"aggregate.chunk"}
+            dispatches = len(issued)
+            rows = sum(s.attrs["rows"] for s in issued)
+        b = _books()
+        assert sum(b["dispatches"].values()) == dispatches
+        assert sum(b["rows"].values()) == rows
+        assert set(_queue_depths().values()) == {0.0}
+
+    def test_telemetry_off_no_gather_span_and_the_counters_live(self):
+        df = _resident(8)
+        z = (tfs.block(df, "x") + 3.0).named("z")
+        with tfs.config.override(telemetry=False):
+            tfs.map_blocks(z, df, devices=FOUR)
+            assert telemetry.spans() == []
+        b = _books()
+        assert sum(b["rows"].values()) == 80 and b["bytes_back"] == 240
+        assert b["gather_seconds"] > 0
+        assert _queue_depths() == {}  # the gauge keeps the master switch
+
+    def test_by_device_gives_issue_time_dispatches_and_rows(self):
+        ms = 1e-3
+
+        def sp(i, dev, t0, t1, rows):
+            return telemetry.Span(
+                i, None, "map_blocks.block", "dispatch", t0 * ms, t1 * ms, 0,
+                {"device": dev, "rows": rows},
+            )
+
+        agg = telemetry.span_aggregates(
+            [sp(1, "tpu:0", 0, 2, 10), sp(2, "tpu:0", 1, 4, 10),
+             sp(3, "tpu:1", 5, 6, 7)]
+        )
+        assert agg["by_device"] == {
+            "tpu:0": {"issue_s": pytest.approx(4 * ms), "dispatches": 2,
+                      "rows": 20.0},
+            "tpu:1": {"issue_s": pytest.approx(1 * ms), "dispatches": 1,
+                      "rows": 7.0},
+        }

@@ -607,7 +607,10 @@ _PLAN = ["graph.analyze", "frame.match", "executor.lookup", "scheduler.plan"]
 # sits in `shape.pad`, and there is no separate cut. The one-block frames
 # are numpy-backed and shorter than their rung: the replicated pad, as it
 # was, and no cut in either verb (a block that is the whole frame is fed
-# the column as it is: both verbs run `api._run_blocks`).
+# the column as it is: both verbs run `api._run_blocks`). The tests run on
+# several virtual devices, so the four blocks are scheduled over four of
+# them and their parts come back to the anchor in ONE `frame.gather`
+# (ISSUE 38), inside the concat.
 _CALLS = {
     "map_blocks-1block-on-rung": ("map_blocks", 64, 1, {}),
     "map_blocks-1block-off-rung": ("map_blocks", 40, 1, {
@@ -618,6 +621,7 @@ _CALLS = {
         "shape.pad": (4, "map_blocks.blocks"),
         "shape.unpad": (4, "map_blocks.blocks"),
         "frame.concat": (1, "map_blocks"),
+        "frame.gather": (1, "frame.concat"),
     }),
     "map_rows-dense": ("map_rows", 40, 1, {
         "shape.pad": (1, "map_rows.blocks"),
@@ -627,6 +631,7 @@ _CALLS = {
         "shape.pad": (4, "map_rows.blocks"),
         "shape.unpad": (4, "map_rows.blocks"),
         "frame.concat": (1, "map_rows"),
+        "frame.gather": (1, "frame.concat"),
     }),
 }
 

@@ -77,9 +77,14 @@ def main():
     chain_len = scaled("SCHED_CHAIN", 24)
 
     rng = np.random.RandomState(0)
+    # a host (numpy) frame: its blocks have no home, so every chip must
+    # be fed anyway and spreading them is what the scheduler does. A
+    # device-resident column under this row-local chain would stay on
+    # its device as one group (the home plan, `runtime.scheduler`) and
+    # measure nothing of the spread this bench asserts.
     df = tfs.TensorFrame.from_dict(
         {"x": rng.rand(rows).astype(np.float32)}, num_blocks=blocks
-    ).to_device()
+    )
 
     def graphs(frame):
         # a deliberately compute-heavy row-local chain: per-block

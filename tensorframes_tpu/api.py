@@ -553,8 +553,11 @@ def _combine_partials_scheduled(
 def _colocate_parts(parts: List, anchor=None) -> List:
     """Move parts spanning several devices onto one anchor device so a
     single jnp op can consume them (jax refuses committed arrays from
-    different devices in one computation). The block scheduler's map
-    outputs and stream partials hit this; everything is `device_put`
+    different devices in one computation). The block scheduler's spread
+    map outputs and stream partials hit this (a call planned at home,
+    `runtime.scheduler`, has its parts on the home device already: they
+    stay there, whatever the anchor, so its output lives with the input
+    columns it is appended to); everything is `device_put`
     (async D2D/H2D) — no host sync. The puts are ONE span a call,
     ``frame.gather`` (kind ``transfer``: the anchor, the parts, how many
     moved and their bytes), and the counters ``scheduler.bytes_back`` /
@@ -562,9 +565,10 @@ def _colocate_parts(parts: List, anchor=None) -> List:
     nothing.
 
     ``anchor`` (a jax device) is the scheduler's anchor: scheduled verbs
-    MUST pass it so every call over the same device set commits its
-    output to the SAME device — per-call anchors (e.g. most-rows) would
-    leave one frame's columns committed to different devices, and any
+    MUST pass it so every call that spread over the same device set
+    commits its output to the SAME device — per-call anchors (e.g.
+    most-rows) would leave one frame's columns committed to different
+    devices, and any
     later dispatch feeding two such columns into one jit call (the
     segment-plan aggregate, or any verb after turning the scheduler
     off) would crash on jax's incompatible-devices check. (Chaining
@@ -849,14 +853,19 @@ def _run_blocks(
     (`shape_policy.group_dispatch`: one pass of the program over the
     run's rows, which is what its blocks give row for row because
     ``bucketed`` programs are row-local), where the call is
-    ``bucketed`` on one device (no scheduler), nothing is bound or
-    trimmed, the outputs' names are known beforehand (a graph's
-    program gives a sequence, a plain function a dict) and the columns
-    are resident on that device (`shape_policy.block_runs`). A frame
-    that is one run has one part and no concat. Any other block, and a
-    run whose group ran out of memory (one pass holds the program's
-    temporaries at the run's rows), goes through the loop one block at
-    a time.
+    ``bucketed``, nothing is bound or trimmed, the outputs' names are
+    known beforehand (a graph's program gives a sequence, a plain
+    function a dict), the columns are resident on one device
+    (`shape_policy.block_runs`) and there is no schedule, or the
+    schedule is a home plan that has every block of the run on that
+    device (`BlockSchedule.at_home`). The group runs on the columns
+    where they are, so a schedule puts nothing and books the run once
+    (`BlockSchedule.note_run`). A frame that is one run has one part
+    and no concat. Any other block, and a run whose group ran out of
+    memory (one pass holds the program's temporaries at the run's rows)
+    or, under a schedule, met a transient fault (the block loop owns
+    retry, backoff and failover), goes through the loop one block at a
+    time.
 
     Per dispatch, a block's or a group's: classified fault handling
     (`runtime.faults`: transient errors retry with backoff and fail
@@ -873,10 +882,13 @@ def _run_blocks(
     col_names = [n for n in feed_names if n in columns]
     col_values = [columns[n] for n in col_names]
 
-    def _dispatch_group(bi: int, lo_: int, n: int, k: int) -> Optional[List]:
+    def _dispatch_group(
+        bi: int, lo_: int, n: int, k: int, end: int
+    ) -> Optional[List]:
         """The outputs over the ``k`` blocks of ``n`` rows from block
         ``bi`` (row ``lo_``) on, from one dispatch; None where the run
-        has no group, or its group ran out of memory."""
+        has no group, or its group ran out of memory or, under a
+        schedule, met a transient fault."""
         call = _sp.group_dispatch(fn, col_values, lo_, n, k)
         if call is None:
             return None
@@ -885,17 +897,27 @@ def _run_blocks(
             with _tele.dispatch_span(
                 f"{verb}.block", program=fp, block=bi, rows=k * n,
                 bucket=k * n, blocks=k,
+                device=sched.label(bi) if sched is not None else None,
             ):
-                return call(*col_values)
+                outs = call(*col_values)
+            if sched is not None:
+                sched.note_run(bi, end)
+            return outs
 
+        # under a schedule one attempt: the block loop fails over
+        scope = fscope if sched is None else _flt.scope(verb, attempts=0)
         try:
-            return list(fscope.dispatch(
+            return list(scope.dispatch(
                 _thunk,
                 what=f"{verb} blocks [{bi}:{bi + k}) rows "
                 f"[{lo_}:{lo_ + k * n})",
+                sched=sched,  # no index: a deadline's stamps, no eviction
             ))
         except Exception as e:
-            if _flt.classify(e) != _flt.RESOURCE:
+            fault = _flt.classify(e)
+            if fault == _flt.TRANSIENT and sched is not None:
+                return None
+            if fault != _flt.RESOURCE:
                 raise
             _flt.record_oom(
                 verb, fp, k * n, 0, f"split:{k} blocks of {n} rows, one by one",
@@ -980,10 +1002,15 @@ def _run_blocks(
     out_sizes: List[int] = []
     runs: Dict[int, Tuple[int, int, int]] = {}
     if (
-        frame.num_blocks > 1 and bucketed and sched is None
+        frame.num_blocks > 1 and bucketed
         and not trim and not bound and names
     ):
         runs = _sp.block_runs(col_values, frame.offsets)
+    # the device a schedule must have a run's blocks on for a group
+    home = (
+        _sp._resident_device(col_values)
+        if runs and sched is not None else None
+    )
     try:
         with _tele.span(f"{verb}.blocks", kind="stage"):
             bi = 0
@@ -995,7 +1022,9 @@ def _run_blocks(
                     continue  # empty block: contributes nothing (the reference's
                     # empty-partition TODO, `DebugRowOps.scala:386-387`)
                 n, k, end = runs.get(bi, (hi - lo, 1, bi + 1))
-                outs = _dispatch_group(bi, lo, n, k) if k > 1 else None
+                outs = None
+                if k > 1 and (sched is None or sched.at_home(bi, end, home)):
+                    outs = _dispatch_group(bi, lo, n, k, end)
                 if outs is None:  # one block, or of a run with no group
                     k, end = 1, bi + 1
                     outs = _dispatch_rows(bi, lo, hi, 0)
@@ -1075,11 +1104,15 @@ def map_blocks(
     placeholders a per-call array instead of a column — updates between
     calls do NOT recompile (see `_check_bindings`).
 
-    Without a mesh, per-block dispatches spread across
-    ``jax.local_devices()`` under the block scheduler
+    Without a mesh the block scheduler places the blocks
     (`runtime.scheduler`; ``config.block_scheduler``, default auto-on
-    when >1 local device). ``devices=`` pins the dispatch to an explicit
-    device list (one device = pinning); mesh= takes precedence.
+    when >1 local device): a row-local graph over columns that all live
+    on one local device stays on that device (the home plan: a run of
+    equal blocks is then one dispatch, and the output lives with its
+    input columns); any other call's per-block dispatches spread across
+    ``jax.local_devices()``. ``devices=`` spreads the blocks over an
+    explicit device list, block for block (one device = pinning);
+    mesh= takes precedence.
 
     On a `LazyFrame` — or on a plain frame under ``with tfs.lazy():``
     with graph fetches (function/``trim``/``bindings`` calls stay
@@ -1230,13 +1263,22 @@ def map_blocks(
                     {p: ph.shape.rank for p, ph in summary.inputs.items()},
                 )
             )
+        columns = {n: frame.column(c).values for n, c in mapping.items()}
         with _tele.span("scheduler.plan"):
-            sched = _rs.schedule_for(frame, devices=devices, executor=ex)
+            # a row-local program stays where its columns are (the home
+            # plan, `runtime.scheduler`)
+            sched = _rs.schedule_for(
+                frame, devices=devices, executor=ex,
+                home=(
+                    _sp._resident_device(list(columns.values()))
+                    if rowwise else None
+                ),
+            )
         bound = _place_bindings(bindings, sched, ex)
 
     out_cols, offsets = _run_blocks(
         "map_blocks", frame, fn, graph.fingerprint(), feed_names,
-        {n: frame.column(c).values for n, c in mapping.items()}, bound,
+        columns, bound,
         fetch_list, sched, trim=trim, rowwise=rowwise, bucketed=rowwise and _sp.enabled(ex),
         empty=lambda: {
             _base(f): _empty_output(summary, _base(f), drop_lead=True)

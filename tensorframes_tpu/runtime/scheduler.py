@@ -1,19 +1,39 @@
 """Multi-device block scheduler: data-parallel dispatch of blocks.
 
 The reference ran one TF session per Spark partition on whatever
-executor the cluster handed it; the port's non-mesh verbs inherited a
-single-device analogue — every per-block jit dispatch landed on the
-default JAX device, so on a multi-chip host every device but one sat
-idle unless the user hand-built a mesh. Blocks are an embarrassingly
-parallel unit of work; this module spreads them.
+executor the cluster handed it, WHERE THE PARTITION LIVED; the port's
+non-mesh verbs inherited a single-device analogue — every per-block jit
+dispatch landed on the default JAX device. Blocks are an embarrassingly
+parallel unit of work; this module places them.
 
-Placement is size-aware largest-first (LPT greedy): blocks sorted by
-row count descending are assigned one at a time to the least-loaded
-device, which bounds the makespan at 4/3 OPT and — crucially — is
-DETERMINISTIC, so a re-run dispatches every block to the same device
-and compiles nothing new. The dispatch loop itself stays in block
-order: assignment decides *where*, never *when*, so partial lists keep
-their block order and ordering-sensitive tests/semantics are untouched.
+Placement looks first at where the data is. HOME PLAN: a row-local map
+(`map_blocks`' graph route, a program `shape.classify` found row-local)
+whose feed columns all live whole on ONE device of the set, under a
+device set the scheduler chose itself (``config.block_scheduler``, not
+``devices=``), plans every non-empty block on that device. Such a
+program reads each row once and writes it once: HBM feeds it several
+times faster than any link out of the chip, so moving a block to an
+idle chip and its result back costs more than computing it at home,
+whatever the other chips are doing. The blocks of a home plan are
+neighbours on one device, so a run of equal ones is one group
+(`shape_policy.group_dispatch`), as it is with no schedule, and the
+output stays with the columns it is appended to. Nothing is sorted for
+a home plan, and no knob, device kind or bandwidth constant decides it:
+the planner's input is where the feeds are and the class the program
+was given. A home whose failover circuit is open is not in the set, and
+its blocks spread over the healthy devices as below.
+
+Everything else (an explicit ``devices=``: the user's placement, block
+for block; numpy, sharded or scattered columns: blocks with no home;
+programs not proven row-local, ``trim``, bound values, the function
+front end, `map_rows`, the reduce verbs and the stream) is placed
+size-aware largest-first (LPT greedy): blocks sorted by row count
+descending are assigned one at a time to the least-loaded device, which
+bounds the makespan at 4/3 OPT and — crucially — is DETERMINISTIC, so a
+re-run dispatches every block to the same device and compiles nothing
+new. The dispatch loop itself stays in block order: assignment decides
+*where*, never *when*, so partial lists keep their block order and
+ordering-sensitive tests/semantics are untouched.
 
 Execution placement rides jax's committed-input semantics: each block's
 feeds are `jax.device_put` onto the assigned device (async; H2D copies
@@ -475,16 +495,24 @@ class BlockSchedule:
     and rows issued, seconds the host spent in the feeds' `device_put`,
     bytes of the feeds that changed device or came from the host) and
     hands them over ONCE a call (`flush`): nothing is counted, gauged or
-    locked a block beyond the one locked section `_note_dispatch` has."""
+    locked a block beyond the one locked section `_note_dispatch` has.
+
+    A HOME plan (``home``: the slot of the one device that holds every
+    feed column, module docstring) has every non-empty item on that
+    slot. Its runs of equal blocks go out as one group on the columns
+    themselves (`at_home` says which, `note_run` books one), and its
+    first `flush` counts the plan: ``scheduler.home_plans`` 1,
+    ``scheduler.home_blocks`` the items it placed."""
 
     __slots__ = (
         "devices", "labels", "assignment", "executor", "weights",
         "_issued", "_remaining", "_lock", "_rows_known", "_books",
-        "_handed", "_dirty",
+        "_handed", "_dirty", "_home", "_home_blocks",
     )
 
     def __init__(self, devices: Tuple, assignment: List[Optional[int]],
-                 executor=None, weights: Optional[Sequence[int]] = None):
+                 executor=None, weights: Optional[Sequence[int]] = None,
+                 home: Optional[int] = None):
         self.devices = tuple(devices)
         self.labels = tuple(device_label(d) for d in self.devices)
         self.assignment = list(assignment)
@@ -513,6 +541,12 @@ class BlockSchedule:
         self._handed = [list(b) for b in self._books]
         # a schedule that never dispatched still has its plan to show
         self._dirty = True
+        self._home = home
+        # what the first flush counts as a home plan's blocks, then None
+        self._home_blocks = (
+            None if home is None
+            else sum(s is not None for s in self.assignment)
+        )
 
     @property
     def ndev(self) -> int:
@@ -542,7 +576,7 @@ class BlockSchedule:
         if s is None:
             return list(feeds)
         out, secs, moved = _place_feeds(feeds, self.devices[s])
-        self._note_dispatch(i, s, secs, moved)
+        self._note_dispatch((i,), s, secs, moved)
         # put-path verbs (reduce_rows folds, chunked aggregation) are
         # the only dispatches some workloads ever issue — a successful
         # transfer onto the device must close its half-open circuit
@@ -588,7 +622,7 @@ class BlockSchedule:
                 if n1 is not None and n1 > n0:
                     _bump(self.executor, "device_compiles",
                           self.labels[s], n1 - n0)
-            self._note_dispatch(i, s, secs, moved)
+            self._note_dispatch((i,), s, secs, moved)
             _health.mark_success(self.labels[s])
             return out
 
@@ -666,21 +700,44 @@ class BlockSchedule:
                     self._remaining[slot] += 1
         return label
 
+    def at_home(self, first: int, end: int, device) -> bool:
+        """Whether this is a home plan on ``device`` that still has
+        every item of ``[first, end)`` there (an `evict` may have
+        re-placed some): the run may then go out as one dispatch on the
+        columns themselves (`note_run`)."""
+        home = self._home
+        return (
+            home is not None
+            and self.devices[home] == device
+            and all(s is None or s == home
+                    for s in self.assignment[first:end])
+        )
+
+    def note_run(self, first: int, end: int) -> None:
+        """The items of ``[first, end)`` went out as ONE dispatch on the
+        home device, on the columns where they are: nothing was put."""
+        self._note_dispatch(
+            [i for i in range(first, end) if self.assignment[i] is not None],
+            self._home, 0.0, 0,
+        )
+        _health.mark_success(self.labels[self._home])
+
     def _note_dispatch(
-        self, i: int, s: int, put_seconds: float, bytes_in: int
+        self, items: Sequence[int], s: int, put_seconds: float, bytes_in: int
     ) -> None:
-        """Item ``i`` went out to slot ``s``: into the books, under the
-        one lock a dispatch takes. The plan's last dispatch hands the
-        books over (`flush`)."""
+        """``items`` went out to slot ``s`` in one dispatch: into the
+        books, under the one lock a dispatch takes. The plan's last
+        dispatch hands the books over (`flush`)."""
         dispatches, rows, seconds, moved = self._books
         with self._lock:
-            if not self._issued[i]:
-                # a block split after running out of memory goes out
-                # twice: its rows are booked once
-                self._issued[i] = True
-                if self._rows_known:
-                    rows[s] += self.weights[i]
-            self._remaining[s] = max(0, self._remaining[s] - 1)
+            for i in items:
+                if not self._issued[i]:
+                    # a block split after running out of memory goes out
+                    # twice: its rows are booked once
+                    self._issued[i] = True
+                    if self._rows_known:
+                        rows[s] += self.weights[i]
+                self._remaining[s] = max(0, self._remaining[s] - 1)
             dispatches[s] += 1
             seconds[s] += put_seconds
             moved[s] += bytes_in
@@ -696,7 +753,9 @@ class BlockSchedule:
         ``{device=}``) and the executor's ``device_dispatches`` ledger
         what was entered since the last flush, and set the gauge
         ``scheduler_queue_depth{device=}`` to the planned dispatches
-        that never went out (0 after a whole call). Runs by itself when
+        that never went out (0 after a whole call); a home plan's first
+        flush also counts it (``scheduler.home_plans``,
+        ``scheduler.home_blocks``). Runs by itself when
         the plan's last dispatch is issued; a caller whose call may end
         early (`api._run_blocks`) calls it in a ``finally``. With
         nothing entered since the last flush it hands over nothing."""
@@ -709,6 +768,10 @@ class BlockSchedule:
             now = [list(b) for b in self._books]
             was, self._handed = self._handed, now
             depth = list(self._remaining)
+            home_blocks, self._home_blocks = self._home_blocks, None
+        if home_blocks is not None:
+            _tele.counter_inc("scheduler.home_plans", 1.0)
+            _tele.counter_inc("scheduler.home_blocks", float(home_blocks))
         gauge = _tele.enabled()
         names = ("scheduler.dispatches", "scheduler.rows",
                  "scheduler.put_seconds", "scheduler.bytes_in")
@@ -770,25 +833,37 @@ def _bump(ex, attr: str, label: str, n: int) -> None:
 
 
 def schedule_weights(
-    weights: Sequence[int], devices=None, executor=None, mesh=None
+    weights: Sequence[int], devices=None, executor=None, mesh=None,
+    home=None,
 ) -> Optional[BlockSchedule]:
     """Resolve the device set and plan ``weights`` over it; None when
     scheduling is off for this dispatch (the caller then runs the
-    ordinary unscheduled loop)."""
+    ordinary unscheduled loop). ``home`` is the one device that holds
+    every feed column of a row-local map (None: there is none, or the
+    caller's program may be worth moving): where the set is the
+    scheduler's own choice and holds it, every item with rows is planned
+    there (the home plan, module docstring); else LPT over the rows."""
     devs = resolve(devices=devices, executor=executor, mesh=mesh)
     if devs is None:
         return None
+    if home is not None and devices is None and home in devs:
+        slot = devs.index(home)
+        return BlockSchedule(
+            devs, [slot if int(w) > 0 else None for w in weights],
+            executor=executor, weights=weights, home=slot,
+        )
     return BlockSchedule(
         devs, plan(weights, len(devs)), executor=executor, weights=weights
     )
 
 
 def schedule_for(
-    frame, devices=None, executor=None, mesh=None
+    frame, devices=None, executor=None, mesh=None, home=None
 ) -> Optional[BlockSchedule]:
     """`schedule_weights` over a frame's block sizes — the per-block
     verbs' entry point (one dispatch per non-empty block, weighted by
     row count)."""
     return schedule_weights(
-        frame.block_sizes(), devices=devices, executor=executor, mesh=mesh
+        frame.block_sizes(), devices=devices, executor=executor, mesh=mesh,
+        home=home,
     )

@@ -128,7 +128,9 @@ Windows, blocks on their rung, numpy or sharded columns, `block_feeds`
 themselves are untouched by both rules; no knob decides any of it.
 
 BLOCK GROUP (`block_runs` / `group_dispatch`, the same two loops, on one
-device: no scheduler): a frame cut into equal blocks asks the host for
+device: no scheduler, or a scheduler whose home plan keeps the blocks
+on the device that holds their columns, `runtime.scheduler`): a frame
+cut into equal blocks asks the host for
 the same work once a block, and for a small block that work (a window,
 a dispatch, an unpad, their spans and checks: about a millisecond) is
 many times the program's. Where every feed column is a `jax.Array`
@@ -155,10 +157,12 @@ decides is what the input shows: a block whose size no neighbour
 shares, numpy or sharded columns, a column too long for an int32 row
 index, a program with no ledger and a program whose outputs do not
 keep the run's rows run block by block as before, and so does every
-block under a scheduler, a trim or bound values (the caller's side,
+block a scheduler spread over devices (an explicit ``devices=``, or a
+plan with no home), a trim or bound values (the caller's side,
 `api._run_blocks`). One pass holds the program's temporaries at ``k *
 n`` rows where a block holds them at ``n``: a RESOURCE fault in a group
-sends its run back to the block loop, which may split rows.
+sends its run back to the block loop, which may split rows (and, under
+a schedule, so does a transient fault: the block loop owns failover).
 BOUND: a group executable is specific to the run's rows ``k * n`` (4 x
 10 and 5 x 8 share one), the columns' whole shapes and dtypes and the
 device, and is compiled on the calling thread at first sight, where

@@ -1044,6 +1044,11 @@ _PROM_HELP: Dict[str, str] = {
         "Bytes of scheduled feeds that changed device or came from the "
         "host, per receiving device"
     ),
+    "scheduler.home_plans": (
+        "Verb calls whose blocks were all planned on the one device that "
+        "holds their columns (a row-local map stays at home)"
+    ),
+    "scheduler.home_blocks": "Blocks planned by those home plans",
     "scheduler.bytes_back": (
         "Bytes of parts copied to the anchor device before a concat or stack"
     ),
@@ -1204,6 +1209,13 @@ def diagnostics_data(executor=None) -> Dict:
         "devices": _device_lines(agg["by_device"]),
         "programs": agg["by_program"],
     }
+    # calls the block scheduler kept on their columns' device, and the
+    # blocks of those (`runtime.scheduler`: the home plan)
+    counters = flat_counters()
+    data["scheduler"] = {
+        k: int(counters.get("scheduler." + k, 0))
+        for k in ("home_plans", "home_blocks")
+    }
 
     # cost ledger x span join ------------------------------------------
     try:
@@ -1228,7 +1240,6 @@ def diagnostics_data(executor=None) -> Dict:
 
     # bucketing pad waste + fill fractions ------------------------------
     try:
-        counters = flat_counters()
         fill: Dict[str, Dict] = {}
         for (name, labels), (
             _b, _c, hsum, hcount,
@@ -1443,6 +1454,13 @@ def _render_diagnostics(data: Dict) -> str:
                 f"rows={d['rows']:<10.0f} issue={d['issue_s']:.4f}s "
                 f"in={_fmt_bytes(d.get('bytes_in', 0))} "
                 f"put={d.get('put_s', 0.0):.4f}s"
+            )
+        home = data.get("scheduler", {})
+        if home.get("home_plans"):
+            lines.append(
+                f"  home plans: {home['home_plans']} call(s) kept "
+                f"{home['home_blocks']} block(s) of a row-local map on "
+                "the device that holds their columns"
             )
     if data["programs"]:
         lines.append("")

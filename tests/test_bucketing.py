@@ -362,7 +362,9 @@ def _group_counters():
 
 # case -> (verb, frame, fetches, scheduler, groups, blocks they cover,
 # windows, padded dispatches, first-size dispatches, pad rows); rungs 8,
-# 16, 32, ...
+# 16, 32, ... The scheduler is a `config.block_scheduler` mode, or
+# "devices": an explicit `devices=` of four (the user's placement, block
+# for block: no home plan, ISSUE 39).
 _GROUP_CASES = {
     "200-equal-blocks": (
         "map_blocks", lambda: _resident({"x": _ints(2000)}, [10] * 200),
@@ -410,6 +412,21 @@ _GROUP_CASES = {
         "map_rows", lambda: _resident({"x": _ints(60, width=3)}, [20] * 3),
         _rows_times_two, "off", 1, 3, 0, 0, 0, 0,
     ),
+    # a schedule the scheduler made itself keeps a row-local map on the
+    # device that holds its column (the home plan, ISSUE 39): the group
+    "home-plan-auto": (
+        "map_blocks", lambda: _resident({"x": _ints(100)}, [10] * 10),
+        _times_two, "auto", 1, 10, 0, 0, 0, 0,
+    ),
+    "home-plan-on-and-a-remainder": (
+        "map_blocks", lambda: _resident({"x": _ints(57)}, [10] * 5 + [7]),
+        _times_two, "on", 1, 5, 1, 0, 0, 1,
+    ),
+    # ... but `map_rows`' program may be worth moving: spread as before
+    "control-map_rows-under-a-scheduler": (
+        "map_rows", lambda: _resident({"x": _ints(60, width=3)}, [20] * 3),
+        _rows_times_two, "auto", 0, 0, 3, 0, 0, 36,
+    ),
     # controls: no run of two, no resident column, more than one device
     "control-one-block": (
         "map_blocks", lambda: _resident({"x": _ints(40)}, [40]),
@@ -435,7 +452,7 @@ _GROUP_CASES = {
     ),
     "control-scheduler-over-devices": (
         "map_blocks", lambda: _resident({"x": _ints(100)}, [10] * 10),
-        _times_two, "auto", 0, 0, 10, 0, 0, 60,
+        _times_two, "devices", 0, 0, 10, 0, 0, 60,
     ),
 }
 
@@ -469,14 +486,23 @@ class TestBlockGroup:
         df = make()
         fetch = fetch_of(df)
         # the block loop, unbucketed, and the block loop on the ladder
-        # (a scheduler keeps it): both ways the program sees each block
+        # (explicit devices keep it): both ways the program sees each block
+        import jax
+
+        four = jax.local_devices()[:4]
         with tfs.config.override(shape_bucketing=False):
             want, _ = _run_columns(verb, fetch, df)
-        with tfs.config.override(block_scheduler="on"):
-            laddered, _ = _run_columns(verb, fetch, df, executor=Executor())
+        laddered, _ = _run_columns(
+            verb, fetch, df, executor=Executor(), devices=four
+        )
         tele.reset()
-        with tfs.config.override(block_scheduler=scheduler):
-            got, out = _run_columns(verb, fetch, df, executor=Executor())
+        if scheduler == "devices":
+            got, out = _run_columns(
+                verb, fetch, df, executor=Executor(), devices=four
+            )
+        else:
+            with tfs.config.override(block_scheduler=scheduler):
+                got, out = _run_columns(verb, fetch, df, executor=Executor())
         for name in want:
             np.testing.assert_array_equal(got[name], want[name])
             np.testing.assert_array_equal(got[name], laddered[name])
@@ -616,33 +642,55 @@ class TestBlockGroup:
         c = _group_counters()
         assert (c["group_dispatch"], c["grouped_blocks"]) == (2, 9)
 
+    @pytest.mark.parametrize("scheduler", ["off", "auto"])
     @pytest.mark.parametrize("fault", ["resource", "transient"])
-    def test_faults_keep_their_meaning(self, fault):
-        """A transient fault retries the group; a group that runs out of
-        memory hands its run back to the block loop, which may split."""
+    def test_faults_keep_their_meaning(self, fault, scheduler):
+        """With no schedule a transient fault retries the group; under a
+        home plan (ISSUE 39) it hands the run to the block loop, which
+        owns retry and failover, as a group that runs out of memory does
+        either way (the loop may split). The schedule's books count
+        each block once, on the column's device."""
+        from tensorframes_tpu.runtime.scheduler import device_label
         from tensorframes_tpu.testing import faults as chaos
+        from tensorframes_tpu.utils import telemetry as tele
 
         df = _resident({"x": _ints(50)}, [10] * 5)
         with tfs.config.override(shape_bucketing=False):
             want = np.asarray(tfs.map_blocks(_times_two(df), df)["z"].values)
-        with tfs.config.override(block_scheduler="off"):
+        tele.reset()
+        with tfs.config.override(block_scheduler=scheduler):
             with chaos.inject(nth=[0], fault=fault) as plan:
                 got = tfs.map_blocks(_times_two(df), df, executor=Executor())
         np.testing.assert_array_equal(np.asarray(got["z"].values), want)
         assert plan.faulted_ordinals == [0]
         c = _group_counters()
         stats = executor_stats()["faults"]
-        if fault == "transient":
+        if fault == "transient" and scheduler == "off":
             assert plan.dispatches == 2  # the group, and the group again
             assert (c["group_dispatch"], c["window_dispatch"]) == (1, None)
             assert not stats["forensics"]
         else:
             assert plan.dispatches == 1 + 5  # the group, then its blocks
             assert (c["group_dispatch"], c["window_dispatch"]) == (1, 5)
+        if fault == "resource":
             (snap,) = stats["forensics"]
             assert snap["decision"] == "split:5 blocks of 10 rows, one by one"
             assert (snap["rows"], snap["depth"]) == (50, 0)
             assert stats["splits"] == 1
+        flat = tele.flat_counters()
+        if scheduler == "off":
+            assert not [k for k in flat if k.startswith("scheduler.")]
+        else:
+            (home,) = df["x"].values.devices()
+            lab = device_label(home)
+            assert flat["scheduler.home_plans"] == 1
+            assert flat["scheduler.home_blocks"] == 5
+            assert flat[f"scheduler.dispatches{{device={lab}}}"] == 5
+            rows = {k: v for k, v in flat.items()
+                    if k.startswith("scheduler.rows{")}
+            assert rows.pop(f"scheduler.rows{{device={lab}}}") == 50
+            assert rows and not any(rows.values())
+            assert got["z"].values.devices() == {home}
 
     def test_numerics_and_row_checks_see_the_group(self):
         """`check_numerics` names the run's blocks, and the outputs a

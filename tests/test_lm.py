@@ -8,6 +8,7 @@ front end's block loop and spans.
 """
 
 import filecmp
+import functools
 import importlib.util
 import os
 
@@ -354,7 +355,9 @@ def test_function_front_end_opens_the_graph_front_ends_spans(verb):
     ph = (tfs.block if verb == "map_blocks" else tfs.row)(df, "x")
     graph = (ph * 2.0).named("z")
     fn = lambda x: {"z": x * 2.0}
-    run = getattr(tfs, verb)
+    # devices=: both front ends block by block (left to the scheduler, a
+    # row-local graph's equal blocks are one group on the column's device)
+    run = functools.partial(getattr(tfs, verb), devices=jax.local_devices()[:4])
     ex = Executor()
     of_graph = _span_names(lambda: run(graph, df, executor=ex))
     of_fn = _span_names(lambda: run(fn, df, executor=ex))

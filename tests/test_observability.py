@@ -45,6 +45,14 @@ def _frame(rows=4096, blocks=8):
     ).to_device()
 
 
+def _block_by_block():
+    """``devices=`` for a map over `_frame()`: the caller's placement,
+    block for block. Left to the scheduler, a row-local map of these
+    resident equal blocks is ONE dispatch on the column's device (the
+    home plan and the block group), not one a block."""
+    return jax.local_devices()[:4]
+
+
 def _chained_lazy(df, executor=None):
     lf = df.lazy().map_blocks(
         (tfs.block(df, "x") * 2.0 + 1.0).named("y"), executor=executor
@@ -98,11 +106,12 @@ class TestCostLedger:
     def test_exec_counts_are_exact(self):
         df = _frame(rows=512, blocks=4)
         z = (tfs.block(df, "x") * 3.0).named("y")
-        tfs.map_blocks(z, df)  # warm: compiles + first 4 execs
+        four = _block_by_block()
+        tfs.map_blocks(z, df, devices=four)  # warm: compiles + first 4 execs
         before = {
             fp: c["execs"] for fp, c in costmodel.program_costs().items()
         }
-        tfs.map_blocks(z, df)
+        tfs.map_blocks(z, df, devices=four)
         after = costmodel.program_costs()
         grew = {
             fp: after[fp]["execs"] - before.get(fp, 0)
@@ -139,7 +148,10 @@ class TestCostLedger:
 
     def test_roofline_fractions_with_known_peak(self, monkeypatch):
         df = _frame(rows=512, blocks=2)
-        out = tfs.map_blocks((tfs.block(df, "x") * 0.5).named("y"), df)
+        out = tfs.map_blocks(
+            (tfs.block(df, "x") * 0.5).named("y"), df,
+            devices=_block_by_block(),
+        )
         jax.block_until_ready(out["y"].values)
         kind = costmodel.device_peaks()["device_kind"]
         monkeypatch.setitem(
@@ -192,9 +204,10 @@ class TestOomForensics:
         program, its modeled footprint, and the split decision."""
         df = _frame(rows=2048, blocks=4)
         z = (tfs.block(df, "x") * 2.0 + 1.0).named("y")
-        ref = np.asarray(tfs.map_blocks(z, df)["y"].values)
+        four = _block_by_block()
+        ref = np.asarray(tfs.map_blocks(z, df, devices=four)["y"].values)
         with chaos.inject(nth=[1], fault="resource") as plan:
-            got = np.asarray(tfs.map_blocks(z, df)["y"].values)
+            got = np.asarray(tfs.map_blocks(z, df, devices=four)["y"].values)
         assert plan.injected == 1
         np.testing.assert_array_equal(ref, got)
 
@@ -216,11 +229,12 @@ class TestOomForensics:
     def test_depth_exhausted_records_reraise_decision(self):
         df = _frame(rows=1024, blocks=2)
         z = (tfs.block(df, "x") + 1.0).named("y")
-        tfs.map_blocks(z, df)  # warm: the ledger knows the program
+        four = _block_by_block()
+        tfs.map_blocks(z, df, devices=four)  # warm: the ledger knows the program
         with config.override(oom_split_depth=0):
             with chaos.inject(nth=[0], fault="resource"):
                 with pytest.raises(chaos.InjectedFault):
-                    tfs.map_blocks(z, df)
+                    tfs.map_blocks(z, df, devices=four)
         snaps = rt_faults.forensics_snapshot()
         assert snaps and snaps[-1]["decision"] == (
             "reraise:split-depth-exhausted"

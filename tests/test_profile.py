@@ -422,9 +422,11 @@ class TestBucketFill:
     def test_exact_rung_observes_full_fill(self):
         ex = Executor()
         df = _frame(rows=4096, blocks=8)  # 512-row blocks: exact rung
+        # devices=: block by block (left to the scheduler, equal blocks of
+        # a resident column are one group on its device: one observation)
         tfs.map_blocks(
             (tfs.block(df, "x") * float(next(_UNIQ) + 2)).named("y"),
-            df, executor=ex,
+            df, executor=ex, devices=jax.local_devices()[:4],
         )
         hists = telemetry.metrics_snapshot()[2]
         _b, _c, hsum, hcount = hists[("bucket_fill", (("verb", "map_blocks"),))]

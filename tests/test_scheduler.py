@@ -859,3 +859,268 @@ class TestBooks:
             "tpu:1": {"issue_s": pytest.approx(1 * ms), "dispatches": 1,
                       "rows": 7.0},
         }
+
+
+# ---------------------------------------------------------------------------
+# the home plan (ISSUE 39): a row-local map stays on the device that holds
+# its columns, where the scheduler chose the devices itself; everything
+# else is planned as before
+# ---------------------------------------------------------------------------
+
+
+def _labels(devices=None):
+    return [rs.device_label(d) for d in devices or jax.local_devices()]
+
+
+def _plus_three(df):
+    return (tfs.block(df, "x") + 3.0).named("z")
+
+
+def _scheduler_writes(monkeypatch):
+    """Counts, from here on, the registry writes under ``scheduler.*``
+    and of the queue-depth gauge."""
+    writes = {"counter": 0, "gauge": 0}
+    reg = telemetry._registry
+    real_inc, real_set = reg.counter_inc, reg.gauge_set
+
+    def counter_inc(name, value=1.0, **labels):
+        writes["counter"] += name.startswith("scheduler.")
+        return real_inc(name, value, **labels)
+
+    def gauge_set(name, value, **labels):
+        writes["gauge"] += name == "scheduler_queue_depth"
+        return real_set(name, value, **labels)
+
+    monkeypatch.setattr(reg, "counter_inc", counter_inc)
+    monkeypatch.setattr(reg, "gauge_set", gauge_set)
+    return writes
+
+
+def _two_devices_frame():
+    import jax.numpy as jnp
+
+    x = jax.device_put(jnp.arange(80.0, dtype=jnp.float32), FOUR[0])
+    y = jax.device_put(jnp.ones(80, dtype=jnp.float32), FOUR[1])
+    return tfs.TensorFrame(
+        [tfs.Column("x", x), tfs.Column("y", y)], [10 * i for i in range(9)]
+    )
+
+
+def _bound_fetch(df):
+    from tensorframes_tpu.schema import ScalarType, Shape
+
+    w = dsl.placeholder(ScalarType.float32, Shape(()), name="w")
+    return (tfs.block(df, "x") * w).named("z")
+
+
+def _minus_its_sum(x):
+    return (x - dsl.reduce_sum(x, axes=[0])).named("z")
+
+
+_X_PLUS_3 = [3.0, 4.0, 5.0]
+
+# case -> (frame, what to call on it, the devices its plan is LPT over
+# (None: every local device), the dispatch spans' verb, the output's
+# first three rows); every frame has 8 blocks of 10 rows
+_AS_TODAY = {
+    "explicit-devices": (
+        lambda: _resident(8),
+        lambda df: tfs.map_blocks(_plus_three(df), df, devices=FOUR),
+        FOUR, "map_blocks", _X_PLUS_3,
+    ),
+    "numpy-column": (
+        lambda: _frame([10] * 8),
+        lambda df: tfs.map_blocks(_plus_three(df), df), None, "map_blocks", _X_PLUS_3,
+    ),
+    "two-columns-on-two-devices": (
+        _two_devices_frame,
+        lambda df: tfs.map_blocks(
+            (tfs.block(df, "x") * 2.0 + tfs.block(df, "y")).named("z"), df
+        ),
+        None, "map_blocks", [1.0, 3.0, 5.0],
+    ),
+    "a-graph-with-a-reduction": (
+        lambda: _resident(8),
+        lambda df: tfs.map_blocks(_minus_its_sum(tfs.block(df, "x")), df),
+        None, "map_blocks", [-45.0, -44.0, -43.0],
+    ),
+    "trim": (
+        lambda: _resident(8),
+        lambda df: tfs.map_blocks(_plus_three(df), df, trim=True),
+        None, "map_blocks", _X_PLUS_3,
+    ),
+    "bindings": (
+        lambda: _resident(8),
+        lambda df: tfs.map_blocks(
+            _bound_fetch(df), df, bindings={"w": np.float32(2.0)}
+        ),
+        None, "map_blocks", [0.0, 2.0, 4.0],
+    ),
+    "map_rows": (
+        lambda: _resident(8),
+        lambda df: tfs.map_rows((tfs.row(df, "x") + 3.0).named("z"), df),
+        None, "map_rows", _X_PLUS_3,
+    ),
+    "function-front-end": (
+        lambda: _resident(8),
+        lambda df: tfs.map_blocks(lambda x: {"z": x + 3.0}, df),
+        None, "map_blocks", _X_PLUS_3,
+    ),
+    "home-circuit-open": (
+        lambda: _resident(8),
+        lambda df: tfs.map_blocks(_plus_three(df), df),
+        "healthy", "map_blocks", _X_PLUS_3,
+    ),
+}
+
+
+@four_devices
+class TestHomePlan:
+    @pytest.mark.parametrize("where", ["first-uncommitted", "last-committed"])
+    @pytest.mark.parametrize("mode", ["auto", "on"])
+    def test_a_row_local_map_stays_where_its_column_is(
+        self, mode, where, monkeypatch
+    ):
+        """One group a call on the column's device (the last one as
+        well as the anchor), nothing put, nothing gathered; what a call
+        records and hands the registry is the same for 8 blocks as for
+        16; the output lives with the column, bit-equal to no schedule."""
+        home = None if where == "first-uncommitted" else jax.local_devices()[-1]
+        calls, seen = 2, {}
+        for k in (8, 16):
+            df = _resident(k, device=home)
+            (dev,) = df["x"].values.devices()
+            z, ex = _plus_three(df), Executor()
+            with tfs.config.override(block_scheduler="off"):
+                want = np.asarray(tfs.map_blocks(z, df)["z"].values)
+            with tfs.config.override(block_scheduler=mode):
+                tfs.map_blocks(z, df, executor=ex)  # compiles
+                telemetry.reset()
+                writes = _scheduler_writes(monkeypatch)
+                for _ in range(calls):
+                    out = tfs.map_blocks(z, df, executor=ex)
+                monkeypatch.undo()
+            assert out["z"].values.devices() == {dev}
+            np.testing.assert_array_equal(np.asarray(out["z"].values), want)
+            names = [s.name for s in telemetry.spans()]
+            seen[k] = ({n: names.count(n) for n in set(names)}, dict(writes))
+            flat, b, lab = telemetry.flat_counters(), _books(), rs.device_label(dev)
+            assert flat["shape_bucketing.group_dispatch"] == calls
+            assert flat["shape_bucketing.grouped_blocks"] == calls * k
+            assert "shape_bucketing.window_dispatch" not in flat
+            assert flat["scheduler.home_plans"] == calls
+            assert flat["scheduler.home_blocks"] == calls * k
+            others = [x for x in _labels() if x != lab]
+            assert b["rows"] == {lab: 10.0 * k * calls, **{o: 0.0 for o in others}}
+            assert b["dispatches"] == {lab: 1.0 * calls, **{o: 0.0 for o in others}}
+            assert not any(b["bytes_in"].values())
+            assert not any(b["put_seconds"].values())
+            assert b["bytes_back"] == 0.0 and b["gather_seconds"] == 0.0
+            assert _dispatch_devices("map_blocks.block") == [lab] * calls
+            assert set(_queue_depths().values()) == {0.0}
+            assert executor_stats(ex)["device_dispatches"] == {lab: 1 + calls}
+        want_names = {n: calls for n in (
+            "map_blocks", "map_blocks.plan", "graph.analyze", "frame.match",
+            "executor.lookup", "shape.classify", "scheduler.plan",
+            "map_blocks.blocks", "map_blocks.block",
+        )}
+        want_writes = {"counter": calls * (4 * NDEV + 2), "gauge": calls * NDEV}
+        assert seen[8] == seen[16] == (want_names, want_writes)
+        report = tfs.diagnostics()
+        assert f"home plans: {calls} call(s) kept {calls * 16} block(s)" in report
+        assert tfs.diagnostics(format="json")["scheduler"] == {
+            "home_plans": calls, "home_blocks": calls * 16}
+
+    @pytest.mark.parametrize("case", sorted(_AS_TODAY))
+    def test_planned_as_before(self, case):
+        """What has no home, what may be worth moving and what the user
+        placed: LPT over the rows, block for block, and no home plan."""
+        make, call, over, verb, first3 = _AS_TODAY[case]
+        df = make()
+        devices = jax.local_devices() if over is None else over
+        if over == "healthy":
+            (home,) = df["x"].values.devices()
+            rs.device_health().mark_failure(rs.device_label(home))
+            devices = [d for d in jax.local_devices() if d != home]
+        telemetry.reset()
+        with tfs.config.override(block_scheduler="auto"):
+            out = call(df)
+        np.testing.assert_array_equal(np.asarray(out["z"].values)[:3], first3)
+        labels = _labels(devices)
+        expect = rs.plan(df.block_sizes(), len(devices))
+        assert _dispatch_devices(f"{verb}.block") == [labels[s] for s in expect]
+        assert len(set(expect)) == min(8, len(devices))
+        flat = telemetry.flat_counters()
+        assert "scheduler.home_plans" not in flat
+        assert "scheduler.home_blocks" not in flat
+        assert "shape_bucketing.group_dispatch" not in flat
+        assert tfs.diagnostics(format="json")["scheduler"] == {
+            "home_plans": 0, "home_blocks": 0}
+        assert "home plans:" not in tfs.diagnostics()
+
+    @pytest.mark.parametrize("where", ["first-uncommitted", "last-committed"])
+    def test_unequal_neighbours_run_block_by_block_at_home(self, where):
+        """No run to group: every block a window on the home device,
+        nothing moved, the parts joined there without a gather."""
+        import jax.numpy as jnp
+
+        x = jnp.arange(38.0, dtype=jnp.float32)
+        if where == "last-committed":
+            x = jax.device_put(x, jax.local_devices()[-1])
+        (dev,) = x.devices()
+        df = tfs.TensorFrame([tfs.Column("x", x)], [0, 10, 19, 29, 38])
+        z = _plus_three(df)
+        with tfs.config.override(block_scheduler="off"):
+            want = np.asarray(tfs.map_blocks(z, df)["z"].values)
+        telemetry.reset()
+        out = tfs.map_blocks(z, df, executor=Executor())
+        np.testing.assert_array_equal(np.asarray(out["z"].values), want)
+        assert out["z"].values.devices() == {dev}
+        lab, flat, b = rs.device_label(dev), telemetry.flat_counters(), _books()
+        assert _dispatch_devices("map_blocks.block") == [lab] * 4
+        assert flat["shape_bucketing.window_dispatch"] == 4
+        assert "shape_bucketing.group_dispatch" not in flat
+        assert (flat["scheduler.home_plans"], flat["scheduler.home_blocks"]) == (1, 4)
+        assert b["rows"][lab] == 38 and sum(b["rows"].values()) == 38
+        assert b["dispatches"][lab] == 4 and sum(b["dispatches"].values()) == 4
+        assert not any(b["bytes_in"].values()) and b["bytes_back"] == 0.0
+        names = [s.name for s in telemetry.spans()]
+        assert names.count("frame.concat") == 1 and "frame.gather" not in names
+
+    def test_a_deadline_in_the_group_carries_the_plans_progress(self):
+        from tensorframes_tpu.runtime import deadline as dl
+        from tensorframes_tpu.testing import faults as chaos
+
+        df = _resident(8)
+        z = _plus_three(df)
+        tfs.map_blocks(z, df)  # compiles
+        with chaos.inject(nth=[0], fault="hang", delay_s=10.0):
+            with pytest.raises(dl.DeadlineExceeded) as hung:
+                tfs.map_blocks(z, df, timeout_s=0.3)
+        assert hung.value.tfs_blocks_issued == 0
+        assert hung.value.tfs_blocks_unissued == 8
+        (lab,) = _labels(df["x"].values.devices())
+        assert _queue_depths()[lab] == 8.0  # what the call never issued
+
+    def test_the_plan_itself(self):
+        """`schedule_weights`: the home's slot for every item with rows,
+        None for an empty one; a home outside the set, or an explicit
+        device list, is LPT."""
+        sizes = [5, 0, 7, 7, 0, 3]
+        with tfs.config.override(block_scheduler="on"):
+            sched = rs.schedule_weights(sizes, home=FOUR[2])
+            assert sched.devices == tuple(jax.local_devices())
+            assert sched.assignment == [2, None, 2, 2, None, 2]
+            assert sched.at_home(0, 6, FOUR[2])
+            assert not sched.at_home(0, 6, FOUR[1])
+            pinned = rs.schedule_weights(sizes, devices=FOUR, home=FOUR[2])
+            assert pinned.assignment == rs.plan(sizes, 4)
+            assert not pinned.at_home(0, 1, FOUR[2])
+            away = rs.schedule_weights(sizes, devices=FOUR[:2], home=FOUR[2])
+            assert away.assignment == rs.plan(sizes, 2)
+        with tfs.config.override(block_scheduler="off"):
+            assert rs.schedule_weights(sizes, home=FOUR[2]) is None
+        # a block re-placed by a failover is no longer at home
+        sched.assignment[3] = 0
+        assert sched.at_home(0, 3, FOUR[2]) and not sched.at_home(2, 4, FOUR[2])
+

@@ -608,9 +608,12 @@ _PLAN = ["graph.analyze", "frame.match", "executor.lookup", "scheduler.plan"]
 # are numpy-backed and shorter than their rung: the replicated pad, as it
 # was, and no cut in either verb (a block that is the whole frame is fed
 # the column as it is: both verbs run `api._run_blocks`). The tests run on
-# several virtual devices, so the four blocks are scheduled over four of
-# them and their parts come back to the anchor in ONE `frame.gather`
-# (ISSUE 38), inside the concat.
+# several virtual devices, so four blocks spread over four of them come
+# back to the anchor in ONE `frame.gather` (ISSUE 38), inside the concat:
+# `map_rows`' by the scheduler's own choice, `map_blocks`' where the caller
+# names the devices (`devices=`: "-spread"). Left to the scheduler, a
+# row-local `map_blocks` stays on the device that holds its column (the
+# home plan, ISSUE 39), where its four equal blocks are one group.
 _CALLS = {
     "map_blocks-1block-on-rung": ("map_blocks", 64, 1, {}),
     "map_blocks-1block-off-rung": ("map_blocks", 40, 1, {
@@ -618,6 +621,9 @@ _CALLS = {
         "shape.unpad": (1, "map_blocks.blocks"),
     }),
     "map_blocks-4blocks-off-rung": ("map_blocks", 40, 4, {
+        "map_blocks.block": (1, "map_blocks.blocks"),
+    }),
+    "map_blocks-4blocks-off-rung-spread": ("map_blocks", 40, 4, {
         "shape.pad": (4, "map_blocks.blocks"),
         "shape.unpad": (4, "map_blocks.blocks"),
         "frame.concat": (1, "map_blocks"),
@@ -639,11 +645,13 @@ _CALLS = {
 def _warm_call(case):
     """The case's verb call, twice: the first compiles, the ring is
     cleared, and the second is the one the test reads."""
+    import functools
+
+    import jax
+
     verb, rows, blocks, extra = _CALLS[case]
     x = np.arange(rows, dtype=np.float32)
     if blocks > 1:
-        import jax
-
         x = jax.device_put(x)
     df = tfs.TensorFrame(
         [tfs.Column("x", x)],
@@ -652,6 +660,8 @@ def _warm_call(case):
     ph = (tfs.block if verb == "map_blocks" else tfs.row)(df, "x")
     fetch = (ph * 2.0).named("z")
     run = getattr(tfs, verb)
+    if case.endswith("-spread"):
+        run = functools.partial(run, devices=jax.local_devices()[:4])
     run(fetch, df)
     tele.reset()
     out = run(fetch, df)
@@ -740,7 +750,7 @@ class TestSpansBelowTheVerb:
             assert agg["by_name"]["leaf"]["self_seconds"] == pytest.approx(1 * ms)
 
     def test_diagnostics_prints_the_by_name_table(self):
-        _warm_call("map_blocks-4blocks-off-rung")
+        _warm_call("map_blocks-4blocks-off-rung-spread")
         data = tfs.diagnostics(format="json")
         assert data["phases"]["shape.pad"]["count"] == 4
         assert "verb_roofline" not in data["cost"]

@@ -8,7 +8,8 @@ The configuration is a dict with a published ``config.json``'s keys
 router's options), under any of three families' names for them
 (`family_keys`: ``first_k_dense_replace``, ``n_routed_experts``,
 ``rms_norm_eps``, ``scoring_func``, ``topk_method``,
-``hybrid_override_pattern``): there is no class per model. A layer is
+``hybrid_override_pattern``, ``mlp_layer_types``, ``rope_parameters``):
+there is no class per model. A layer is
 ``r = h + Op(RMSNorm(h))``,
 ``h' = r + FFN(RMSNorm(r))`` with ``Op`` by ``layer_types[i]`` — "conv",
 the gated short convolution; "full_attention", grouped-query attention
@@ -31,6 +32,26 @@ convolution and SiLU over ``x B C``, the state-space scan of
 layer as the layer's operator ("experts": relu² experts of two matrices in
 a ``moe_latent_size`` latent the input is projected into and their sum
 out of, beside a shared expert of its own width on the residual width).
+A configuration whose ``layer_types`` are ``deepseek_sparse_attention``
+(`_sparse_keys`: ``indexer_types``, ``mlp_layer_types``,
+``rope_parameters``) has gated latent attention on a learned sparse index
+in every layer ("sparse_attention", `_sparse_attention_op`): latent
+attention's projections; on a ``full`` layer a lightning indexer
+(``index_n_heads`` heads of ``index_head_dim``, ReLU scores weighted by
+the heads, `ops.pallas_kernels.index_scores`) keeps each query's
+``index_topk`` best earlier keys (`_select`), and the ``shared`` layers
+after it attend to the same keys, carried by the layer scan; the score of
+the kept keys runs on `ops.pallas_kernels.sparse_attention` with a
+learned sink logit a head in the softmax's denominator, and the heads'
+output is gated elementwise by ``sigmoid(u W_g)`` (``gated_mla``). Its
+FFNs are SwiGLUs clamped at ``swiglu_limit``, the experts routed with no
+correction bias; its head is float32 (``enable_lm_head_fp32``). With
+``enable_ihc`` the residual is ``hc_mult`` float32 streams and each
+sublayer F is a manifold-constrained hyper-connection (`_hc_sublayer`):
+``X <- M X + a_post ⊗ F(RMSNorm(Σ_i a_pre[i] X[i]))``, the coefficients
+from the normed streams, ``M`` a Sinkhorn-normalised mixing; the streams
+are read out by ``a_head`` after the last layer. A configuration without
+these keys traces none of it.
 
 Parameters are one pytree of arrays stacked by kind (every conv part's
 ``w_in`` in one array, every expert layer's ``w_up`` in one, ...), and all
@@ -51,7 +72,9 @@ layers, seq, experts per token) int32, the experts each token went to (so
 that a checker can follow the very routing the program took: a rounded
 residual stream swaps a token's k-th and (k+1)-th expert where their
 scores are close, and every later number then differs for that reason
-alone). `score` is that call with its counters.
+alone). Under sparse attention a fourth, ``index_choice`` (rows, ``full``
+layers, seq, index_topk) int16, the keys each query kept (-1 past t + 1),
+for the same reason. `score` is that call with its counters.
 """
 
 from __future__ import annotations
@@ -63,19 +86,21 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..ops.pallas_kernels import flash_attention, ssd_scan
+from ..ops.pallas_kernels import flash_attention, index_scores, sparse_attention, ssd_scan
 from . import moe
 
 __all__ = [
     "init_params", "scoring_fn", "score", "layer_plan", "held_all", "family_keys",
 ]
 
-OPS = ("conv", "full_attention", "latent_attention", "ssm", "experts")
-ATTENTION = (1, 2)  # the operator kinds that attend
-SSM, EXPERTS = OPS.index("ssm"), OPS.index("experts")
+OPS = ("conv", "full_attention", "latent_attention", "ssm", "experts", "sparse_attention")
+ATTENTION = (1, 2)  # the operator kinds that attend to every earlier key
+SSM, EXPERTS, SPARSE = OPS.index("ssm"), OPS.index("experts"), OPS.index("sparse_attention")
 NO_FFN = -1  # a layer of one mixer: no FFN half
 PATTERN = {"M": "ssm", "*": "full_attention", "E": "experts"}
 HEAD_CHUNK = 2048  # tokens whose logits exist at one time
+INDEX_QUERIES = 1024  # queries whose index scores exist at one time
+SINKHORN = 20  # a hyper-connection's row and column normalisations
 
 
 def family_keys(config) -> dict:
@@ -111,6 +136,8 @@ def family_keys(config) -> dict:
         for key, value in (("one_mixer", True), ("qk_norm", False), ("rope", False),
                            ("use_expert_bias", True)):
             c.setdefault(key, value)
+    if "deepseek_sparse_attention" in c.get("layer_types", ()):
+        _sparse_keys(c)
     if "layer_types" not in c:
         if "kv_lora_rank" not in c:
             raise ValueError(
@@ -157,6 +184,42 @@ def family_keys(config) -> dict:
             f"rope_scaling = {c['rope_scaling']!r}: RoPE length scaling is not computed here"
         )
     return c
+
+
+def _sparse_keys(c):
+    """In place: the names of a family of gated latent attention on a
+    learned sparse index (``layer_types: deepseek_sparse_attention``,
+    ``indexer_types`` "full" / "shared", ``mlp_layer_types`` "dense" /
+    "sparse", ``rope_parameters``), with hyper-connections
+    (``enable_ihc``, ``hc_mult``) and a float32 head where it says so.
+    What is not computed raises by its key."""
+    types = list(c["layer_types"])
+    n = int(c.get("num_hidden_layers", len(types)))
+    if set(types) != {"deepseek_sparse_attention"}:
+        raise ValueError(f"layer_types = {sorted(set(types))}: every layer is "
+                         "deepseek_sparse_attention in this family")
+    for key in ("use_dsa", "use_mla"):
+        if not c.get(key):
+            raise ValueError(f"{key} = {c.get(key)!r}: sparse attention here is on latent attention")
+    index = list(c["indexer_types"])
+    if set(index) - {"full", "shared"} or index[:1] != ["full"]:
+        raise ValueError(f"indexer_types = {index}: 'full' first, then 'full' or 'shared'")
+    kinds = list(c.get("mlp_layer_types", ["sparse"] * n))
+    dense = kinds.count("dense")
+    if kinds != ["dense"] * dense + ["sparse"] * (len(kinds) - dense):
+        raise ValueError(f"mlp_layer_types = {kinds}: leading 'dense' layers, then 'sparse'")
+    if not len(types) == len(index) == len(kinds) == n:
+        raise ValueError(f"num_hidden_layers = {n}: layer_types, indexer_types and "
+                         "mlp_layer_types give a kind a layer")
+    if c.get("gated_mla") and c.get("gating_type", "elementwise") != "elementwise":
+        raise ValueError(f"gating_type = {c['gating_type']!r}: only 'elementwise' is computed here")
+    rope = c.get("rope_parameters") or {}
+    if rope.get("rope_type", "default") != "default":
+        raise ValueError(f"rope_parameters = {rope!r}: RoPE length scaling is not computed here")
+    if "rope_theta" in rope:
+        c.setdefault("rope_theta", rope["rope_theta"])
+    c["layer_types"] = ["sparse_attention"] * n
+    c.setdefault("num_dense_layers", dense)
 
 
 def held_all(config) -> Tuple[int, int]:
@@ -208,6 +271,29 @@ def _latent_shapes(config) -> Dict[str, Tuple[int, ...]]:
     }
 
 
+def _index_shapes(config) -> Dict[str, Tuple[int, ...]]:
+    """One lightning indexer's arrays: ``w_q`` from the query latent to
+    ``index_n_heads`` heads of ``index_head_dim``, ``w_k`` one key a token
+    for all of them (a LayerNorm over it: ``k_norm``, ``k_bias``), ``w_w``
+    the heads' weights."""
+    d, heads = int(config["hidden_size"]), int(config["index_n_heads"])
+    width = int(config["index_head_dim"])
+    return {"w_q": (int(config["q_lora_rank"]), heads * width), "w_k": (d, width),
+            "k_norm": (width,), "k_bias": (width,), "w_w": (d, heads)}
+
+
+def _hc_width(config) -> int:
+    """The residual's streams (``hc_mult`` where ``enable_ihc``), else 0."""
+    return int(config.get("hc_mult", 1)) if config.get("enable_ihc") else 0
+
+
+def _full_index(config) -> np.ndarray:
+    """A layer's place in the indexers' stack, -1 where it reuses the
+    selection of the last ``full`` layer before it."""
+    full = np.asarray([t == "full" for t in config["indexer_types"]])
+    return np.where(full, np.cumsum(full) - 1, -1).astype(np.int32)
+
+
 def _shared_width(config) -> int:
     if not int(config.get("n_shared_experts") or 0):
         return 0
@@ -257,7 +343,7 @@ def init_params(config, seed: int, held: Optional[Tuple[int, int]] = None):
     plan = layer_plan(config)
     n_conv = int(np.sum(plan[:, 0] == 0))
     n_attn = int(np.sum(plan[:, 0] == 1))
-    n_mla = int(np.sum(plan[:, 0] == 2))
+    n_mla = int(np.sum(np.isin(plan[:, 0], (2, SPARSE))))
     n_ssm = int(np.sum(plan[:, 0] == SSM))
     n_moe = len(_expert_layers(plan))
     n_dense = int(np.sum(plan[:, 2] == 0))
@@ -302,6 +388,22 @@ def init_params(config, seed: int, held: Optional[Tuple[int, int]] = None):
             name: ((n_mla,) + shape, scale.get(name, std))
             for name, shape in _latent_shapes(config).items()
         }
+    if SPARSE in plan[:, 0]:
+        heads, dv = int(config["num_attention_heads"]), int(config["v_head_dim"])
+        if config.get("gated_mla"):
+            shapes["mla"]["w_g"] = ((n_mla, d, heads * dv), std)
+        if config.get("learnable_sink"):
+            shapes["mla"]["sink"] = ((n_mla, heads), float(config.get("sink_range", std)))
+        n_full = int(np.sum(_full_index(config) >= 0))
+        shapes["index"] = {name: ((n_full,) + shape, None if name == "k_norm" else std)
+                           for name, shape in _index_shapes(config).items()}
+    n_hc = _hc_width(config)
+    if n_hc:  # a sublayer's maps: [pre (n) | post (n) | residual (n x n)]
+        maps = 2 * n_hc + n_hc * n_hc
+        shapes["hc"] = {"phi": ((len(plan), 2, n_hc * d, maps), std),
+                        "alpha": ((len(plan), 2, 3), std), "bias": ((len(plan), 2, maps), std)}
+        shapes["hc_head"] = {"phi": ((n_hc * d, n_hc), std), "alpha": ((1,), std),
+                             "bias": ((n_hc,), std)}
     if n_ssm:
         shapes["ssm"] = {
             name: ((n_ssm,) + shape, scale)
@@ -313,6 +415,8 @@ def init_params(config, seed: int, held: Optional[Tuple[int, int]] = None):
                 del shapes[kind]
     if config.get("one_mixer"):  # no FFN half: its norm and the dense FFN go
         del shapes["ffn_norm"], shapes["dense"]
+    if not config.get("use_expert_bias"):  # no correction bias in the choice
+        del shapes["moe"]["bias"]
     fs = _shared_width(config)
     if fs:
         shapes["moe"]["shared_up"] = ((n_moe, d, side * fs), std)
@@ -432,29 +536,9 @@ def _latent_attention_op(config, p, u, interpret):
     are the kernel's two score parts; nothing is concatenated or repeated."""
     with jax.named_scope("lm.mla"):
         rows, seq, _ = u.shape
-        heads, eps = int(config["num_attention_heads"]), float(config["norm_eps"])
-        rkv = int(config["kv_lora_rank"])
         dn, dr = int(config["qk_nope_head_dim"]), int(config["qk_rope_head_dim"])
-        theta = float(config["rope_theta"])
-        pairs = bool(config.get("rope_interleave", False))
         dtype = p["w_qa"].dtype
-
-        def by_head(x):  # (rows, seq, heads * w) -> (rows, heads, seq, w)
-            return jnp.swapaxes(x.reshape(rows, seq, heads, -1), 1, 2)
-
-        with jax.named_scope("mla.project"):
-            # a head's [q_n | q_r] columns apart, so that q_n and [k_n | v]
-            # leave their matmuls rounded as the kernel takes them and only
-            # the rotary parts pass through float32 (the same products)
-            w_qb = p["w_qb"].reshape(-1, heads, dn + dr)
-            c_q = _rms_norm(_matmul(u, p["w_qa"]), p["q_norm"], eps)
-            q_n = by_head(_matmul(c_q, w_qb[..., :dn].reshape(-1, heads * dn)).astype(dtype))
-            q_r = by_head(_matmul(c_q, w_qb[..., dn:].reshape(-1, heads * dr)))
-            kva = _matmul(u, p["w_kva"])
-            c_kv = _rms_norm(kva[..., :rkv], p["kv_norm"], eps)
-            kv = by_head(_matmul(c_kv, p["w_kvb"]).astype(dtype))
-            q_r = _rope(q_r, theta, pairs)
-            k_r = _rope(kva[:, None, :, rkv:], theta, pairs)  # one head
+        _, q_n, q_r, kv, k_r = _latent_project(config, p, u)
         # 1,024-blocks: at 32,768 positions the kernel's grid steps, not
         # its matmuls, bound 512-blocks (my chip run, PR 33: 151 -> 110 ms)
         block = min(1024, max(8, seq))
@@ -466,6 +550,169 @@ def _latent_attention_op(config, p, u, interpret):
         with jax.named_scope("mla.project"):
             att = jnp.swapaxes(att, 1, 2).reshape(rows, seq, -1)
             return _matmul(att, p["w_o"])
+
+
+def _latent_project(config, p, u):
+    """Latent attention's projections: (``c_q``, ``q_n``, ``q_r``, ``[k_n |
+    v]``, ``k_r``), by head (rows, heads, seq, width), RoPE applied; ``q_n``
+    and ``[k_n | v]`` in the weights' dtype, ``k_r`` one head."""
+    rows, seq, _ = u.shape
+    heads, eps = int(config["num_attention_heads"]), float(config["norm_eps"])
+    rkv = int(config["kv_lora_rank"])
+    dn, dr = int(config["qk_nope_head_dim"]), int(config["qk_rope_head_dim"])
+    theta = float(config["rope_theta"])
+    pairs = bool(config.get("rope_interleave", False))
+    dtype = p["w_qa"].dtype
+
+    def by_head(x):  # (rows, seq, heads * w) -> (rows, heads, seq, w)
+        return jnp.swapaxes(x.reshape(rows, seq, heads, -1), 1, 2)
+
+    with jax.named_scope("mla.project"):
+        # a head's [q_n | q_r] columns apart, so that q_n and [k_n | v]
+        # leave their matmuls rounded as the kernel takes them and only
+        # the rotary parts pass through float32 (the same products)
+        w_qb = p["w_qb"].reshape(-1, heads, dn + dr)
+        c_q = _rms_norm(_matmul(u, p["w_qa"]), p["q_norm"], eps)
+        q_n = by_head(_matmul(c_q, w_qb[..., :dn].reshape(-1, heads * dn)).astype(dtype))
+        q_r = by_head(_matmul(c_q, w_qb[..., dn:].reshape(-1, heads * dr)))
+        kva = _matmul(u, p["w_kva"])
+        c_kv = _rms_norm(kva[..., :rkv], p["kv_norm"], eps)
+        kv = by_head(_matmul(c_kv, p["w_kvb"]).astype(dtype))
+        q_r = _rope(q_r, theta, pairs)
+        k_r = _rope(kva[:, None, :, rkv:], theta, pairs)  # one head
+    return c_q, q_n, q_r, kv, k_r
+
+
+def _select(config, p, u, c_q, interpret):
+    """The lightning indexer of a ``full`` layer and its top-k: (selection
+    (rows, seq, seq) int8, 1 where key s is in query t's set; the keys
+    (rows, seq, k) int32, -1 past t + 1). ``I[t, s] = Σ_j w[t, j]
+    ReLU(q[t, j] · k[s] / sqrt(width))``, ``q = c_q W_q`` (heads of
+    ``index_head_dim``), ``k = LayerNorm(u W_k)``, RoPE on the first
+    ``qk_rope_head_dim`` of both, ``w = u W_w / sqrt(heads)``; query t
+    keeps the ``index_topk`` largest over s <= t (every s <= t while t <
+    k). The scores exist `INDEX_QUERIES` queries at a time: the kernel's
+    block, then its top-k."""
+    rows, seq, _ = u.shape
+    heads, width = int(config["index_n_heads"]), int(config["index_head_dim"])
+    dr, eps = int(config["qk_rope_head_dim"]), float(config["norm_eps"])
+    theta, dtype = float(config["rope_theta"]), p["w_q"].dtype
+    top = min(int(config["index_topk"]), seq)
+    with jax.named_scope("dsa.project"):
+        q = _matmul(c_q, p["w_q"]).reshape(rows, seq, heads, width)
+        q = jnp.concatenate([jnp.swapaxes(_rope(jnp.swapaxes(q[..., :dr], 1, 2), theta), 1, 2),
+                             q[..., dr:]], axis=-1).astype(dtype)
+        k = _matmul(u, p["w_k"])
+        k = k - jnp.mean(k, axis=-1, keepdims=True)
+        k = (k * lax.rsqrt(jnp.mean(k * k, axis=-1, keepdims=True) + eps)
+             * p["k_norm"].astype(jnp.float32) + p["k_bias"].astype(jnp.float32))
+        k = jnp.concatenate([_rope(k[:, None, :, :dr], theta)[:, 0], k[..., dr:]],
+                            axis=-1).astype(dtype)
+        w = _matmul(u, p["w_w"]) * jnp.float32(1.0 / np.sqrt(heads))
+    block = min(INDEX_QUERIES, seq)
+    pad = (-seq) % block
+    if pad:
+        q = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        w = jnp.pad(w, ((0, 0), (0, pad), (0, 0)))
+    i32 = jnp.int32
+
+    def one(start):
+        qb = lax.dynamic_slice_in_dim(q, start, block, axis=1)
+        wb = lax.dynamic_slice_in_dim(w, start, block, axis=1)
+        with jax.named_scope("lm.dsa_index"):
+            scores = index_scores(qb, k, wb, start, scale=float(1.0 / np.sqrt(width)),
+                                  block_k=512, interpret=interpret)
+        with jax.named_scope("dsa.top_k"):
+            kept, keys = lax.top_k(scores, top)
+            keys = keys.astype(i32)
+            # the k-th score may tie: top_k keeps the lower keys first
+            kth = kept[..., -1:]
+            tied = jnp.max(jnp.where(kept == kth, keys, i32(-1)), axis=-1, keepdims=True)
+            s_at, at = lax.iota(i32, seq), start + lax.iota(i32, block)[:, None]
+            chosen = (scores > kth) | ((scores == kth) & (s_at <= tied))
+            chosen = chosen & (s_at <= at)
+            keys = jnp.where(lax.iota(i32, top)[None, :] <= at, keys, i32(-1))
+            return chosen.astype(jnp.int8), keys
+
+    chosen, keys = lax.map(one, lax.iota(i32, (seq + pad) // block) * i32(block))
+    whole = lambda a: jnp.moveaxis(a, 0, 1).reshape((rows, seq + pad) + a.shape[3:])[:, :seq]
+    return whole(chosen), whole(keys)
+
+
+def _sparse_attention_op(config, p, index_p, u, carried, index_at, interpret):
+    """Gated latent attention on a learned sparse index, with a sink:
+    latent attention's projections (`_latent_project`); on a ``full``
+    layer (``index_at`` >= 0) the indexer chooses each query's keys
+    (`_select`), a ``shared`` layer takes ``carried``, the selection of the
+    ``full`` layer before it; the score of the selected keys on
+    `sparse_attention` with a learned sink logit a head (``sink``); the
+    heads' output times ``sigmoid(u W_g)``, elementwise (``gated_mla``),
+    then ``W_o``. Returns (y, the selection handed on)."""
+    rows, seq, _ = u.shape
+    dn, dr = int(config["qk_nope_head_dim"]), int(config["qk_rope_head_dim"])
+    dtype = p["w_qa"].dtype
+    c_q, q_n, q_r, kv, k_r = _latent_project(config, p, u)
+    carried = lax.cond(
+        index_at >= 0,
+        lambda: _select(config, _at(index_p, jnp.maximum(index_at, 0)), u, c_q, interpret),
+        lambda: carried)
+    with jax.named_scope("lm.dsa"):
+        att = sparse_attention(
+            q_n, kv[..., :dn], kv[..., dn:], carried[0], p.get("sink"),
+            q2=q_r.astype(dtype), k2=k_r.astype(dtype), scale=float(1.0 / np.sqrt(dn + dr)),
+            block=min(512, max(8, seq)), interpret=interpret)
+    att = jnp.swapaxes(att, 1, 2).reshape(rows, seq, -1)
+    if "w_g" in p:
+        with jax.named_scope("mla.gate"):
+            att = att.astype(jnp.float32) * jax.nn.sigmoid(_matmul(u, p["w_g"]))
+    with jax.named_scope("mla.project"):
+        return _matmul(att, p["w_o"]), carried
+
+
+def _hc_coefficients(config, p, X):
+    """A hyper-connection's coefficients from the residual's ``n`` streams
+    ``X`` (rows, seq, n, d), float32: ``x = vec(X) / rms(vec(X))``; the
+    read-in ``a_pre = sigmoid(alpha_0 x phi_pre + b_pre)``, the write-out
+    ``a_post = hc_magnitude * sigmoid(alpha_1 x phi_post + b_post)`` and the
+    mixing ``M = Sinkhorn(exp(alpha_2 mat(x phi_res) + b_res))`` (`SINKHORN`
+    row then column normalisations, ``hc_eps`` in each denominator),
+    ``phi`` side by side in ``p["phi"]``; the maps take float32 operands at
+    full precision."""
+    n = X.shape[-2]
+    eps, f32 = float(config.get("hc_eps", 1e-6)), jnp.float32
+    flat = X.reshape(X.shape[:-2] + (-1,))
+    x = flat * lax.rsqrt(jnp.mean(flat * flat, axis=-1, keepdims=True) + float(config["norm_eps"]))
+    c = jnp.dot(x, p["phi"].astype(f32), precision=lax.Precision.HIGHEST)
+    alpha, b = p["alpha"].astype(f32), p["bias"].astype(f32)
+    pre = jax.nn.sigmoid(alpha[0] * c[..., :n] + b[:n])
+    if c.shape[-1] == n:  # the read-out after the last layer
+        return pre
+    post = f32(config.get("hc_magnitude", 2.0)) * jax.nn.sigmoid(
+        alpha[1] * c[..., n:2 * n] + b[n:2 * n])
+    m = jnp.exp(alpha[2] * c[..., 2 * n:] + b[2 * n:]).reshape(c.shape[:-1] + (n, n))
+    for _ in range(SINKHORN):
+        m = m / (jnp.sum(m, axis=-1, keepdims=True) + eps)
+        m = m / (jnp.sum(m, axis=-2, keepdims=True) + eps)
+    return pre, post, m
+
+
+def _streams(a, X):
+    """``Σ_i a[..., i] X[..., i, :]`` as elementwise float32 sums (a dot
+    over four streams would round X to bfloat16 passes on the chip)."""
+    return sum(a[..., i, None] * X[..., i, :] for i in range(X.shape[-2]))
+
+
+def _hc_sublayer(config, p, X, f):
+    """``X <- M X + a_post ⊗ f(u)``, ``u = Σ_i a_pre[i] X[i]``
+    (`_hc_coefficients`); ``f`` returns its output first and hands on the
+    rest."""
+    with jax.named_scope("lm.hc"):
+        pre, post, m = _hc_coefficients(config, p, X)
+        u = _streams(pre, X)
+    y, *rest = f(u)
+    with jax.named_scope("lm.hc"):
+        mixed = jnp.stack([_streams(m[..., i, :], X) for i in range(X.shape[-2])], axis=-2)
+        return (mixed + post[..., None] * y[..., None, :],) + tuple(rest)
 
 
 def _ssm_op(config, p, u, interpret):
@@ -511,7 +758,7 @@ def _ssm_op(config, p, u, interpret):
             return _matmul(g, p["w_out"])
 
 
-def _dense_ffn(p, u, act: str = "swiglu"):
+def _dense_ffn(p, u, act: str = "swiglu", limit=None):
     """An FFN of two or (SwiGLU) three matrices over the tokens ``u`` (...,
     d), in as many parts as keep the up projection's float32 output and
     the activation within `moe.PART_BYTES` (`moe.parts_for`, one expert a
@@ -520,7 +767,7 @@ def _dense_ffn(p, u, act: str = "swiglu"):
     f = p["w_down"].shape[0]
 
     def part(x):
-        return _matmul(moe.activation(act, _matmul(x, p["w_up"])), p["w_down"])
+        return _matmul(moe.activation(act, _matmul(x, p["w_up"]), limit), p["w_down"])
 
     flat = u.reshape(-1, d)
     n = moe.parts_for(flat.shape[0], 1, d, up, f, p["w_up"].dtype.itemsize)
@@ -536,7 +783,7 @@ def _moe_ffn(config, stacks, i, u, held):
     p = _at({k: v for k, v in stacks.items() if k not in big}, i)
     rows, seq, d = u.shape
     e, top_k = int(config["num_experts"]), int(config["num_experts_per_tok"])
-    act = config.get("ffn_act", "swiglu")
+    act, limit = config.get("ffn_act", "swiglu"), config.get("swiglu_limit")
     flat = u.reshape(rows * seq, d)
     idx, w = moe.route(
         flat, p["router"], p["bias"] if config.get("use_expert_bias") else None,
@@ -550,7 +797,7 @@ def _moe_ffn(config, stacks, i, u, held):
             x = _matmul(flat, p["latent_in"])
     y = moe.held_experts(
         x.astype(stacks["w_up"].dtype), idx, w, stacks["w_up"], stacks["w_down"],
-        held, act=act, layer=i, experts=e,
+        held, act=act, layer=i, experts=e, limit=limit,
     )
     if "latent_out" in p:
         with jax.named_scope("moe.latent"):
@@ -558,7 +805,7 @@ def _moe_ffn(config, stacks, i, u, held):
     if "shared_up" in p:  # once for every token, whatever is held here
         with jax.named_scope("moe.shared"):
             y = y + _dense_ffn(
-                {"w_up": p["shared_up"], "w_down": p["shared_down"]}, flat, act)
+                {"w_up": p["shared_up"], "w_down": p["shared_down"]}, flat, act, limit)
     load = jnp.sum(
         idx.reshape(rows, seq * top_k, 1) == jnp.arange(e, dtype=jnp.int32),
         axis=1, dtype=jnp.int32,
@@ -581,7 +828,11 @@ def _head(config, params, h, tokens):
 
         def one(args):
             xc, tc = args
-            logits = _matmul(xc, params["head"])
+            if config.get("enable_lm_head_fp32"):  # float32 operands, full precision
+                logits = jnp.dot(xc, params["head"].astype(jnp.float32),
+                                 precision=lax.Precision.HIGHEST)
+            else:
+                logits = _matmul(xc, params["head"])
             lse = jax.nn.logsumexp(logits, axis=-1)
             return moe._along_rows(logits, tc[:, None])[:, 0] - lse
 
@@ -602,7 +853,7 @@ def scoring_fn(
     config, held: Optional[Tuple[int, int]] = None, interpret: bool = False,
 ) -> Callable:
     """``fn(tokens, params) -> {"token_logprob", "expert_load",
-    "expert_choice"}`` over a
+    "expert_choice"}`` (and ``"index_choice"`` under sparse attention) over a
     block of ``(rows, seq)`` token ids (the verb feeds the column named
     as the parameter, ``tokens``). The kernels compile for the
     TPU; ``interpret=True`` (a CPU test, an example) interprets them, and
@@ -625,6 +876,8 @@ def scoring_fn(
     # an expert layer as a layer's operator: every operator then answers
     # with the routing too (none, for the others)
     routed_ops = EXPERTS in ops_present
+    limit = config.get("swiglu_limit")
+    sparse = SPARSE in ops_present
 
     def lm_score(tokens, params):
         tokens = tokens.astype(jnp.int32)
@@ -647,9 +900,12 @@ def scoring_fn(
             ops = {kind: (lambda u, i, op=op: unrouted(op(u, i))) for kind, op in ops.items()}
             ops[EXPERTS] = lambda u, i: _moe_ffn(config, params["moe"], i, u, held)
         ffns = {
-            0: lambda u, i: unrouted(_dense_ffn(_at(params["dense"], i), u, act)),
+            0: lambda u, i: unrouted(_dense_ffn(_at(params["dense"], i), u, act, limit)),
             1: lambda u, i: _moe_ffn(config, params["moe"], i, u, held),
         }
+        if sparse:
+            return _sparse_layers(config, params, tokens, h, which, ffns, ffn_present,
+                                  moe_layers, interpret)
 
         def layer(h, xs):
             row, gains = xs
@@ -674,6 +930,56 @@ def scoring_fn(
     return lm_score
 
 
+def _sparse_layers(config, params, tokens, h, which, ffns, ffn_present, moe_layers,
+                   interpret):
+    """`scoring_fn`'s layer scan for sparse attention: the scan carries the
+    selection (the mask `sparse_attention` reads and the keys, from a
+    ``full`` layer to the ``shared`` ones after it) beside the residual,
+    which is ``hc_mult`` streams under hyper-connections where
+    ``enable_ihc`` (`_hc_sublayer`; the embedding in every stream, read out
+    after the last layer by ``a_head``), else one. Outputs as
+    `scoring_fn`'s, and ``index_choice`` (rows, full layers, seq,
+    index_topk) int16: the keys each query of a ``full`` layer kept."""
+    rows, seq = tokens.shape
+    eps, n_hc = float(config["norm_eps"]), _hc_width(config)
+    index_at = _full_index(config)
+    top = min(int(config["index_topk"]), seq)
+    gains = (params["op_norm"], params["ffn_norm"])
+
+    def sublayer(X, links, j, f):
+        if not n_hc:
+            y, *rest = f(X)
+            return (X + y,) + tuple(rest)
+        return _hc_sublayer(config, jax.tree_util.tree_map(lambda a: a[j], links), X, f)
+
+    def layer(carry, xs):
+        X, chosen = carry
+        row, (g_op, g_ffn), at, links = xs
+        X, chosen = sublayer(X, links, 0, lambda u: _sparse_attention_op(
+            config, _at(params["mla"], row[1]), params["index"], _rms_norm(u, g_op, eps),
+            chosen, at, bool(interpret)))
+        X, load, choice = sublayer(X, links, 1, lambda u: _pick(
+            ffns, ffn_present, row[2], _rms_norm(u, g_ffn, eps), row[3]))
+        return (X, chosen), (load, choice, chosen[1].astype(jnp.int16))
+
+    if n_hc:
+        h = jnp.broadcast_to(h[:, :, None, :], (rows, seq, n_hc, h.shape[-1]))
+    start = (jnp.zeros((rows, seq, seq), jnp.int8), jnp.full((rows, seq, top), -1, jnp.int32))
+    (X, _), (loads, choices, keys) = lax.scan(
+        layer, (h, start),
+        (jnp.asarray(which), gains, jnp.asarray(index_at),
+         params["hc"] if n_hc else jnp.zeros(len(which), jnp.int32)))
+    if n_hc:
+        with jax.named_scope("lm.hc"):
+            X = _streams(_hc_coefficients(config, params["hc_head"], X), X)
+    return {
+        "token_logprob": _head(config, params, X, tokens),
+        "expert_load": jnp.swapaxes(loads[moe_layers], 0, 1),
+        "expert_choice": jnp.swapaxes(choices[moe_layers], 0, 1),
+        "index_choice": jnp.swapaxes(keys[np.flatnonzero(index_at >= 0).astype(np.int32)], 0, 1),
+    }
+
+
 def score(fn: Callable, frame, params, config, **verb_args):
     """``tfs.map_blocks(fn, frame, bindings={"params": params})`` with the
     model's counters: ``lm.tokens`` (rows x seq of the frame),
@@ -681,8 +987,13 @@ def score(fn: Callable, frame, params, config, **verb_args):
     ``moe.held_rows_expected`` (the routed rows x the share of the experts
     whose weights ``params`` holds: what this holder's experts are expected
     to compute), ``lm.attention_pairs`` (causal query-key pairs x heads x
-    attention layers) and ``lm.ssm_steps`` (tokens x state-space layers),
-    all known on the host before the dispatch."""
+    attention layers), ``lm.ssm_steps`` (tokens x state-space layers);
+    under sparse attention ``lm.dsa_selected_pairs`` (Σ_t min(t + 1,
+    index_topk) x heads x sparse layers x rows), ``lm.dsa_index_pairs``
+    (causal pairs x index heads x ``full`` layers x rows) and
+    ``lm.index_reuses`` (``shared`` layers x rows); under hyper-connections
+    ``lm.hc_stream_bytes`` (streams x d x 4 B x tokens x 2 sublayers x
+    layers): all known on the host before the dispatch."""
     from .. import api
     from ..utils import telemetry
 
@@ -704,4 +1015,17 @@ def score(fn: Callable, frame, params, config, **verb_args):
               * int(np.isin(plan[:, 0], ATTENTION).sum())),
     )
     telemetry.counter_inc("lm.ssm_steps", float(tokens * int(np.sum(plan[:, 0] == SSM))))
+    if SPARSE in plan[:, 0]:
+        full = int(np.sum(_full_index(config) >= 0))
+        top = min(int(config["index_topk"]), seq)
+        kept = top * (top + 1) // 2 + (seq - top) * top  # Σ_t min(t + 1, top)
+        for name, value in (
+                ("lm.dsa_selected_pairs",
+                 kept * int(config["num_attention_heads"]) * len(plan)),
+                ("lm.dsa_index_pairs", seq * (seq + 1) // 2 * int(config["index_n_heads"]) * full),
+                ("lm.index_reuses", len(plan) - full)):
+            telemetry.counter_inc(name, float(frame.nrows * value))
+    if _hc_width(config):
+        telemetry.counter_inc("lm.hc_stream_bytes", float(
+            _hc_width(config) * int(config["hidden_size"]) * 4 * tokens * 2 * len(plan)))
     return api.map_blocks(fn, frame, bindings={"params": params}, **verb_args)

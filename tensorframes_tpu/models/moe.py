@@ -123,13 +123,16 @@ def parts_for(rows: int, k: int, d: int, up: int, f: int, itemsize: int) -> int:
     return next(n for n in range(max(1, least), rows + 1) if rows % n == 0)
 
 
-def activation(act: str, h):
+def activation(act: str, h, limit: Optional[float] = None):
     """An FFN's activation over its up projection ``h``: "swiglu" (gate
-    and up side by side, ``silu(gate) * up``), "relu2" (``relu(h)**2``) or
-    "gelu", the last two over one matrix's output."""
+    and up side by side, ``silu(gate) * up``; with ``limit``, a clamped
+    SwiGLU ``silu(min(gate, limit)) * clip(up, -limit, limit)``), "relu2"
+    (``relu(h)**2``) or "gelu", the last two over one matrix's output."""
     if act == "swiglu":
         f = h.shape[-1] // 2
-        return jax.nn.silu(h[..., :f]) * h[..., f:]
+        if limit is None:
+            return jax.nn.silu(h[..., :f]) * h[..., f:]
+        return jax.nn.silu(jnp.minimum(h[..., :f], limit)) * jnp.clip(h[..., f:], -limit, limit)
     if act == "relu2":
         return jnp.square(jax.nn.relu(h))
     if act == "gelu":
@@ -139,7 +142,7 @@ def activation(act: str, h):
 
 def held_experts(
     u, idx, weight, w_up, w_down, held, *, act: str = "swiglu", layer=None,
-    experts: Optional[int] = None,
+    experts: Optional[int] = None, limit: Optional[float] = None,
 ):
     """The held experts' part of the layer's output, ``(rows, d)`` float32.
 
@@ -183,7 +186,7 @@ def held_experts(
         xs = jnp.take(u, places // k, axis=0)
         h = lax.ragged_dot(xs, w_up, sizes, preferred_element_type=jnp.float32)
         return lax.ragged_dot(
-            activation(act, h).astype(u.dtype), w_down, sizes,
+            activation(act, h, limit).astype(u.dtype), w_down, sizes,
             preferred_element_type=jnp.float32,
         )
 

@@ -23,6 +23,20 @@ It compiles for the TPU unless the caller passes ``interpret=True``
 itself, so a chip run cannot be an interpreted one without saying so.
 Production CPU paths use `parallel.ring.full_attention`.
 
+`sparse_attention` (PR 40): the same kernel with two more operands, a
+selection mask (batch, seq, seq) int8 that keeps each query's chosen keys
+(one mask for all heads) and a sink logit a head that joins the softmax's
+denominator at the end (under the running max). Blocks above the diagonal
+are skipped; every other block is a dense pass masked by the selection.
+
+`index_scores` (PR 40): a lightning indexer's scores of ONE block of
+queries against every key of the window, ``Σ_j w_j ReLU(q_j · k)`` over
+the index heads (one key a position for all of them), causal, in float32;
+the block's first position is a scalar-prefetch operand, so that blocks of
+keys after the block's last query are skipped and the kernel is one
+program for every block of a loop. The caller takes the block's top-k
+directly after: the window's score matrix is never written.
+
 `ssd_scan`: a state-space scan (Mamba-2's recurrence ``S_t = exp(Δ_t A)
 S_{t-1} + Δ_t x_t ⊗ B_t``, ``y_t = S_t C_t + D x_t``) computed a chunk of
 positions at a time: inside a chunk the recurrence is three matmuls (the
@@ -47,7 +61,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-__all__ = ["flash_attention", "ssd_scan"]
+__all__ = ["flash_attention", "ssd_scan", "sparse_attention", "index_scores"]
 
 _NEG_INF = -1e30
 
@@ -55,9 +69,14 @@ _NEG_INF = -1e30
 def _flash_kernel(
     q_ref, k_ref, v_ref, *rest,
     scale: float, causal: bool, seq_len: int, blk_q: int, blk_k: int,
+    selected: bool = False, sink: bool = False,
 ):
-    # with a second score part its two blocks come before the output
-    second, (o_ref, m_sc, l_sc, acc_sc) = rest[:-4], rest[-4:]
+    # before the output: a second score part's two blocks, then (sparse
+    # attention) the block of the selection mask and the head's sink logit
+    rest, (o_ref, m_sc, l_sc, acc_sc) = list(rest[:-4]), rest[-4:]
+    sink_ref = rest.pop() if sink else None
+    sel_ref = rest.pop() if selected else None
+    second = rest
     i = pl.program_id(2)
     j = pl.program_id(3)
 
@@ -93,6 +112,8 @@ def _flash_kernel(
         mask = k_pos < seq_len  # padded tail keys contribute nothing
         if causal:
             mask = jnp.logical_and(mask, q_pos >= k_pos)
+        if selected:  # only the keys the query's selection holds
+            mask = jnp.logical_and(mask, sel_ref[:] != 0)
         # NB: f32-typed constants — x64-mode weak f64 literals trip Mosaic
         s = jnp.where(mask, s, jnp.float32(_NEG_INF))
 
@@ -109,9 +130,15 @@ def _flash_kernel(
 
     @pl.when(j == pl.num_programs(3) - 1)
     def _finish():
-        l = l_sc[:, 0]
+        l, acc = l_sc[:, 0], acc_sc[:]
+        if sink:  # exp(sink) joins the denominator, under the same max
+            m, logit = m_sc[:], sink_ref[:]  # (blk_q, 1), (1, 1)
+            top = jnp.maximum(m, logit)
+            keep = jnp.exp(m - top)
+            l = (l_sc[:] * keep + jnp.exp(logit - top))[:, 0]
+            acc = acc * keep
         l = jnp.where(l == jnp.float32(0.0), jnp.float32(1.0), l)
-        o_ref[:] = (acc_sc[:] / l[:, None]).astype(o_ref.dtype)
+        o_ref[:] = (acc / l[:, None]).astype(o_ref.dtype)
 
 
 def flash_attention(
@@ -157,8 +184,13 @@ def flash_attention(
 
 
 def _flash_forward(q, k, v, second, causal, scale, block_q, block_k, interpret):
+    return _flash_call(q, k, v, second, causal, scale, block_q, block_k, interpret)
+
+
+def _flash_call(q, k, v, second, causal, scale, block_q, block_k, interpret,
+                selection=None, sink=None):
     if q.ndim == 2:
-        out = _flash_forward(
+        out = _flash_call(
             q[None, None], k[None, None], v[None, None], second,
             causal, scale, block_q, block_k, interpret,
         )
@@ -184,6 +216,8 @@ def _flash_forward(q, k, v, second, causal, scale, block_q, block_k, interpret):
         seq_len=seq,
         blk_q=blk_q,
         blk_k=blk_k,
+        selected=selection is not None,
+        sink=sink is not None,
     )
 
     def q_block(b, h, i, j):
@@ -220,6 +254,16 @@ def _flash_forward(q, k, v, second, causal, scale, block_q, block_k, interpret):
             pl.BlockSpec((None, None, blk_q, d2), q_block),
             pl.BlockSpec((None, None, blk_k, d2), key_block(k2p)),
         ]
+    if selection is not None:  # (batch, seq, seq): one mask for every head
+        operands.append(jnp.pad(selection, ((0, 0), (0, pad_q), (0, pad_k)))
+                        if pad_q or pad_k else selection)
+        at = key_block(kp)
+        in_specs.append(pl.BlockSpec(
+            (None, blk_q, blk_k), lambda b, h, i, j: (b, i, at(b, h, i, j)[2])))
+    if sink is not None:  # a logit a head
+        operands.append(sink.astype(jnp.float32).reshape(heads, 1, 1))
+        in_specs.append(pl.BlockSpec(
+            (None, 1, 1), lambda b, h, i, j: (h, jnp.int32(0), jnp.int32(0))))
 
     out = pl.pallas_call(
         kernel,
@@ -263,6 +307,99 @@ def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
 
 
 _flash.defvjp(_flash_fwd, _flash_bwd)
+
+
+def sparse_attention(
+    q, k, v, selection, sink, *, q2, k2, scale: float, block: int = 512,
+    interpret: bool = False,
+) -> jax.Array:
+    """Causal attention over a selection of keys a query, with a sink:
+    `flash_attention`'s two score parts (``q kᵀ + q2 k2ᵀ``, ``k2`` with
+    heads of its own count), ``selection`` (batch, seq, seq) int8, 1 where
+    key s is in query t's set (one mask for every head), and ``sink``
+    (heads,) a logit a head in the softmax's denominator: ``p = exp(z) /
+    (exp(sink) + Σ_selected exp(z))``. Blocks of keys above the diagonal
+    hold no selected key and are skipped; every other block is a dense
+    pass masked by the selection. No backward pass (scoring)."""
+    return _flash_call(
+        q, k, v, (q2, k2), True, float(scale), block, block, bool(interpret),
+        selection=selection, sink=sink)
+
+
+def _index_kernel(start_ref, q_ref, k_ref, w_ref, o_ref, *, heads: int, width: int,
+                  scale: float, seq_len: int, blk_q: int, blk_k: int):
+    """One block of keys against the block of queries from ``start_ref[0]``:
+    ``Σ_j w_j ReLU(q_j · k) * scale`` over ``heads`` heads of ``width``
+    side by side in ``q_ref``; a key after the query (or past the window)
+    reads `_NEG_INF`."""
+    j = pl.program_id(1)
+    first = start_ref[0]
+
+    @pl.when(j * blk_k <= first + (blk_q - 1))
+    def _scores():
+        k = k_ref[:]
+        acc = jnp.zeros((blk_q, blk_k), jnp.float32)
+        for h in range(heads):
+            s = jax.lax.dot_general(
+                q_ref[:, h * width:(h + 1) * width], k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            acc = acc + w_ref[:, h:h + 1] * jnp.maximum(s, jnp.float32(0.0))
+        q_pos = first + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
+        k_pos = j * blk_k + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
+        keep = jnp.logical_and(q_pos >= k_pos, k_pos < seq_len)
+        o_ref[:] = jnp.where(keep, acc * jnp.float32(scale), jnp.float32(_NEG_INF))
+
+    @pl.when(j * blk_k > first + (blk_q - 1))
+    def _after():
+        o_ref[:] = jnp.full((blk_q, blk_k), _NEG_INF, jnp.float32)
+
+
+def index_scores(
+    q, k, w, start, *, scale: float, block_k: int = 512, interpret: bool = False,
+) -> jax.Array:
+    """A lightning indexer's scores of one block of queries against every
+    key of the window: ``I[t, s] = scale * Σ_j w[t, j] ReLU(q[t, j] · k[s])``
+    for ``s <= t``, `_NEG_INF` after. ``q`` (rows, block, heads, width) the
+    queries from position ``start`` (int32, may be traced) on, ``k`` (rows,
+    seq, width) one key a position for all heads, ``w`` (rows, block,
+    heads) float32 the heads' weights. Returns (rows, block, seq) float32:
+    the score matrix of the whole window is never written. Products take
+    the operands' dtype, sums float32; blocks of keys after the block's last
+    query are skipped."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows, blk_q, heads, width = q.shape
+    seq = k.shape[1]
+    blk_k = min(block_k, max(8, seq))
+    pad = (-seq) % blk_k
+    kp = jnp.pad(k, ((0, 0), (0, pad), (0, 0))) if pad else k
+    nk = kp.shape[1] // blk_k
+
+    def key_block(r, j, first):  # above the block's diagonal: the last needed again
+        last = jax.lax.div(first[0] + (blk_q - 1), jnp.int32(blk_k))
+        return (r, jnp.minimum(j, last), jnp.int32(0))
+
+    zero = lambda r, j, first: (r, jnp.int32(0), jnp.int32(0))
+    out = pl.pallas_call(
+        functools.partial(_index_kernel, heads=heads, width=width, scale=float(scale),
+                          seq_len=seq, blk_q=blk_q, blk_k=blk_k),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(rows, nk),
+            in_specs=[
+                pl.BlockSpec((None, blk_q, heads * width), zero),
+                pl.BlockSpec((None, blk_k, width), key_block),
+                pl.BlockSpec((None, blk_q, heads), zero),
+            ],
+            out_specs=pl.BlockSpec(
+                (None, blk_q, blk_k), lambda r, j, first: (r, jnp.int32(0), j)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((rows, blk_q, nk * blk_k), jnp.float32),
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(jnp.reshape(start, (1,)).astype(jnp.int32), q.reshape(rows, blk_q, heads * width),
+      kp, w.astype(jnp.float32))
+    return out[..., :seq] if pad else out
 
 
 def _ssd_kernel(

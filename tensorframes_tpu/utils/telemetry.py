@@ -990,6 +990,22 @@ _PROM_HELP: Dict[str, str] = {
         "State-space scan steps (tokens x state-space layers) of "
         "models.lm.score"
     ),
+    "lm.dsa_selected_pairs": (
+        "Selected query-key pairs (x heads x sparse attention layers) "
+        "attended by models.lm.score"
+    ),
+    "lm.dsa_index_pairs": (
+        "Causal query-key pairs (x index heads x full indexer layers) "
+        "scored by the lightning indexer in models.lm.score"
+    ),
+    "lm.index_reuses": (
+        "Layers (x rows) that reused the selection of an earlier indexer "
+        "in models.lm.score"
+    ),
+    "lm.hc_stream_bytes": (
+        "Bytes of the hyper-connection streams (streams x d x 4 B x tokens x "
+        "2 sublayers x layers) of models.lm.score"
+    ),
     "fault_retries": "Classified dispatch retries by fault class",
     "device_evictions": "Failover circuit-breaker device evictions",
     "block_splits": "OOM-triggered block split-retries by verb",
@@ -1215,6 +1231,11 @@ def diagnostics_data(executor=None) -> Dict:
     data["scheduler"] = {
         k: int(counters.get("scheduler." + k, 0))
         for k in ("home_plans", "home_blocks")
+    }
+    # what a model scored: `models.lm.score`'s counters (tokens, routed
+    # rows, attended and selected pairs, the residual's stream bytes)
+    data["model"] = {
+        k: v for k, v in sorted(counters.items()) if k.startswith(("lm.", "moe."))
     }
 
     # cost ledger x span join ------------------------------------------
@@ -1462,6 +1483,11 @@ def _render_diagnostics(data: Dict) -> str:
                 f"{home['home_blocks']} block(s) of a row-local map on "
                 "the device that holds their columns"
             )
+    if data.get("model"):
+        lines.append("")
+        lines.append("model (models.lm.score's counters, summed over calls):")
+        for name, value in data["model"].items():
+            lines.append(f"  {name:<26} {value:,.0f}")
     if data["programs"]:
         lines.append("")
         lines.append("programs (by graph fingerprint):")

@@ -465,3 +465,101 @@ def test_the_hybrid_scoring_program_at_the_cells_block(one_chip):
     # a layer's 1.4 GB of held experts stay where they are bound
     assert not re.search(r"= bf16\[(1,)?128,1024,2688\]\S* (fusion|copy|dynamic-slice)", text)
     assert not re.findall(r"\b[sufc](?:64|128)\[", text)
+
+
+def _hy4_config():
+    """`hy4-preview` as the benchmark's runner hands it to the program: the
+    router's published width and the share held here."""
+    import json
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from perf.runners.map_blocks_lm_hybrid import model_config
+
+    with open(os.path.join(root, "perf", "configs", "hy4-preview.json")) as f:
+        config = json.load(f)
+    return (config,) + model_config(config, False)
+
+
+def test_index_kernel_at_the_cells_shape(one_chip):
+    # a block of 1,024 queries of 32 index heads of 128 against the
+    # window's 16,384 keys, bfloat16 operands, float32 weights and scores:
+    # as `models.lm._select` calls the kernel
+    from tensorframes_tpu.ops.pallas_kernels import index_scores
+
+    bf16, f32 = jnp.bfloat16, jnp.float32
+    lowered, compiled = _compile(
+        lambda q, k, w, start: index_scores(q, k, w, start, scale=float(1 / np.sqrt(128))),
+        one_chip, ((1, 1024, 32, 128), bf16), ((1, 16384, 128), bf16),
+        ((1, 1024, 32), f32), ((), jnp.int32),
+    )
+    assert "tpu_custom_call" in lowered.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**28
+
+
+def test_sparse_attention_kernel_at_the_cells_shape(one_chip):
+    # one window of 16,384 positions: 64 heads with a score of a per-head
+    # part (192) and a rotary part (64) whose key all heads share, values
+    # of 256, the selection mask, a sink a head, 512-blocks (1,024-blocks
+    # do not fit the kernel's VMEM at these widths)
+    from tensorframes_tpu.ops.pallas_kernels import sparse_attention
+
+    bf16 = jnp.bfloat16
+    lowered, compiled = _compile(
+        lambda q, k, v, sel, sink, q2, k2: sparse_attention(
+            q, k, v, sel, sink, q2=q2, k2=k2, scale=1 / 16.0, block=512),
+        one_chip, ((1, 64, 16384, 192), bf16), ((1, 64, 16384, 192), bf16),
+        ((1, 64, 16384, 256), bf16), ((1, 16384, 16384), jnp.int8), ((64,), jnp.float32),
+        ((1, 64, 16384, 64), bf16), ((1, 1, 16384, 64), bf16),
+    )
+    assert "tpu_custom_call" in lowered.as_text()
+    assert compiled.memory_analysis() is not None
+
+
+def test_the_sparse_scoring_program_at_the_cells_block(one_chip):
+    """`lm.scoring_fn` of `hy4-preview` at the published widths over one
+    block of the cell (one window of 16,384 ids), experts 0-7 of 256 held,
+    the weights arguments: it compiles for the chip as module
+    `jit_lm_score`; its temporaries fit beside 6.04 GiB of weights; the
+    operations that the configuration's `kernel_ops.dsa_index` and
+    `kernel_ops.dsa_attention` match are in it under those names and
+    shapes (what the four `dsa_*` readers sum in a device trace); the
+    grouped matmuls run a step of the held rows' loop; and the program
+    holds no 64-bit array."""
+    import re
+
+    from perf.lib.trace import op_label
+    from tensorframes_tpu.models import lm, moe
+
+    config, cfg, held = _hy4_config()
+    assert held == (0, 8) and cfg["n_routed_experts"] == 256
+    shapes = jax.eval_shape(lambda: lm.init_params(cfg, 0, held))
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), shapes)
+    tokens = jax.ShapeDtypeStruct((1, 16384), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(lm.scoring_fn(cfg, held=held)).lower(tokens, params).compile()
+    memory = compiled.memory_analysis()
+    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(shapes))
+    assert 6.45e9 < weights < 6.55e9  # 3,244 M parameters in bfloat16: 6.04 GiB
+    assert weights + memory.temp_size_in_bytes < 14.0 * 2**30
+    assert memory.output_size_in_bytes < 160 * 2**20
+
+    text = compiled.as_text()
+    assert re.search(r"^HloModule jit_lm_score\b", text, re.M)
+    labels = [
+        op_label(line.strip().removeprefix("ROOT "))
+        for line in text.splitlines() if " = " in line
+    ]
+    index = sorted({l for l in labels if re.search(config["kernel_ops"]["dsa_index"], l)})
+    assert len(index) == 1 and index[0].endswith("f32[1,1024,16384]"), index
+    assert re.match(r"^lm\.dsa_index\.\d+ ", index[0])
+    attend = sorted({l for l in labels if re.search(config["kernel_ops"]["dsa_attention"], l)})
+    assert len(attend) == 1 and attend[0].endswith("bf16[1,64,16384,256]"), attend
+    assert re.match(r"^lm\.dsa\.\d+ ", attend[0])
+    experts = sorted({l for l in labels if l.startswith("ragged-dot-none")})
+    assert {l.split()[1] for l in experts} == {
+        f"f32[{moe.STEP_ROWS},4096]", f"f32[{moe.STEP_ROWS},6144]"}, experts
+    assert not re.findall(r"\b[sufc](?:64|128)\[", text)

@@ -73,8 +73,9 @@ that a checker can follow the very routing the program took: a rounded
 residual stream swaps a token's k-th and (k+1)-th expert where their
 scores are close, and every later number then differs for that reason
 alone). Under sparse attention a fourth, ``index_choice`` (rows, ``full``
-layers, seq, index_topk) int16, the keys each query kept (-1 past t + 1),
-for the same reason. `score` is that call with its counters.
+layers, seq, index_topk) int16, the keys each query kept in ascending
+order (-1 past min(t + 1, index_topk)), for the same reason. `score` is
+that call with its counters.
 """
 
 from __future__ import annotations
@@ -86,7 +87,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ..ops.pallas_kernels import flash_attention, index_scores, sparse_attention, ssd_scan
+from ..ops.pallas_kernels import (
+    flash_attention, index_scores, index_top_k, sparse_attention, ssd_scan,
+)
 from . import moe
 
 __all__ = [
@@ -591,8 +594,12 @@ def _select(config, p, u, c_q, interpret):
     ``index_head_dim``), ``k = LayerNorm(u W_k)``, RoPE on the first
     ``qk_rope_head_dim`` of both, ``w = u W_w / sqrt(heads)``; query t
     keeps the ``index_topk`` largest over s <= t (every s <= t while t <
-    k). The scores exist `INDEX_QUERIES` queries at a time: the kernel's
-    block, then its top-k."""
+    k), ties at the k-th score kept from the lowest key on, the keys in
+    ascending order. The scores exist `INDEX_QUERIES` queries at a time:
+    the kernel's block, then its selection, `index_top_k` (an exact
+    threshold and one compaction, no sort; -0.0 counts as +0.0; a tile of
+    queries below k keeps every causal key and selects nothing) and the
+    mask by the threshold."""
     rows, seq, _ = u.shape
     heads, width = int(config["index_n_heads"]), int(config["index_head_dim"])
     dr, eps = int(config["qk_rope_head_dim"]), float(config["norm_eps"])
@@ -623,20 +630,26 @@ def _select(config, p, u, c_q, interpret):
             scores = index_scores(qb, k, wb, start, scale=float(1.0 / np.sqrt(width)),
                                   block_k=512, interpret=interpret)
         with jax.named_scope("dsa.top_k"):
-            kept, keys = lax.top_k(scores, top)
-            keys = keys.astype(i32)
-            # the k-th score may tie: top_k keeps the lower keys first
-            kth = kept[..., -1:]
-            tied = jnp.max(jnp.where(kept == kth, keys, i32(-1)), axis=-1, keepdims=True)
+            kth, tied, keys = index_top_k(scores, start, k=top, interpret=interpret)
             s_at, at = lax.iota(i32, seq), start + lax.iota(i32, block)[:, None]
             chosen = (scores > kth) | ((scores == kth) & (s_at <= tied))
-            chosen = chosen & (s_at <= at)
-            keys = jnp.where(lax.iota(i32, top)[None, :] <= at, keys, i32(-1))
-            return chosen.astype(jnp.int8), keys
+            return (chosen & (s_at <= at)).astype(jnp.int8), keys
 
     chosen, keys = lax.map(one, lax.iota(i32, (seq + pad) // block) * i32(block))
     whole = lambda a: jnp.moveaxis(a, 0, 1).reshape((rows, seq + pad) + a.shape[3:])[:, :seq]
     return whole(chosen), whole(keys)
+
+
+def _index_blocks(config, seq: int) -> Tuple[int, int]:
+    """A row's query blocks of the ``full`` layers at a window of ``seq``:
+    (chosen by threshold, keeping every causal key: the block lies below
+    ``index_topk``), as `_select`'s kernel takes them."""
+    block = min(INDEX_QUERIES, seq)
+    top = min(int(config["index_topk"]), seq)
+    starts = range(0, seq, block)
+    full = int(np.sum(_full_index(config) >= 0))
+    prefix = sum(start + block <= top for start in starts)
+    return full * (len(starts) - prefix), full * prefix
 
 
 def _sparse_attention_op(config, p, index_p, u, carried, index_at, interpret):
@@ -990,8 +1003,11 @@ def score(fn: Callable, frame, params, config, **verb_args):
     attention layers), ``lm.ssm_steps`` (tokens x state-space layers);
     under sparse attention ``lm.dsa_selected_pairs`` (Σ_t min(t + 1,
     index_topk) x heads x sparse layers x rows), ``lm.dsa_index_pairs``
-    (causal pairs x index heads x ``full`` layers x rows) and
-    ``lm.index_reuses`` (``shared`` layers x rows); under hyper-connections
+    (causal pairs x index heads x ``full`` layers x rows),
+    ``lm.index_reuses`` (``shared`` layers x rows),
+    ``lm.index_threshold_blocks`` and ``lm.index_prefix_blocks`` (query
+    blocks x ``full`` layers x rows chosen by threshold, and those that
+    kept every causal key: `_index_blocks`); under hyper-connections
     ``lm.hc_stream_bytes`` (streams x d x 4 B x tokens x 2 sublayers x
     layers): all known on the host before the dispatch."""
     from .. import api
@@ -1019,11 +1035,13 @@ def score(fn: Callable, frame, params, config, **verb_args):
         full = int(np.sum(_full_index(config) >= 0))
         top = min(int(config["index_topk"]), seq)
         kept = top * (top + 1) // 2 + (seq - top) * top  # Σ_t min(t + 1, top)
+        threshold, prefix = _index_blocks(config, seq)
         for name, value in (
                 ("lm.dsa_selected_pairs",
                  kept * int(config["num_attention_heads"]) * len(plan)),
                 ("lm.dsa_index_pairs", seq * (seq + 1) // 2 * int(config["index_n_heads"]) * full),
-                ("lm.index_reuses", len(plan) - full)):
+                ("lm.index_reuses", len(plan) - full),
+                ("lm.index_threshold_blocks", threshold), ("lm.index_prefix_blocks", prefix)):
             telemetry.counter_inc(name, float(frame.nrows * value))
     if _hc_width(config):
         telemetry.counter_inc("lm.hc_stream_bytes", float(

@@ -1002,6 +1002,14 @@ _PROM_HELP: Dict[str, str] = {
         "Layers (x rows) that reused the selection of an earlier indexer "
         "in models.lm.score"
     ),
+    "lm.index_threshold_blocks": (
+        "Query blocks (x full indexer layers x rows) whose top-k the indexer "
+        "chose by an exact threshold in models.lm.score"
+    ),
+    "lm.index_prefix_blocks": (
+        "Query blocks (x full indexer layers x rows) below the indexer's top-k "
+        "that kept every causal key in models.lm.score"
+    ),
     "lm.hc_stream_bytes": (
         "Bytes of the hyper-connection streams (streams x d x 4 B x tokens x "
         "2 sublayers x layers) of models.lm.score"

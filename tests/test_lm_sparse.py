@@ -121,6 +121,91 @@ def test_index_choice_is_the_references_own_top_k(seed):
     assert not np.array_equal(np.sort(keys[:, 0], -1), np.sort(keys[:, 1], -1))
 
 
+def _top_k_oracle(scores, top):
+    """The selection as the earlier program took it, kept here as the
+    oracle: `lax.top_k` (a sort) over each query's scores, ties at the
+    top-th kept from the lower key on, then s <= t."""
+    seq = scores.shape[1]
+    i32 = jnp.int32
+    kept, keys = jax.lax.top_k(scores, top)
+    kth = kept[..., -1:]
+    tied = jnp.max(jnp.where(kept == kth, keys, i32(-1)), axis=-1, keepdims=True)
+    s_at, at = jax.lax.iota(i32, seq), jax.lax.iota(i32, seq)[:, None]
+    chosen = ((scores > kth) | ((scores == kth) & (s_at <= tied))) & (s_at <= at)
+    keys = jnp.where(jax.lax.iota(i32, top)[None, :] <= at, keys, i32(-1))
+    return np.asarray(chosen.astype(jnp.int8)), np.asarray(keys)
+
+
+def _planted_scores(kind, seq, seed=0):
+    """Index scores of two rows, -1e30 after each query, with ties planted."""
+    rng = np.random.RandomState(seed)
+    x = rng.randn(2, seq, seq).astype(np.float32)
+    if kind in ("zeros", "signed_zeros"):  # every head's ReLU 0: exactly 0.0
+        zero = rng.rand(*x.shape) < 0.7
+        x[zero] = 0.0
+        if kind == "signed_zeros":
+            x[zero & (rng.rand(*x.shape) < 0.5)] = -0.0
+    elif kind == "duplicates":  # a few values, so the k-th place straddles ties
+        x = rng.randint(0, 4, size=x.shape).astype(np.float32)
+    causal = np.arange(seq)[None, :] <= np.arange(seq)[:, None]
+    return np.where(causal, x, np.float32(-1e30))
+
+
+@pytest.mark.parametrize("kind,seq,topk,block", [
+    ("zeros", 64, 16, 16),  # the first block wholly below k
+    ("duplicates", 64, 16, 16),
+    ("signed_zeros", 64, 16, 16),
+    ("tail", 64, 24, 16),  # a block across k: queries t + 1 < k beside t + 1 > k
+    ("duplicates", 60, 16, 16),  # seq not a multiple of the block
+    ("zeros", 40, 64, 16),  # seq <= index_topk: k is the window
+    ("duplicates", 200, 32, 64),  # lanes past the window
+])
+def test_the_selection_is_the_sorts_selection(monkeypatch, kind, seq, topk, block):
+    """`_select` on planted index scores (the indexer kernel stood in for)
+    against the sort it replaced: the same mask bit for bit, the same keys
+    a query, ascending, -1 exactly past min(t + 1, k). -0.0 counts as +0.0:
+    `lax.top_k` ranks -0.0 below +0.0, so on mixed-sign zeros at the k-th
+    place the sort's own mask held more than k keys, not its key list (the
+    indexer kernel's sums start at +0.0 and never give -0.0)."""
+    planted = _planted_scores(kind, seq)
+    pad = (-seq) % block
+    padded = jnp.asarray(np.pad(planted, ((0, 0), (0, pad), (0, 0))))
+    monkeypatch.setattr(lm, "INDEX_QUERIES", block)
+    monkeypatch.setattr(lm, "index_scores", lambda q, k, w, start, **_: (
+        jax.lax.dynamic_slice_in_dim(padded, start, block, axis=1)))
+    cfg = lm.family_keys(dict(SMALL, index_topk=topk))
+    _, params = _seeded(SMALL, 0)
+    u = _layer_input(seq=seq)
+    c_q = lm._latent_project(cfg, lm._at(params["mla"], 0), u)[0]
+    chosen, keys = (np.asarray(a) for a in lm._select(
+        cfg, lm._at(params["index"], 0), u, c_q, True))
+    top = min(topk, seq)
+    want, want_keys = _top_k_oracle(jnp.asarray(planted) + 0.0, top)  # -0.0 -> +0.0
+    assert chosen.dtype == np.int8 and keys.shape == (2, seq, top)
+    np.testing.assert_array_equal(chosen, want)
+    np.testing.assert_array_equal(np.sort(keys, -1), np.sort(want_keys, -1))
+    named = np.minimum(np.arange(seq) + 1, top)[:, None]
+    assert ((keys >= 0) == (np.arange(top)[None, :] < named)).all()
+    listed = np.where(keys >= 0, keys, seq + np.arange(top))
+    assert (np.diff(listed, axis=-1) > 0).all()
+    assert ref._keys_valid(keys)
+    np.testing.assert_array_equal(chosen.sum(-1), np.broadcast_to(named[:, 0], (2, seq)))
+    if kind == "signed_zeros":
+        assert (_top_k_oracle(jnp.asarray(planted), top)[0].sum(-1) > named[:, 0]).any()
+
+
+@pytest.mark.parametrize("topk,seq,want", [
+    (None, 64, (2, 0)),  # the small preset's window
+    (None, 40, (2, 0)),
+    (64, 64, (0, 2)),  # k is the window: every block keeps every causal key
+    ("cell", 16384, (28, 4)),  # hy4_score_16k: blocks 0 and 1 of 16 lie below 2,048
+])
+def test_the_blocks_each_selection_takes(topk, seq, want):
+    cfg = (model_config(FILE, False)[0] if topk == "cell"
+           else dict(SMALL, **({"index_topk": topk} if topk else {})))
+    assert lm._index_blocks(lm.family_keys(cfg), seq) == want
+
+
 def _layer_input(seed=4, seq=64):
     rng = np.random.RandomState(seed)
     return jnp.asarray(rng.randn(2, seq, 64), jnp.float32)
@@ -359,12 +444,14 @@ def test_the_counters_of_a_call_and_diagnostics():
     assert got("lm.dsa_selected_pairs") == 2 * kept * 4 * 5
     assert got("lm.dsa_index_pairs") == 2 * (40 * 41 // 2) * 2 * 2
     assert got("lm.index_reuses") == 2 * 3
+    # one block of 40 queries a full layer, above the top-16: by threshold
+    assert got("lm.index_threshold_blocks") == 2 * 2 and got("lm.index_prefix_blocks") == 0
     assert got("lm.hc_stream_bytes") == 4 * 64 * 4 * 80 * 2 * 5
     assert got("moe.held_rows_expected") == got("moe.routed_rows") / 2
     assert got("lm.attention_pairs") == 0
     data = tfs.diagnostics(format="json")
     for name in ("lm.dsa_selected_pairs", "lm.dsa_index_pairs", "lm.index_reuses",
-                 "lm.hc_stream_bytes"):
+                 "lm.index_threshold_blocks", "lm.index_prefix_blocks", "lm.hc_stream_bytes"):
         assert data["model"][name] == counters[name]
     assert "lm.dsa_selected_pairs" in tfs.diagnostics()
 

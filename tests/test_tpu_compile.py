@@ -499,6 +499,19 @@ def test_index_kernel_at_the_cells_shape(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 2**28
 
 
+def test_index_top_k_kernel_at_the_cells_shape(one_chip):
+    # a block of 1,024 queries' float32 scores over the window's 16,384
+    # keys, the top-2,048: as `models.lm._select` calls the kernel
+    from tensorframes_tpu.ops.pallas_kernels import index_top_k
+
+    lowered, compiled = _compile(
+        lambda scores, start: index_top_k(scores, start, k=2048),
+        one_chip, ((1, 1024, 16384), jnp.float32), ((), jnp.int32),
+    )
+    assert "tpu_custom_call" in lowered.as_text()
+    assert compiled.memory_analysis().output_size_in_bytes < 2**24
+
+
 def test_sparse_attention_kernel_at_the_cells_shape(one_chip):
     # one window of 16,384 positions: 64 heads with a score of a per-head
     # part (192) and a rotary part (64) whose key all heads share, values
@@ -563,3 +576,12 @@ def test_the_sparse_scoring_program_at_the_cells_block(one_chip):
     assert {l.split()[1] for l in experts} == {
         f"f32[{moe.STEP_ROWS},4096]", f"f32[{moe.STEP_ROWS},6144]"}, experts
     assert not re.findall(r"\b[sufc](?:64|128)\[", text)
+    # the top-2,048 of a block's scores is the threshold kernel, named after
+    # its scope, and no sort of them; the temporaries are no more than the
+    # 7,241,536,000 B that the program holding that sort compiled to here
+    assert not [l for l in text.splitlines() if " sort(" in l and "f32[1,1024,16384]" in l]
+    top_k = [l for l in labels if re.match(r"^dsa\.top_k\.\d+ \(f32\[1,1024,1\]", l)]
+    assert len(top_k) == 1, sorted(l for l in labels if "top_k" in l)
+    assert re.search(r"= \(f32\[1,1024,1\]\S*, s32\[1,1024,1\]\S*, s32\[1,1024,2048\]\S*\) "
+                     r"custom-call\(.*tpu_custom_call", text)
+    assert memory.temp_size_in_bytes <= 7_241_536_000

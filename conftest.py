@@ -21,7 +21,8 @@ import pytest
 from perf.conftest import TABLED, _runner_of
 
 OWN_TESTS = {"map_blocks_lm_hybrid": "test_lm_hybrid_cell.py",  # runner -> its own file
-             "map_blocks_lm_sparse": "test_lm_sparse_cell.py"}
+             "map_blocks_lm_sparse": "test_lm_sparse_cell.py",
+             "map_blocks_lm_window": "test_lm_window_cell.py"}
 ON_FOUR_DEVICES = {"map_chain_200blocks_4chips": "test_four_chips_cell.py"}  # cell -> its file
 PERF_TESTS = os.path.join(os.path.dirname(os.path.abspath(__file__)), "perf", "tests")
 

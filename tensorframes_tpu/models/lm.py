@@ -50,8 +50,16 @@ correction bias; its head is float32 (``enable_lm_head_fp32``). With
 sublayer F is a manifold-constrained hyper-connection (`_hc_sublayer`):
 ``X <- M X + a_post ⊗ F(RMSNorm(Σ_i a_pre[i] X[i]))``, the coefficients
 from the normed streams, ``M`` a Sinkhorn-normalised mixing; the streams
-are read out by ``a_head`` after the last layer. A configuration without
-these keys traces none of it.
+are read out by ``a_head`` after the last layer. A configuration of
+``model_type: afmoe`` (`_afmoe_keys`: ``sliding_window``,
+``num_shared_experts``, ``route_norm``, ``route_scale``, ``score_func``)
+mixes "sliding_attention" layers, grouped-query attention over the last
+``sliding_window`` keys on the kernel's banded grid with RoPE, and
+"full_attention" layers without a positional encoding; both gate the
+heads' output by ``sigmoid(u W_g)`` before ``W_o``, every sublayer's output
+is normed before the residual add (``h + RMSNorm(Op(RMSNorm(h)))``), and
+the embedding is scaled by sqrt(d) (``mup_enabled``). A configuration
+without these keys traces none of it.
 
 Parameters are one pytree of arrays stacked by kind (every conv part's
 ``w_in`` in one array, every expert layer's ``w_up`` in one, ...), and all
@@ -88,7 +96,7 @@ import numpy as np
 from jax import lax
 
 from ..ops.pallas_kernels import (
-    flash_attention, index_scores, index_top_k, sparse_attention, ssd_scan,
+    band_pairs, flash_attention, index_scores, index_top_k, sparse_attention, ssd_scan,
 )
 from . import moe
 
@@ -96,9 +104,12 @@ __all__ = [
     "init_params", "scoring_fn", "score", "layer_plan", "held_all", "family_keys",
 ]
 
-OPS = ("conv", "full_attention", "latent_attention", "ssm", "experts", "sparse_attention")
+OPS = ("conv", "full_attention", "latent_attention", "ssm", "experts", "sparse_attention",
+       "sliding_attention")
 ATTENTION = (1, 2)  # the operator kinds that attend to every earlier key
 SSM, EXPERTS, SPARSE = OPS.index("ssm"), OPS.index("experts"), OPS.index("sparse_attention")
+SLIDING = OPS.index("sliding_attention")
+SWA_BLOCK = 1024  # the sliding window kernel's query and key blocks
 NO_FFN = -1  # a layer of one mixer: no FFN half
 PATTERN = {"M": "ssm", "*": "full_attention", "E": "experts"}
 HEAD_CHUNK = 2048  # tokens whose logits exist at one time
@@ -141,6 +152,8 @@ def family_keys(config) -> dict:
             c.setdefault(key, value)
     if "deepseek_sparse_attention" in c.get("layer_types", ()):
         _sparse_keys(c)
+    if c.get("model_type") == "afmoe" or "sliding_attention" in c.get("layer_types", ()):
+        _afmoe_keys(c)
     if "layer_types" not in c:
         if "kv_lora_rank" not in c:
             raise ValueError(
@@ -155,7 +168,11 @@ def family_keys(config) -> dict:
                          ("num_experts", "n_routed_experts"),
                          ("norm_eps", "rms_norm_eps"),
                          ("norm_eps", "layer_norm_epsilon"),
-                         ("router_score", "scoring_func")):
+                         ("router_score", "scoring_func"),
+                         ("router_score", "score_func"),
+                         ("n_shared_experts", "num_shared_experts"),
+                         ("norm_topk_prob", "route_norm"),
+                         ("routed_scaling_factor", "route_scale")):
         if ours not in c and theirs in c:
             c[ours] = c[theirs]
     if "use_expert_bias" not in c and "topk_method" in c:
@@ -177,7 +194,7 @@ def family_keys(config) -> dict:
         if "expand" in c and inner != int(c["expand"]) * int(c["hidden_size"]):
             raise ValueError(
                 f"expand = {c['expand']}: mamba_num_heads x mamba_head_dim is {inner}")
-    for key in ("n_group", "topk_group"):
+    for key in ("n_group", "topk_group", "num_expert_groups", "num_limited_groups"):
         if int(c.get(key) or 1) != 1:
             raise ValueError(
                 f"{key} = {c[key]}: a group-limited expert choice is not computed here"
@@ -187,6 +204,36 @@ def family_keys(config) -> dict:
             f"rope_scaling = {c['rope_scaling']!r}: RoPE length scaling is not computed here"
         )
     return c
+
+
+def _afmoe_keys(c):
+    """In place: the names of a family of sliding-window and full attention
+    mixed (``model_type: afmoe``): ``layer_types`` "sliding_attention"
+    (the last ``sliding_window`` keys, RoPE) and "full_attention" (every
+    earlier key, no positional encoding), both gated elementwise
+    (``attn_gate``) and every sublayer's output normed before the residual
+    add (``sandwich_norm``); the expert bias in the choice; the embedding
+    times sqrt(d) under ``mup_enabled``. ``layer_types`` governs;
+    ``global_attn_every_n_layers``, where given, has to agree with it.
+    What is not computed raises by its key."""
+    types = list(c["layer_types"])
+    if set(types) - {"sliding_attention", "full_attention"}:
+        raise ValueError(f"layer_types = {sorted(set(types))}: 'sliding_attention' or "
+                         "'full_attention' in this family")
+    every = c.get("global_attn_every_n_layers")
+    if every and [t == "full_attention" for t in types] != [
+            (i + 1) % int(every) == 0 for i in range(len(types))]:
+        raise ValueError(f"global_attn_every_n_layers = {every}: layer_types has its full "
+                         "layers elsewhere")
+    if "sliding_attention" in types and not c.get("sliding_window"):
+        raise ValueError("sliding_window: a sliding_attention layer needs its window")
+    for key, want in (("score_func", "sigmoid"), ("hidden_act", "silu"),
+                      ("tie_word_embeddings", False)):
+        if c.get(key, want) != want:
+            raise ValueError(f"{key} = {c[key]!r}: only {want!r} is computed here")
+    for key, value in (("rope", False), ("attn_gate", True), ("sandwich_norm", True),
+                       ("use_expert_bias", True)):
+        c.setdefault(key, value)
 
 
 def _sparse_keys(c):
@@ -346,6 +393,7 @@ def init_params(config, seed: int, held: Optional[Tuple[int, int]] = None):
     plan = layer_plan(config)
     n_conv = int(np.sum(plan[:, 0] == 0))
     n_attn = int(np.sum(plan[:, 0] == 1))
+    n_swa = int(np.sum(plan[:, 0] == SLIDING))
     n_mla = int(np.sum(np.isin(plan[:, 0], (2, SPARSE))))
     n_ssm = int(np.sum(plan[:, 0] == SSM))
     n_moe = len(_expert_layers(plan))
@@ -381,6 +429,14 @@ def init_params(config, seed: int, held: Optional[Tuple[int, int]] = None):
     }
     if not config.get("qk_norm", True):
         del shapes["attn"]["q_norm"], shapes["attn"]["k_norm"]
+    if config.get("attn_gate"):  # sigmoid(u W_g) on the heads' output
+        shapes["attn"]["w_g"] = ((n_attn, d, heads * hd), std)
+    if n_swa:  # the sliding layers' own stack of the same arrays
+        shapes["swa"] = {name: ((n_swa,) + shape[1:], scale)
+                         for name, (shape, scale) in shapes["attn"].items()}
+    if config.get("sandwich_norm"):  # each sublayer's output normed
+        shapes["op_post_norm"] = ((len(plan), d), None)
+        shapes["ffn_post_norm"] = ((len(plan), d), None)
     if latent:
         shapes["moe"]["latent_in"] = ((n_moe, d, latent), std)
         shapes["moe"]["latent_out"] = ((n_moe, latent, d), std)
@@ -412,7 +468,7 @@ def init_params(config, seed: int, held: Optional[Tuple[int, int]] = None):
             name: ((n_ssm,) + shape, scale)
             for name, (shape, scale) in _ssm_shapes(config, std).items()
         }
-    if n_mla or config.get("one_mixer"):
+    if n_mla or n_swa or config.get("one_mixer"):
         for kind, n in (("conv", n_conv), ("attn", n_attn)):
             if not n:  # this family has the stacks of the operators it has
                 del shapes[kind]
@@ -508,7 +564,11 @@ def _rope(x, theta, interleave: bool = False):
     return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
 
 
-def _attention_op(config, p, u, interpret):
+def _attention_op(config, p, u, interpret, window=None):
+    """Grouped-query attention; with ``window`` a sliding layer: the last
+    ``window`` keys, RoPE whatever ``rope`` says (it speaks of the full
+    layers), the kernel under its own scope, ``lm.swa``. Where the layer has
+    ``w_g``, the heads' output times ``sigmoid(u W_g)``, elementwise."""
     with jax.named_scope("lm.attention"):
         rows, seq, _ = u.shape
         heads, kv = int(config["num_attention_heads"]), int(config["num_key_value_heads"])
@@ -518,18 +578,29 @@ def _attention_op(config, p, u, interpret):
         q, k, v = qkv[:, :heads], qkv[:, heads:heads + kv], qkv[:, heads + kv:]
         if config.get("qk_norm", True):
             q, k = _rms_norm(q, p["q_norm"], eps), _rms_norm(k, p["k_norm"], eps)
-        if config.get("rope", True):
+        if window is not None or config.get("rope", True):
             theta = float(config["rope_theta"])
             q, k = _rope(q, theta), _rope(k, theta)
         dtype = p["w_qkv"].dtype
-        # 1,024-blocks from 16,384 positions on, as the latent kernel's
-        block = min(1024 if seq >= 16384 else 512, max(8, seq))
-        att = flash_attention(
-            q.astype(dtype), k.astype(dtype), v.astype(dtype), causal=True,
-            scale=float(1.0 / np.sqrt(hd)), block_q=block, block_k=block,
-            interpret=interpret,
-        )
+        if window is not None:
+            with jax.named_scope("lm.swa"):
+                att = flash_attention(
+                    q.astype(dtype), k.astype(dtype), v.astype(dtype), causal=True,
+                    scale=float(1.0 / np.sqrt(hd)), block_q=SWA_BLOCK, block_k=SWA_BLOCK,
+                    interpret=interpret, window=window,
+                )
+        else:
+            # 1,024-blocks from 16,384 positions on, as the latent kernel's
+            block = min(1024 if seq >= 16384 else 512, max(8, seq))
+            att = flash_attention(
+                q.astype(dtype), k.astype(dtype), v.astype(dtype), causal=True,
+                scale=float(1.0 / np.sqrt(hd)), block_q=block, block_k=block,
+                interpret=interpret,
+            )
         att = jnp.swapaxes(att, 1, 2).reshape(rows, seq, heads * hd)
+        if "w_g" in p:
+            with jax.named_scope("attn.gate"):
+                att = att.astype(jnp.float32) * jax.nn.sigmoid(_matmul(u, p["w_g"]))
         return _matmul(att, p["w_o"])
 
 
@@ -897,6 +968,8 @@ def scoring_fn(
         rows, seq = tokens.shape
         with jax.named_scope("lm.embed"):
             h = jnp.take(params["embed"], tokens, axis=0).astype(jnp.float32)
+            if config.get("mup_enabled"):
+                h = h * jnp.float32(np.sqrt(int(config["hidden_size"])))
 
         def unrouted(y):
             return (y, jnp.zeros((rows, e), jnp.int32),
@@ -908,6 +981,9 @@ def scoring_fn(
             2: lambda u, i: _latent_attention_op(
                 config, _at(params["mla"], i), u, bool(interpret)),
             SSM: lambda u, i: _ssm_op(config, _at(params["ssm"], i), u, bool(interpret)),
+            SLIDING: lambda u, i: _attention_op(
+                config, _at(params["swa"], i), u, bool(interpret),
+                window=int(config["sliding_window"])),
         }
         if routed_ops:
             ops = {kind: (lambda u, i, op=op: unrouted(op(u, i))) for kind, op in ops.items()}
@@ -921,19 +997,25 @@ def scoring_fn(
                                   moe_layers, interpret)
 
         def layer(h, xs):
-            row, gains = xs
+            row, gains, post = xs
             y = _pick(ops, ops_present, row[0], _rms_norm(h, gains[0], eps), row[1])
             if routed_ops:
                 y, *routed = y
+            if "op_post_norm" in post:
+                y = _rms_norm(y, post["op_post_norm"], eps)
             h = h + y
             if ffn_present:  # a layer of one mixer has no FFN half
                 y, *routed = _pick(
                     ffns, ffn_present, row[2], _rms_norm(h, gains[1], eps), row[3])
+                if "ffn_post_norm" in post:
+                    y = _rms_norm(y, post["ffn_post_norm"], eps)
                 h = h + y
             return h, tuple(routed)
 
         gains = (params["op_norm"],) + ((params["ffn_norm"],) if ffn_present else ())
-        h, (loads, choices) = lax.scan(layer, h, (jnp.asarray(which), gains))
+        # sandwich norms: a sublayer's output normed before the residual add
+        post = {k: params[k] for k in ("op_post_norm", "ffn_post_norm") if k in params}
+        h, (loads, choices) = lax.scan(layer, h, (jnp.asarray(which), gains, post))
         return {
             "token_logprob": _head(config, params, h, tokens),
             "expert_load": jnp.swapaxes(loads[moe_layers], 0, 1),
@@ -1000,7 +1082,11 @@ def score(fn: Callable, frame, params, config, **verb_args):
     ``moe.held_rows_expected`` (the routed rows x the share of the experts
     whose weights ``params`` holds: what this holder's experts are expected
     to compute), ``lm.attention_pairs`` (causal query-key pairs x heads x
-    attention layers), ``lm.ssm_steps`` (tokens x state-space layers);
+    full attention layers), ``lm.ssm_steps`` (tokens x state-space layers);
+    under sliding-window attention ``lm.swa_pairs`` (Σ_t min(t + 1,
+    sliding_window) x heads x sliding layers x rows) and ``lm.swa_blocks``
+    (the (query block, key block) pairs the banded kernel computes, x heads
+    x sliding layers x rows: `band_pairs`);
     under sparse attention ``lm.dsa_selected_pairs`` (Σ_t min(t + 1,
     index_topk) x heads x sparse layers x rows), ``lm.dsa_index_pairs``
     (causal pairs x index heads x ``full`` layers x rows),
@@ -1031,6 +1117,14 @@ def score(fn: Callable, frame, params, config, **verb_args):
               * int(np.isin(plan[:, 0], ATTENTION).sum())),
     )
     telemetry.counter_inc("lm.ssm_steps", float(tokens * int(np.sum(plan[:, 0] == SSM))))
+    n_swa = int(np.sum(plan[:, 0] == SLIDING))
+    if n_swa:
+        window, heads = int(config["sliding_window"]), int(config["num_attention_heads"])
+        top = min(window, seq)
+        kept = top * (top + 1) // 2 + (seq - top) * top  # Σ_t min(t + 1, window)
+        blocks = band_pairs(seq, SWA_BLOCK, SWA_BLOCK, window)
+        for name, value in (("lm.swa_pairs", kept), ("lm.swa_blocks", blocks)):
+            telemetry.counter_inc(name, float(frame.nrows * value * heads * n_swa))
     if SPARSE in plan[:, 0]:
         full = int(np.sum(_full_index(config) >= 0))
         top = min(int(config["index_topk"]), seq)

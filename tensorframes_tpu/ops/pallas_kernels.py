@@ -13,7 +13,11 @@ attention) through the block index, so nothing is repeated in memory.
 take it), and the score may have a second part, ``q2 · k2ᵀ`` added to
 ``q · kᵀ`` before the scale and the mask, whose keys ``k2`` have heads of
 their own count (latent attention: one rotary key a token for all heads,
-found through the block index like a grouped key head).
+found through the block index like a grouped key head). With a causal
+``window`` the grid is BANDED: a query block's key axis has as many steps
+as the longest band holds key blocks, step j visits the band's (first +
+j)-th block, and a block wholly outside the band is never read (a
+sliding-window layer costs its band, not its causal prefix).
 
 This kernel is the single-device building block the ring attention in
 `parallel/ring.py` composes across chips (K/V rotation over ICI); it is
@@ -67,15 +71,40 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-__all__ = ["flash_attention", "ssd_scan", "sparse_attention", "index_scores", "index_top_k"]
+__all__ = ["flash_attention", "band_pairs", "ssd_scan", "sparse_attention", "index_scores",
+           "index_top_k"]
 
 _NEG_INF = -1e30
+
+
+def _band(i, blk_q: int, blk_k: int, window: int, nk: int):
+    """(first, last) key block of query block ``i``'s band: the blocks that
+    hold a key s with ``t - window < s <= t`` for some query t of the
+    block (int32; `lax.div`, as in the index maps)."""
+    first = jax.lax.div(jnp.maximum(i * blk_q - (window - 1), 0), jnp.int32(blk_k))
+    last = jnp.minimum(jax.lax.div(i * blk_q + (blk_q - 1), jnp.int32(blk_k)), nk - 1)
+    return first, last
+
+
+def _band_sizes(nq: int, blk_q: int, blk_k: int, window: int, nk: int):
+    """How many key blocks each query block's band holds (`_band`, on the
+    host); the banded grid's key axis takes the most of them."""
+    return [min((i * blk_q + blk_q - 1) // blk_k, nk - 1)
+            - max(i * blk_q - (window - 1), 0) // blk_k + 1 for i in range(nq)]
+
+
+def band_pairs(seq: int, block_q: int, block_k: int, window: int) -> int:
+    """The (query block, key block) pairs `flash_attention` computes for
+    one head over ``seq`` positions under ``window`` at these blocks."""
+    blk_q, blk_k = min(block_q, max(8, seq)), min(block_k, max(8, seq))
+    return sum(_band_sizes(-(-seq // blk_q), blk_q, blk_k, window, -(-seq // blk_k)))
 
 
 def _flash_kernel(
     q_ref, k_ref, v_ref, *rest,
     scale: float, causal: bool, seq_len: int, blk_q: int, blk_k: int,
-    selected: bool = False, sink: bool = False,
+    selected: bool = False, sink: bool = False, window: Optional[int] = None,
+    nk: int = 0,
 ):
     # before the output: a second score part's two blocks, then (sparse
     # attention) the block of the selection mask and the head's sink logit
@@ -92,10 +121,16 @@ def _flash_kernel(
         l_sc[:] = jnp.zeros_like(l_sc)
         acc_sc[:] = jnp.zeros_like(acc_sc)
 
-    # Causal fast-skip: whole k-block strictly above the diagonal.
-    needed = jnp.logical_or(
-        not causal, j * blk_k <= i * blk_q + (blk_q - 1)
-    )
+    if window is None:
+        kb = j
+        # Causal fast-skip: whole k-block strictly above the diagonal.
+        needed = jnp.logical_or(
+            not causal, j * blk_k <= i * blk_q + (blk_q - 1)
+        )
+    else:  # step j of the band visits its (first + j)-th block, up to its last
+        first, last = _band(i, blk_q, blk_k, window, nk)
+        kb = first + j
+        needed = kb <= last
 
     @pl.when(needed)
     def _step():
@@ -114,10 +149,12 @@ def _flash_kernel(
         s = s * scale
 
         q_pos = i * blk_q + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
-        k_pos = j * blk_k + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
+        k_pos = kb * blk_k + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
         mask = k_pos < seq_len  # padded tail keys contribute nothing
         if causal:
             mask = jnp.logical_and(mask, q_pos >= k_pos)
+        if window is not None:  # the band: the last `window` keys up to the query
+            mask = jnp.logical_and(mask, k_pos > q_pos - window)
         if selected:  # only the keys the query's selection holds
             mask = jnp.logical_and(mask, sel_ref[:] != 0)
         # NB: f32-typed constants — x64-mode weak f64 literals trip Mosaic
@@ -159,6 +196,7 @@ def flash_attention(
     block_q: int = 128,
     block_k: int = 128,
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Single-device blockwise attention. q/k/v: one head ``(seq,
     head_dim)``, or ``(batch, heads, seq, head_dim)`` with k and v
@@ -170,13 +208,20 @@ def flash_attention(
     ``(batch, kv2_heads, seq, d2)``, given together, are a second part of
     the score, ``(q kᵀ + q2 k2ᵀ) * scale``, ``kv2_heads`` dividing
     ``heads`` as ``kv_heads`` does (the default scale is
-    ``1 / sqrt(head_dim + d2)``)."""
+    ``1 / sqrt(head_dim + d2)``). ``window`` (a static int, causal only):
+    query t attends to the keys s with ``t - window < s <= t``, the last
+    ``window`` keys including its own, on a BANDED grid: a query block's
+    key axis visits only the key blocks that meet its band
+    (`_band_sizes`), keys of a visited block outside the band are
+    masked, and a block wholly outside it is never read."""
     if (q2 is None) != (k2 is None):
         raise ValueError("q2 and k2 are the two sides of one score part: give both")
     if q2 is not None and (q2.ndim != 4 or q.ndim != 4):
         raise ValueError("a second score part takes (batch, heads, seq, width) arrays")
     if scale is None:
         scale = 1.0 / np.sqrt(q.shape[-1] + (0 if q2 is None else q2.shape[-1]))
+    if window is not None and (not causal or int(window) < 1):
+        raise ValueError(f"window = {window!r}: a window is a causal band of one key or more")
     for name, keys in (("key/value", k), ("second-part key", k2)):
         if keys is not None and q.ndim == 4 and q.shape[1] % keys.shape[1]:
             raise ValueError(
@@ -185,20 +230,21 @@ def flash_attention(
             )
     return _flash(
         q, k, v, None if q2 is None else (q2, k2), bool(causal), float(scale),
-        block_q, block_k, bool(interpret),
+        block_q, block_k, bool(interpret), None if window is None else int(window),
     )
 
 
-def _flash_forward(q, k, v, second, causal, scale, block_q, block_k, interpret):
-    return _flash_call(q, k, v, second, causal, scale, block_q, block_k, interpret)
+def _flash_forward(q, k, v, second, causal, scale, block_q, block_k, interpret, window):
+    return _flash_call(q, k, v, second, causal, scale, block_q, block_k, interpret,
+                       window=window)
 
 
 def _flash_call(q, k, v, second, causal, scale, block_q, block_k, interpret,
-                selection=None, sink=None):
+                selection=None, sink=None, window=None):
     if q.ndim == 2:
         out = _flash_call(
             q[None, None], k[None, None], v[None, None], second,
-            causal, scale, block_q, block_k, interpret,
+            causal, scale, block_q, block_k, interpret, window=window,
         )
         return out[0, 0]
     batch, heads, seq, d = q.shape
@@ -224,6 +270,7 @@ def _flash_call(q, k, v, second, causal, scale, block_q, block_k, interpret,
         blk_k=blk_k,
         selected=selection is not None,
         sink=sink is not None,
+        **({} if window is None else {"window": window, "nk": nk}),
     )
 
     def q_block(b, h, i, j):
@@ -236,7 +283,12 @@ def _flash_call(q, k, v, second, causal, scale, block_q, block_k, interpret,
             # `lax.div`, not `//`: the operands are never negative, and
             # Mosaic lowers an index map too (a floor division's sign
             # handling does not lower under x64)
-            if causal:
+            if window is not None:
+                # the band's (first + j)-th block; past its last, the last
+                # again (already there), as the causal map does
+                first, last = _band(i, blk_q, blk_k, window, nk)
+                j = jnp.minimum(first + j, last)
+            elif causal:
                 # a block above the diagonal is skipped: ask for the last
                 # one needed again, which is already there, not for a new one
                 j = jnp.minimum(
@@ -273,7 +325,8 @@ def _flash_call(q, k, v, second, causal, scale, block_q, block_k, interpret,
 
     out = pl.pallas_call(
         kernel,
-        grid=(batch, heads, nq, nk),
+        grid=(batch, heads, nq,
+              nk if window is None else max(_band_sizes(nq, blk_q, blk_k, window, nk))),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((None, None, blk_q, dv), q_block),
         out_shape=jax.ShapeDtypeStruct(qp.shape[:-1] + (dv,), q.dtype),
@@ -287,26 +340,38 @@ def _flash_call(q, k, v, second, causal, scale, block_q, block_k, interpret,
     return out[:, :, :seq] if pad_q else out
 
 
-_flash = jax.custom_vjp(_flash_forward, nondiff_argnums=(4, 5, 6, 7, 8))
+_flash = jax.custom_vjp(_flash_forward, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 
 
 def _flash_fwd(q, k, v, second, *static):
     return _flash_forward(q, k, v, second, *static), (q, k, v, second)
 
 
-def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
+def _windowed(q, k, v, scale, window):
+    """Plain attention of one head over the band ``t - window < s <= t``."""
+    t = jnp.arange(q.shape[0])[:, None] - jnp.arange(k.shape[0])[None, :]
+    s = (q.astype(jnp.float32) @ k.astype(jnp.float32).T) * scale
+    w = jax.nn.softmax(jnp.where((t >= 0) & (t < window), s, -jnp.inf), axis=-1)
+    return (w @ v.astype(jnp.float32)).astype(q.dtype)
+
+
+def _flash_bwd(causal, scale, block_q, block_k, interpret, window, res, g):
     from ..parallel.ring import full_attention
+
+    def attend(q, k, v):
+        if window is not None:
+            return _windowed(q, k, v, scale, window)
+        return full_attention(q, k, v, causal=causal, scale=scale)
 
     def plain(q, k, v, second):
         if q.ndim == 2:
-            return full_attention(q, k, v, causal=causal, scale=scale)
+            return attend(q, k, v)
         every = lambda a: jnp.repeat(a, q.shape[1] // a.shape[1], axis=1)
         k = every(k)
         if second is not None:  # the score's two parts as one wider dot
             q = jnp.concatenate([q, second[0]], axis=-1)
             k = jnp.concatenate([k, every(second[1])], axis=-1)
-        one = lambda a, b, c: full_attention(a, b, c, causal=causal, scale=scale)
-        return jax.vmap(jax.vmap(one))(q, k, every(v))
+        return jax.vmap(jax.vmap(attend))(q, k, every(v))
 
     _, vjp = jax.vjp(plain, *res)
     return vjp(g)

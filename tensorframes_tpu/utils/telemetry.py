@@ -986,6 +986,14 @@ _PROM_HELP: Dict[str, str] = {
         "Causal query-key pairs (x heads x attention layers) attended by "
         "models.lm.score"
     ),
+    "lm.swa_pairs": (
+        "Query-key pairs inside the window (x heads x sliding attention "
+        "layers) attended by models.lm.score"
+    ),
+    "lm.swa_blocks": (
+        "Query block x key block pairs (x heads x sliding attention layers) "
+        "the banded attention grid computed in models.lm.score"
+    ),
     "lm.ssm_steps": (
         "State-space scan steps (tokens x state-space layers) of "
         "models.lm.score"

@@ -142,6 +142,94 @@ class TestFlashAttention:
         for g, w in zip(got, want):
             np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4, atol=2e-5)
 
+    @staticmethod
+    def _grid(fn, *args):
+        """The grid of the one `pallas_call` in ``fn``'s jaxpr."""
+        def calls(jaxpr):
+            for eqn in jaxpr.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    yield eqn.params["grid_mapping"].grid
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from calls(sub)
+
+        (grid,) = calls(jax.make_jaxpr(fn)(*args).jaxpr)
+        return grid
+
+    @pytest.mark.parametrize("group", [1, 8])
+    @pytest.mark.parametrize("blocks", [(32, 32), (24, 40)])
+    @pytest.mark.parametrize("window", [1, 7, 64, 100, 150, 1000])
+    def test_window_is_a_band_on_a_banded_grid(self, window, blocks, group):
+        # query t sees keys t - window < s <= t: against a plain masked
+        # softmax (GQA: `group` query heads a key head; 150 positions, so
+        # the last blocks are padded); blocks that divide the window and
+        # blocks that do not; the grid's key axis spans the band's blocks
+        # alone; a window of the whole sequence or more IS the causal kernel
+        block_q, block_k = blocks
+        rng = np.random.RandomState(window + group)
+        seq, kv = 150, 2 if group == 1 else 1
+        q = jnp.asarray(rng.randn(1, kv * group, seq, 16), jnp.float32)
+        k, v = (jnp.asarray(rng.randn(1, kv, seq, 16), jnp.float32) for _ in range(2))
+        call = functools.partial(flash_attention, causal=True, block_q=block_q,
+                                 block_k=block_k)
+        out = call(q, k, v, window=window)
+        t = np.arange(seq)[:, None] - np.arange(seq)[None, :]
+        every = lambda a: jnp.repeat(a, group, axis=1)
+        s = jnp.einsum("bhqd,bhkd->bhqk", q, every(k), precision="highest") / 4.0
+        s = jnp.where(jnp.asarray((t >= 0) & (t < window)), s, -jnp.inf)
+        want = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), every(v),
+                          precision="highest")
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want), rtol=2e-5, atol=2e-6)
+        nq, nk = -(-seq // block_q), -(-seq // block_k)
+        band = max(min((i * block_q + block_q - 1) // block_k, nk - 1)
+                   - max(i * block_q - window + 1, 0) // block_k + 1 for i in range(nq))
+        grid = self._grid(functools.partial(call, window=window), q, k, v)
+        assert grid == (1, kv * group, nq, band)
+        if window >= seq:
+            np.testing.assert_array_equal(np.asarray(out), np.asarray(call(q, k, v)))
+        elif window < 100:
+            assert band < nk  # blocks wholly outside the band are never visited
+
+    def test_without_a_window_the_kernel_traces_as_it_did(self):
+        # `window=None` is the kernel as it was: its jaxpr (the kernel's
+        # source location aside) hashes to what it hashed to before the
+        # window came, for a causal and a plain call
+        import hashlib
+        import re
+
+        q = jnp.zeros((1, 8, 150, 16), jnp.float32)
+        kv = jnp.zeros((1, 1, 150, 16), jnp.float32)
+        pinned = {
+            True: "ef5637e0f1708ad653c95ecb38304188551da4aa4b0de39acd319503fe78b770",
+            False: "a1f3b3e0fe916e69f852c1ea43de539ffcbbad93380df1957bcdd4c995b917cc",
+        }
+        for causal, digest in pinned.items():
+            text = str(jax.make_jaxpr(functools.partial(
+                flash_attention, causal=causal, block_q=32, block_k=32, window=None))(q, kv, kv))
+            text = re.sub(r"name_and_src_info=\S+ at \S+", "", text)
+            assert hashlib.sha256(text.encode()).hexdigest() == digest, causal
+        with pytest.raises(ValueError, match="causal band"):
+            flash_attention(q, kv, kv, window=4)
+        with pytest.raises(ValueError, match="causal band"):
+            flash_attention(q, kv, kv, causal=True, window=0)
+
+    def test_grad_through_a_window_is_the_plain_forms(self):
+        q, k, v = (jnp.asarray(np.random.RandomState(s).randn(1, 2, 24, 8), jnp.float32)
+                   for s in (11, 12, 13))
+        t = np.arange(24)[:, None] - np.arange(24)[None, :]
+
+        def plain(q, k, v):
+            s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(8)
+            s = jnp.where(jnp.asarray((t >= 0) & (t < 5)), s, -jnp.inf)
+            return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), v)
+
+        loss = lambda f: lambda *a: jnp.sum(f(*a) ** 2)
+        kernel = lambda q, k, v: flash_attention(q, k, v, causal=True, block_q=8,
+                                                 block_k=8, window=5)
+        got = jax.grad(loss(kernel), argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(loss(plain), argnums=(0, 1, 2))(q, k, v)
+        for g, w in zip(got, want):
+            np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4, atol=2e-5)
+
     def test_grad_is_full_attentions(self):
         # the kernel has no transpose rule of its own: its custom_vjp
         # backward is full_attention's, also under the per-head vmap

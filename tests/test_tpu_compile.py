@@ -585,3 +585,75 @@ def test_the_sparse_scoring_program_at_the_cells_block(one_chip):
     assert re.search(r"= \(f32\[1,1024,1\]\S*, s32\[1,1024,1\]\S*, s32\[1,1024,2048\]\S*\) "
                      r"custom-call\(.*tpu_custom_call", text)
     assert memory.temp_size_in_bytes <= 7_241_536_000
+
+
+def _trinity_config():
+    """`trinity-mini` as the benchmark's runner hands it to the program."""
+    import json
+    import os
+    import sys
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    from perf.runners.map_blocks_lm import model_config
+
+    with open(os.path.join(root, "perf", "configs", "trinity-mini.json")) as f:
+        config = json.load(f)
+    return config, model_config(config, False)
+
+
+def test_sliding_window_kernel_at_the_cells_shape(one_chip):
+    # one window of 32,768 positions: 32 query heads over 4 key/value heads
+    # of 128, a band of 2,048 keys, bfloat16, 1,024-blocks: as `models.lm`
+    # calls the kernel for a sliding layer (3 key blocks a query block)
+    bf16 = jnp.bfloat16
+    lowered, compiled = _compile(
+        functools.partial(flash_attention, causal=True, block_q=1024, block_k=1024,
+                          window=2048),
+        one_chip, ((1, 32, 32768, 128), bf16), ((1, 4, 32768, 128), bf16),
+        ((1, 4, 32768, 128), bf16),
+    )
+    assert "tpu_custom_call" in lowered.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**29
+
+
+def test_the_window_scoring_program_at_the_cells_block(one_chip):
+    """`lm.scoring_fn` of `trinity-mini` at the published widths over one
+    block of the cell (one window of 32,768 ids), every expert held, the
+    weights arguments: it compiles for the chip as module `jit_lm_score`;
+    its temporaries fit beside 7.90 GiB of weights; the sliding layers'
+    kernel is one operation that `kernel_ops.swa_attention` matches, the
+    full layer's another that it does not; the grouped matmuls are what
+    `kernel_ops.moe_experts` matches; and the program holds no 64-bit
+    array."""
+    import re
+
+    from perf.lib.trace import op_label
+    from tensorframes_tpu.models import lm
+
+    config, cfg = _trinity_config()
+    shapes = jax.eval_shape(lambda: lm.init_params(cfg, 0))
+    params = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip), shapes)
+    tokens = jax.ShapeDtypeStruct((1, 32768), jnp.int32, sharding=one_chip)
+    compiled = jax.jit(lm.scoring_fn(cfg)).lower(tokens, params).compile()
+    memory = compiled.memory_analysis()
+    weights = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                  for a in jax.tree_util.tree_leaves(shapes))
+    assert weights == 4_241_534_720 * 2  # 4,241,490,432 in matrices, 44,288 in norms' gains
+    assert weights + memory.temp_size_in_bytes < 12.0 * 2**30
+    assert memory.output_size_in_bytes < 8 * 2**20
+
+    text = compiled.as_text()
+    assert re.search(r"^HloModule jit_lm_score\b", text, re.M)
+    labels = [
+        op_label(line.strip().removeprefix("ROOT "))
+        for line in text.splitlines() if " = " in line
+    ]
+    band = sorted({l for l in labels if re.search(config["kernel_ops"]["swa_attention"], l)})
+    assert len(band) == 1 and band[0].endswith("bf16[1,32,32768,128]"), band
+    full = sorted({l for l in labels if l.startswith("lm.attention")})
+    assert len(full) == 1 and full[0].endswith("bf16[1,32,32768,128]"), full
+    experts = sorted({l for l in labels if re.search(config["kernel_ops"]["moe_experts"], l)})
+    assert len(experts) == 2 and all(l.startswith("ragged-dot-none") for l in experts)
+    assert not re.findall(r"\b[sufc](?:64|128)\[", text)

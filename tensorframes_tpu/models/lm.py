@@ -68,7 +68,10 @@ its FFN with `lax.switch`: compile time does not grow with depth, and each
 kernel is in the program, and in a device trace, once. Weights are
 bfloat16 (``config["dtype"]``), made on the device from a seed; the
 residual stream, norms, softmax, router scores and the log-sum-exp are
-float32; matmuls take bfloat16 operands and accumulate in float32.
+float32; matmuls take bfloat16 operands and accumulate in float32. The
+head is one kernel, `ops.pallas_kernels.head_logprob`: a tile of tokens'
+logits live in VMEM while the vocabulary streams past, and only each
+token's log-probability of the next reaches HBM.
 
 `scoring_fn(config)` is the plain function a verb runs over a block:
 ``tfs.map_blocks(fn, frame, bindings={"params": params})``. Its outputs
@@ -96,7 +99,8 @@ import numpy as np
 from jax import lax
 
 from ..ops.pallas_kernels import (
-    band_pairs, flash_attention, index_scores, index_top_k, sparse_attention, ssd_scan,
+    band_pairs, flash_attention, head_logprob, index_scores, index_top_k, sparse_attention,
+    ssd_scan,
 )
 from . import moe
 
@@ -112,7 +116,7 @@ SLIDING = OPS.index("sliding_attention")
 SWA_BLOCK = 1024  # the sliding window kernel's query and key blocks
 NO_FFN = -1  # a layer of one mixer: no FFN half
 PATTERN = {"M": "ssm", "*": "full_attention", "E": "experts"}
-HEAD_CHUNK = 2048  # tokens whose logits exist at one time
+HEAD_CHUNK = 2048  # tokens whose float32 logits exist at one time (`enable_lm_head_fp32`)
 INDEX_QUERIES = 1024  # queries whose index scores exist at one time
 SINKHORN = 20  # a hyper-connection's row and column normalisations
 
@@ -897,14 +901,22 @@ def _moe_ffn(config, stacks, i, u, held):
     return y.reshape(rows, seq, d), load, idx.reshape(rows, seq, top_k)
 
 
-def _head(config, params, h, tokens):
-    """log p(next token) per position, the logits made a chunk of tokens
-    at a time: (rows, seq) float32, the last position 0."""
+def _head(config, params, h, tokens, interpret):
+    """log p(next token) per position: (rows, seq) float32, the last
+    position 0. In the weights' dtype, one kernel (`head_logprob`) whose
+    logits never leave VMEM; under ``enable_lm_head_fp32`` float32
+    operands at full precision, the logits made a chunk of tokens at a
+    time."""
     with jax.named_scope("lm.head"):
         rows, seq, d = h.shape
         x = _rms_norm(h, params["final_norm"], float(config["norm_eps"]))
         target = jnp.concatenate([tokens[:, 1:], tokens[:, :1]], axis=1)
         n = rows * seq
+        if not config.get("enable_lm_head_fp32"):
+            w = params["head"]
+            lp = head_logprob(x.reshape(n, d).astype(w.dtype), w, target.reshape(n),
+                              interpret=interpret)
+            return lp.reshape(rows, seq).at[:, -1].set(0.0)
         chunk = min(HEAD_CHUNK, n)
         pad = (-n) % chunk
         x = jnp.pad(x.reshape(n, d), ((0, pad), (0, 0)))
@@ -912,11 +924,8 @@ def _head(config, params, h, tokens):
 
         def one(args):
             xc, tc = args
-            if config.get("enable_lm_head_fp32"):  # float32 operands, full precision
-                logits = jnp.dot(xc, params["head"].astype(jnp.float32),
-                                 precision=lax.Precision.HIGHEST)
-            else:
-                logits = _matmul(xc, params["head"])
+            logits = jnp.dot(xc, params["head"].astype(jnp.float32),
+                             precision=lax.Precision.HIGHEST)
             lse = jax.nn.logsumexp(logits, axis=-1)
             return moe._along_rows(logits, tc[:, None])[:, 0] - lse
 
@@ -1017,7 +1026,7 @@ def scoring_fn(
         post = {k: params[k] for k in ("op_post_norm", "ffn_post_norm") if k in params}
         h, (loads, choices) = lax.scan(layer, h, (jnp.asarray(which), gains, post))
         return {
-            "token_logprob": _head(config, params, h, tokens),
+            "token_logprob": _head(config, params, h, tokens, bool(interpret)),
             "expert_load": jnp.swapaxes(loads[moe_layers], 0, 1),
             "expert_choice": jnp.swapaxes(choices[moe_layers], 0, 1),
         }
@@ -1068,7 +1077,7 @@ def _sparse_layers(config, params, tokens, h, which, ffns, ffn_present, moe_laye
         with jax.named_scope("lm.hc"):
             X = _streams(_hc_coefficients(config, params["hc_head"], X), X)
     return {
-        "token_logprob": _head(config, params, X, tokens),
+        "token_logprob": _head(config, params, X, tokens, bool(interpret)),
         "expert_load": jnp.swapaxes(loads[moe_layers], 0, 1),
         "expert_choice": jnp.swapaxes(choices[moe_layers], 0, 1),
         "index_choice": jnp.swapaxes(keys[np.flatnonzero(index_at >= 0).astype(np.int32)], 0, 1),
@@ -1095,7 +1104,9 @@ def score(fn: Callable, frame, params, config, **verb_args):
     blocks x ``full`` layers x rows chosen by threshold, and those that
     kept every causal key: `_index_blocks`); under hyper-connections
     ``lm.hc_stream_bytes`` (streams x d x 4 B x tokens x 2 sublayers x
-    layers): all known on the host before the dispatch."""
+    layers); ``lm.head_kernel_tokens`` (the tokens whose log-probability
+    the fused head kernel computes: ``lm.tokens``, or 0 under
+    ``enable_lm_head_fp32``): all known on the host before the dispatch."""
     from .. import api
     from ..utils import telemetry
 
@@ -1105,6 +1116,8 @@ def score(fn: Callable, frame, params, config, **verb_args):
     plan = layer_plan(config)
     routed = tokens * int(config["num_experts_per_tok"]) * len(_expert_layers(plan))
     telemetry.counter_inc("lm.tokens", float(tokens))
+    telemetry.counter_inc(
+        "lm.head_kernel_tokens", 0.0 if config.get("enable_lm_head_fp32") else float(tokens))
     telemetry.counter_inc("moe.routed_rows", float(routed))
     telemetry.counter_inc(
         "moe.held_rows_expected",

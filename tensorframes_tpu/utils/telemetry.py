@@ -1022,6 +1022,10 @@ _PROM_HELP: Dict[str, str] = {
         "Bytes of the hyper-connection streams (streams x d x 4 B x tokens x "
         "2 sublayers x layers) of models.lm.score"
     ),
+    "lm.head_kernel_tokens": (
+        "Tokens whose next-token log-probability the fused head kernel "
+        "computed in models.lm.score"
+    ),
     "fault_retries": "Classified dispatch retries by fault class",
     "device_evictions": "Failover circuit-breaker device evictions",
     "block_splits": "OOM-triggered block split-retries by verb",

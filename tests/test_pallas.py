@@ -266,3 +266,60 @@ class TestFlashAttention:
         for _ in range(3):
             params, loss = lm.train_step(params, tokens)
         assert np.isfinite(float(loss)) and float(loss) < float(first)
+
+
+class TestHeadLogprob:
+    """`head_logprob` (interpreted) against `jax.nn.log_softmax` and
+    `take_along_axis` over the whole float32 logits."""
+
+    @staticmethod
+    def _plain(x, w, t):
+        s = jnp.dot(x.astype(jnp.float32), w.astype(jnp.float32), precision="highest")
+        return jnp.take_along_axis(jax.nn.log_softmax(s, -1), t[:, None], 1)[:, 0]
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("n,vocab,block_t,block_v", [
+        (64, 1024, 32, 256),   # both axes whole tiles
+        (50, 1000, 16, 256),   # a padded token tail, a masked vocabulary tail
+        (48, 320, 16, 256),    # 256 + 64: the last tile a quarter real
+        (37, 256, 32, 128),    # tokens padded, the vocabulary whole tiles
+        (40, 300, 32, 512),    # one vocabulary tile, wider than V: a block of V
+    ])
+    def test_matches_log_softmax(self, n, vocab, block_t, block_v, dtype):
+        rng = np.random.RandomState(n + vocab)
+        d = 32
+        # logits spanning about ±80 (x · w has sd 45): the running max moves
+        # from tile to tile and an unshifted exponential would overflow
+        x = jnp.asarray(8.0 * rng.randn(n, d), dtype)
+        w = jnp.asarray(rng.randn(d, vocab), dtype)
+        bv = min(block_v, vocab)
+        # a tile's first and last column, the next tile's first, V - 1
+        ends = [e for e in (0, bv - 1, bv, vocab - 1) if e < vocab]
+        t = rng.randint(0, vocab, n)
+        t[:len(ends)] = ends
+        t = jnp.asarray(t, jnp.int32)
+        got = pallas_kernels.head_logprob(x, w, t, block_t=block_t, block_v=block_v,
+                                          interpret=True)
+        want = self._plain(x, w, t)
+        assert got.shape == (n,) and got.dtype == jnp.float32
+        assert float(jnp.max(jnp.abs(x.astype(jnp.float32) @ w.astype(jnp.float32)))) > 80
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-6, atol=2e-5)
+
+    def test_no_logits_leave_the_kernel(self):
+        # one pallas_call; no (n, V) float32 array anywhere in the program
+        x, w = jnp.zeros((64, 32), jnp.bfloat16), jnp.zeros((32, 1000), jnp.bfloat16)
+        text = str(jax.make_jaxpr(functools.partial(
+            pallas_kernels.head_logprob, block_t=32, block_v=256, interpret=True))(
+                x, w, jnp.zeros((64,), jnp.int32)))
+        assert text.count("pallas_call") == 1 and "f32[64,1000]" not in text
+
+    @pytest.mark.parametrize("d,vocab,tiles", [
+        (2048, 200192, (1024, 2176)),  # trinity-mini: 200,192 = 92 x 2,176
+        (2048, 129280, (1024, 1280)),  # joyai-llm-flash: 101 x 1,280
+        (2048, 65536, (1024, 2048)),   # lfm2-8b-a1b: logits of a tile within 9 MiB
+        (4096, 32768, (1024, 1024)),   # nemotron's slice: a tile of w within 12 MiB
+        (6144, 15104, (1024, 1024)),   # no divisor from 1,024 on: masked
+        (64, 256, (1024, 256)),        # a small preset: one vocabulary tile
+    ])
+    def test_tiles_come_from_the_shape(self, d, vocab, tiles):
+        assert pallas_kernels.head_tiles(32768, d, vocab) == tiles

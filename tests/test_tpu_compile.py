@@ -657,3 +657,22 @@ def test_the_window_scoring_program_at_the_cells_block(one_chip):
     experts = sorted({l for l in labels if re.search(config["kernel_ops"]["moe_experts"], l)})
     assert len(experts) == 2 and all(l.startswith("ragged-dot-none") for l in experts)
     assert not re.findall(r"\b[sufc](?:64|128)\[", text)
+    # the head is one kernel named after its scope; its float32 logits
+    # exist nowhere in HBM: no chunk of (2,048, 200,192) and no flat relayout
+    head = sorted({l for l in labels if re.match(r"lm\.head(\.\d+)? ", l)})
+    assert len(head) == 1 and head[0].endswith("f32[32768,1]"), head
+    assert "f32[409993216]" not in text and "f32[2048,200192]" not in text
+
+
+@pytest.mark.parametrize("d,vocab", [(2048, 200192), (2048, 129280), (4096, 32768)])
+def test_head_kernel_at_the_cells_shapes(one_chip, d, vocab):
+    """`head_logprob` over one block of 32,768 tokens at its own tiles
+    (`head_tiles`: the VMEM limit it asks for is within the chip's): the
+    logits never take HBM, so the program's temporaries stay under 1 MiB."""
+    from tensorframes_tpu.ops.pallas_kernels import head_logprob
+
+    lowered, compiled = _compile(
+        head_logprob, one_chip, ((32768, d), jnp.bfloat16), ((d, vocab), jnp.bfloat16),
+        ((32768,), jnp.int32))
+    assert "tpu_custom_call" in lowered.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**20

@@ -99,8 +99,8 @@ import numpy as np
 from jax import lax
 
 from ..ops.pallas_kernels import (
-    band_pairs, flash_attention, head_logprob, index_scores, index_top_k, sparse_attention,
-    ssd_scan,
+    band_pairs, block_classes, flash_attention, head_logprob, index_scores, index_top_k,
+    sparse_attention, ssd_scan,
 )
 from . import moe
 
@@ -113,6 +113,7 @@ OPS = ("conv", "full_attention", "latent_attention", "ssm", "experts", "sparse_a
 ATTENTION = (1, 2)  # the operator kinds that attend to every earlier key
 SSM, EXPERTS, SPARSE = OPS.index("ssm"), OPS.index("experts"), OPS.index("sparse_attention")
 SLIDING = OPS.index("sliding_attention")
+FULL, LATENT = ATTENTION
 SWA_BLOCK = 1024  # the sliding window kernel's query and key blocks
 NO_FFN = -1  # a layer of one mixer: no FFN half
 PATTERN = {"M": "ssm", "*": "full_attention", "E": "experts"}
@@ -568,6 +569,22 @@ def _rope(x, theta, interleave: bool = False):
     return x * cos + jnp.concatenate([-x2, x1], axis=-1) * sin
 
 
+def _attention_block(op: int, seq: int) -> int:
+    """The query and key block of the attention kernel of a layer of kind
+    ``op`` over ``seq`` positions (`flash_attention` / `sparse_attention`
+    take it as their block; `score` counts the kernels' block pairs at it)."""
+    if op == SLIDING:
+        return SWA_BLOCK
+    if op == SPARSE:
+        return min(512, max(8, seq))
+    if op == LATENT:
+        # 1,024-blocks: at 32,768 positions the kernel's grid steps, not
+        # its matmuls, bound 512-blocks (a layer on a v5e: 151 -> 110 ms)
+        return min(1024, max(8, seq))
+    # 1,024-blocks from 16,384 positions on, as the latent kernel's
+    return min(1024 if seq >= 16384 else 512, max(8, seq))
+
+
 def _attention_op(config, p, u, interpret, window=None):
     """Grouped-query attention; with ``window`` a sliding layer: the last
     ``window`` keys, RoPE whatever ``rope`` says (it speaks of the full
@@ -594,8 +611,7 @@ def _attention_op(config, p, u, interpret, window=None):
                     interpret=interpret, window=window,
                 )
         else:
-            # 1,024-blocks from 16,384 positions on, as the latent kernel's
-            block = min(1024 if seq >= 16384 else 512, max(8, seq))
+            block = _attention_block(FULL, seq)
             att = flash_attention(
                 q.astype(dtype), k.astype(dtype), v.astype(dtype), causal=True,
                 scale=float(1.0 / np.sqrt(hd)), block_q=block, block_k=block,
@@ -617,9 +633,7 @@ def _latent_attention_op(config, p, u, interpret):
         dn, dr = int(config["qk_nope_head_dim"]), int(config["qk_rope_head_dim"])
         dtype = p["w_qa"].dtype
         _, q_n, q_r, kv, k_r = _latent_project(config, p, u)
-        # 1,024-blocks: at 32,768 positions the kernel's grid steps, not
-        # its matmuls, bound 512-blocks (my chip run, PR 33: 151 -> 110 ms)
-        block = min(1024, max(8, seq))
+        block = _attention_block(LATENT, seq)
         att = flash_attention(
             q_n, kv[..., :dn], kv[..., dn:], q2=q_r.astype(dtype), k2=k_r.astype(dtype),
             causal=True, scale=float(1.0 / np.sqrt(dn + dr)),
@@ -748,7 +762,7 @@ def _sparse_attention_op(config, p, index_p, u, carried, index_at, interpret):
         att = sparse_attention(
             q_n, kv[..., :dn], kv[..., dn:], carried[0], p.get("sink"),
             q2=q_r.astype(dtype), k2=k_r.astype(dtype), scale=float(1.0 / np.sqrt(dn + dr)),
-            block=min(512, max(8, seq)), interpret=interpret)
+            block=_attention_block(SPARSE, seq), interpret=interpret)
     att = jnp.swapaxes(att, 1, 2).reshape(rows, seq, -1)
     if "w_g" in p:
         with jax.named_scope("mla.gate"):
@@ -1095,7 +1109,11 @@ def score(fn: Callable, frame, params, config, **verb_args):
     under sliding-window attention ``lm.swa_pairs`` (Σ_t min(t + 1,
     sliding_window) x heads x sliding layers x rows) and ``lm.swa_blocks``
     (the (query block, key block) pairs the banded kernel computes, x heads
-    x sliding layers x rows: `band_pairs`);
+    x sliding layers x rows: `band_pairs`); ``lm.attention_inner_blocks``
+    and ``lm.attention_edge_blocks`` (the (query block, key block) pairs
+    the attention kernels compute without and with the positional mask, x
+    heads x rows, over the full, sliding, latent and sparse layers:
+    `block_classes`);
     under sparse attention ``lm.dsa_selected_pairs`` (Σ_t min(t + 1,
     index_topk) x heads x sparse layers x rows), ``lm.dsa_index_pairs``
     (causal pairs x index heads x ``full`` layers x rows),
@@ -1130,6 +1148,15 @@ def score(fn: Callable, frame, params, config, **verb_args):
               * int(np.isin(plan[:, 0], ATTENTION).sum())),
     )
     telemetry.counter_inc("lm.ssm_steps", float(tokens * int(np.sum(plan[:, 0] == SSM))))
+    classes = np.zeros(2)
+    for op in (FULL, LATENT, SLIDING, SPARSE):
+        layers, block = int(np.sum(plan[:, 0] == op)), _attention_block(op, seq)
+        if layers:
+            window = int(config["sliding_window"]) if op == SLIDING else None
+            classes += layers * np.array(block_classes(seq, block, block, window))
+    classes *= frame.nrows * int(config["num_attention_heads"])
+    for name, value in zip(("lm.attention_inner_blocks", "lm.attention_edge_blocks"), classes):
+        telemetry.counter_inc(name, float(value))
     n_swa = int(np.sum(plan[:, 0] == SLIDING))
     if n_swa:
         window, heads = int(config["sliding_window"]), int(config["num_attention_heads"])

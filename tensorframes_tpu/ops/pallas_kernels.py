@@ -6,7 +6,16 @@ Grid is (batch, heads, q_blocks, k_blocks); the k axis iterates
 sequentially (TPU grids run minor-axis-last), carrying the running max /
 denominator / weighted accumulator in VMEM scratch that persists across
 k iterations. Q·Kᵀ and P·V ride the MXU in the operands' own dtype with
-float32 accumulation; masking (causal + padded tail) happens on the VPU.
+float32 accumulation; masking (causal + padded tail) happens on the VPU,
+and only on the blocks that need it (`_edge`: a block crossing the
+diagonal or a band's edge, or holding padded keys); every other block
+runs a body with no iota, compare or select, which gives the same
+numbers. A causal call without a window has no grid step above the
+diagonal: its last grid axis walks the list of the (query block, key
+block) pairs it computes (`_visits`), query block by query block, from
+a scalar-prefetch table (on a v5e at 32,768 positions and 1,024-blocks
+the latent kernel's layer took 92.2 ms, 110.4 with every block masked on
+the full grid).
 A query head reads the key/value head of its group (grouped-query
 attention) through the block index, so nothing is repeated in memory.
 ``v`` may have a head width of its own (the output and the accumulator
@@ -31,7 +40,8 @@ Production CPU paths use `parallel.ring.full_attention`.
 selection mask (batch, seq, seq) int8 that keeps each query's chosen keys
 (one mask for all heads) and a sink logit a head that joins the softmax's
 denominator at the end (under the running max). Blocks above the diagonal
-are skipped; every other block is a dense pass masked by the selection.
+are never visited; every other block is a dense pass masked by the
+selection (and by the positions on the diagonal's blocks).
 
 `index_scores` (PR 40): a lightning indexer's scores of ONE block of
 queries against every key of the window, ``Σ_j w_j ReLU(q_j · k)`` over
@@ -77,8 +87,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-__all__ = ["flash_attention", "band_pairs", "ssd_scan", "sparse_attention", "index_scores",
-           "index_top_k", "head_logprob", "head_tiles"]
+__all__ = ["flash_attention", "band_pairs", "block_classes", "ssd_scan", "sparse_attention",
+           "index_scores", "index_top_k", "head_logprob", "head_tiles"]
 
 _NEG_INF = -1e30
 
@@ -92,54 +102,99 @@ def _band(i, blk_q: int, blk_k: int, window: int, nk: int):
     return first, last
 
 
-def _band_sizes(nq: int, blk_q: int, blk_k: int, window: int, nk: int):
-    """How many key blocks each query block's band holds (`_band`, on the
-    host); the banded grid's key axis takes the most of them."""
-    return [min((i * blk_q + blk_q - 1) // blk_k, nk - 1)
-            - max(i * blk_q - (window - 1), 0) // blk_k + 1 for i in range(nq)]
+def _edge(i, kb, *, blk_q: int, blk_k: int, seq_len: int, causal: bool,
+          window: Optional[int]):
+    """Whether key block ``kb`` holds a key that some query of query block
+    ``i`` does not see: a padded key, a key after a query (the block
+    crosses the diagonal) or a key ``window`` or more before one (it
+    crosses the band's lower edge). Such a block runs the masked body;
+    every other computed block is whole. Python ints on the host, int32
+    scalars in the kernel."""
+    edge = (kb + 1) * blk_k > seq_len
+    if causal:
+        edge = edge | ((kb + 1) * blk_k - 1 > i * blk_q)
+    if window is not None:
+        edge = edge | (kb * blk_k + window <= i * blk_q + (blk_q - 1))
+    return edge
+
+
+def _visits(seq: int, blk_q: int, blk_k: int, window: Optional[int]):
+    """The (query block, key block) pairs a causal call computes, query
+    block by query block, key blocks ascending: every block that holds a
+    key some query of the block sees."""
+    nq, nk = -(-seq // blk_q), -(-seq // blk_k)
+    first = lambda i: 0 if window is None else max(i * blk_q - (window - 1), 0) // blk_k
+    return [(i, kb) for i in range(nq)
+            for kb in range(first(i), min((i * blk_q + blk_q - 1) // blk_k, nk - 1) + 1)]
+
+
+def _blocks(seq: int, block_q: int, block_k: int):
+    """The query and key blocks the kernel takes at ``seq`` positions."""
+    return min(block_q, max(8, seq)), min(block_k, max(8, seq))
 
 
 def band_pairs(seq: int, block_q: int, block_k: int, window: int) -> int:
     """The (query block, key block) pairs `flash_attention` computes for
     one head over ``seq`` positions under ``window`` at these blocks."""
-    blk_q, blk_k = min(block_q, max(8, seq)), min(block_k, max(8, seq))
-    return sum(_band_sizes(-(-seq // blk_q), blk_q, blk_k, window, -(-seq // blk_k)))
+    return len(_visits(seq, *_blocks(seq, block_q, block_k), window))
+
+
+@functools.lru_cache(maxsize=64)
+def block_classes(seq: int, block_q: int, block_k: int,
+                  window: Optional[int] = None) -> tuple:
+    """(inner, edge): the (query block, key block) pairs a causal
+    `flash_attention` or `sparse_attention` computes for one head over
+    ``seq`` positions at these blocks (under ``window``, if given) without
+    the positional mask (every key seen by every query of the block) and
+    with it (`_edge`)."""
+    blk_q, blk_k = _blocks(seq, block_q, block_k)
+    visits = _visits(seq, blk_q, blk_k, window)
+    edge = sum(bool(_edge(i, kb, blk_q=blk_q, blk_k=blk_k, seq_len=seq, causal=True,
+                          window=window)) for i, kb in visits)
+    return len(visits) - edge, edge
 
 
 def _flash_kernel(
-    q_ref, k_ref, v_ref, *rest,
+    *refs,
     scale: float, causal: bool, seq_len: int, blk_q: int, blk_k: int,
     selected: bool = False, sink: bool = False, window: Optional[int] = None,
-    nk: int = 0,
+    nk: int = 0, listed: bool = False,
 ):
+    # ``listed``: the grid's last axis walks a list of (query block, key
+    # block) pairs, a scalar-prefetch table before the operands
+    if listed:
+        pairs_ref, *refs = refs
+    q_ref, k_ref, v_ref, *rest = refs
     # before the output: a second score part's two blocks, then (sparse
     # attention) the block of the selection mask and the head's sink logit
     rest, (o_ref, m_sc, l_sc, acc_sc) = list(rest[:-4]), rest[-4:]
     sink_ref = rest.pop() if sink else None
     sel_ref = rest.pop() if selected else None
     second = rest
-    i = pl.program_id(2)
-    j = pl.program_id(3)
 
-    @pl.when(j == 0)
+    needed = True
+    if listed:  # step p is pair p: its first and last key blocks open and close the row
+        p = pl.program_id(2)
+        i, kb = pairs_ref[2 * p], pairs_ref[2 * p + 1]
+        opens = kb == 0
+        closes = kb == jnp.minimum(jax.lax.div(i * blk_q + (blk_q - 1), jnp.int32(blk_k)),
+                                   nk - 1)
+    else:
+        i, j = pl.program_id(2), pl.program_id(3)
+        opens, closes = j == 0, j == pl.num_programs(3) - 1
+        kb = j
+        if window is not None:  # step j of the band visits its (first + j)-th block
+            first, last = _band(i, blk_q, blk_k, window, nk)
+            kb = first + j
+            needed = kb <= last
+
+    @pl.when(opens)
     def _init():
         m_sc[:] = jnp.full_like(m_sc, _NEG_INF)
         l_sc[:] = jnp.zeros_like(l_sc)
         acc_sc[:] = jnp.zeros_like(acc_sc)
 
-    if window is None:
-        kb = j
-        # Causal fast-skip: whole k-block strictly above the diagonal.
-        needed = jnp.logical_or(
-            not causal, j * blk_k <= i * blk_q + (blk_q - 1)
-        )
-    else:  # step j of the band visits its (first + j)-th block, up to its last
-        first, last = _band(i, blk_q, blk_k, window, nk)
-        kb = first + j
-        needed = kb <= last
-
-    @pl.when(needed)
-    def _step():
+    def step(masked: bool):
         # operands stay in their own dtype (bfloat16 rides the MXU at its
         # full rate); both products accumulate in float32
         q, k, v = q_ref[:], k_ref[:], v_ref[:]
@@ -154,22 +209,28 @@ def _flash_kernel(
             )
         s = s * scale
 
-        q_pos = i * blk_q + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
-        k_pos = kb * blk_k + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
-        mask = k_pos < seq_len  # padded tail keys contribute nothing
-        if causal:
-            mask = jnp.logical_and(mask, q_pos >= k_pos)
-        if window is not None:  # the band: the last `window` keys up to the query
-            mask = jnp.logical_and(mask, k_pos > q_pos - window)
-        if selected:  # only the keys the query's selection holds
-            mask = jnp.logical_and(mask, sel_ref[:] != 0)
-        # NB: f32-typed constants — x64-mode weak f64 literals trip Mosaic
-        s = jnp.where(mask, s, jnp.float32(_NEG_INF))
-
+        mask = None
+        if masked:  # an edge block: the positions say which keys each query sees
+            q_pos = i * blk_q + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
+            k_pos = kb * blk_k + jax.lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
+            mask = k_pos < seq_len  # padded tail keys contribute nothing
+            if causal:
+                mask = jnp.logical_and(mask, q_pos >= k_pos)
+            if window is not None:  # the band: the last `window` keys up to the query
+                mask = jnp.logical_and(mask, k_pos > q_pos - window)
+        if selected:  # only the keys the query's selection holds, on every block
+            chosen = sel_ref[:] != 0
+            mask = chosen if mask is None else jnp.logical_and(mask, chosen)
+        # the mask enters through the max and after the exponential, so
+        # both bodies compute ``exp(s - m_new)`` from one expression (a
+        # compiler that fuses the scale into the subtraction does so in both)
         m_prev = m_sc[:, 0]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
+        # NB: f32-typed constants — x64-mode weak f64 literals trip Mosaic
+        seen = s if mask is None else jnp.where(mask, s, jnp.float32(_NEG_INF))
+        m_new = jnp.maximum(m_prev, jnp.max(seen, axis=-1))
         p = jnp.exp(s - m_new[:, None])
-        p = jnp.where(mask, p, jnp.float32(0.0))
+        if mask is not None:  # an unseen key, also in a row with nothing seen yet
+            p = jnp.where(mask, p, jnp.float32(0.0))
         alpha = jnp.exp(m_prev - m_new)
         l_sc[:, 0] = alpha * l_sc[:, 0] + jnp.sum(p, axis=-1)
         acc_sc[:] = alpha[:, None] * acc_sc[:] + jnp.dot(
@@ -177,7 +238,14 @@ def _flash_kernel(
         )
         m_sc[:, 0] = m_new
 
-    @pl.when(j == pl.num_programs(3) - 1)
+    # a whole block runs the body without the positional mask; on a whole
+    # block the mask is all true, so both bodies give the same numbers
+    edge = _edge(i, kb, blk_q=blk_q, blk_k=blk_k, seq_len=seq_len, causal=causal,
+                 window=window)
+    pl.when(jnp.logical_and(needed, edge))(lambda: step(True))
+    pl.when(jnp.logical_and(needed, jnp.logical_not(edge)))(lambda: step(False))
+
+    @pl.when(closes)
     def _finish():
         l, acc = l_sc[:, 0], acc_sc[:]
         if sink:  # exp(sink) joins the denominator, under the same max
@@ -218,7 +286,7 @@ def flash_attention(
     query t attends to the keys s with ``t - window < s <= t``, the last
     ``window`` keys including its own, on a BANDED grid: a query block's
     key axis visits only the key blocks that meet its band
-    (`_band_sizes`), keys of a visited block outside the band are
+    (`_visits`), keys of a visited block outside the band are
     masked, and a block wholly outside it is never read."""
     if (q2 is None) != (k2 is None):
         raise ValueError("q2 and k2 are the two sides of one score part: give both")
@@ -256,8 +324,7 @@ def _flash_call(q, k, v, second, causal, scale, block_q, block_k, interpret,
     batch, heads, seq, d = q.shape
     dv = v.shape[-1]
 
-    blk_q = min(block_q, max(8, seq))
-    blk_k = min(block_k, max(8, seq))
+    blk_q, blk_k = _blocks(seq, block_q, block_k)
     pad_q = (-seq) % blk_q
     pad_k = (-seq) % blk_k
     pad = lambda a, n: jnp.pad(a, ((0, 0), (0, 0), (0, n), (0, 0))) if n else a
@@ -267,6 +334,9 @@ def _flash_call(q, k, v, second, causal, scale, block_q, block_k, interpret,
 
     from jax.experimental.pallas import tpu as pltpu
 
+    # a causal call without a window walks the list of the pairs it
+    # computes (`_visits`): no grid step above the diagonal
+    listed = causal and window is None
     kernel = functools.partial(
         _flash_kernel,
         scale=scale,
@@ -276,33 +346,46 @@ def _flash_call(q, k, v, second, causal, scale, block_q, block_k, interpret,
         blk_k=blk_k,
         selected=selection is not None,
         sink=sink is not None,
-        **({} if window is None else {"window": window, "nk": nk}),
+        window=window,
+        nk=nk,
+        listed=listed,
     )
 
-    def q_block(b, h, i, j):
-        return (b, h, i, jnp.int32(0))
+    # each grid step's (query block, key block), for the index maps
+    # (`lax.div`, not `//`: the operands are never negative, and Mosaic
+    # lowers an index map too: a floor division's sign handling does not
+    # lower under x64)
+    if listed:
+        # one table, (query block, key block) a step side by side: each
+        # scalar-prefetch operand's copy takes 16 KiB of a v5e program's
+        # temporaries
+        pairs = _visits(seq, blk_q, blk_k, None)
+        tables = [jnp.asarray(np.ravel(pairs), jnp.int32)]
+        grid = (batch, heads, len(pairs))
+        at = lambda b, h, p, table: (table[2 * p], table[2 * p + 1])
+    elif window is not None:
+        tables = []
+        # the key axis takes as many steps as the longest band holds blocks
+        bands = np.bincount([i for i, _ in _visits(seq, blk_q, blk_k, window)])
+        grid = (batch, heads, nq, int(bands.max()))
+
+        def at(b, h, i, j):
+            # the band's (first + j)-th block; past its last, the last
+            # again (already there): nothing new is read
+            first, last = _band(i, blk_q, blk_k, window, nk)
+            return i, jnp.minimum(first + j, last)
+    else:  # not causal: every block
+        tables = []
+        grid = (batch, heads, nq, nk)
+        at = lambda b, h, i, j: (i, j)
+
+    def q_block(b, h, *step):
+        return (b, h, at(b, h, *step)[0], jnp.int32(0))
 
     def key_block(keys):
         group = heads // keys.shape[1]
-
-        def block(b, h, i, j):
-            # `lax.div`, not `//`: the operands are never negative, and
-            # Mosaic lowers an index map too (a floor division's sign
-            # handling does not lower under x64)
-            if window is not None:
-                # the band's (first + j)-th block; past its last, the last
-                # again (already there), as the causal map does
-                first, last = _band(i, blk_q, blk_k, window, nk)
-                j = jnp.minimum(first + j, last)
-            elif causal:
-                # a block above the diagonal is skipped: ask for the last
-                # one needed again, which is already there, not for a new one
-                j = jnp.minimum(
-                    j, jax.lax.div(i * blk_q + (blk_q - 1), jnp.int32(blk_k))
-                )
-            return (b, jax.lax.div(h, jnp.int32(group)), j, jnp.int32(0))
-
-        return block
+        return lambda b, h, *step: (b, jax.lax.div(h, jnp.int32(group)),
+                                    at(b, h, *step)[1], jnp.int32(0))
 
     operands = [qp, kp, vp]
     in_specs = [
@@ -321,28 +404,29 @@ def _flash_call(q, k, v, second, causal, scale, block_q, block_k, interpret,
     if selection is not None:  # (batch, seq, seq): one mask for every head
         operands.append(jnp.pad(selection, ((0, 0), (0, pad_q), (0, pad_k)))
                         if pad_q or pad_k else selection)
-        at = key_block(kp)
         in_specs.append(pl.BlockSpec(
-            (None, blk_q, blk_k), lambda b, h, i, j: (b, i, at(b, h, i, j)[2])))
+            (None, blk_q, blk_k), lambda b, h, *step: (b, *at(b, h, *step))))
     if sink is not None:  # a logit a head
         operands.append(sink.astype(jnp.float32).reshape(heads, 1, 1))
         in_specs.append(pl.BlockSpec(
-            (None, 1, 1), lambda b, h, i, j: (h, jnp.int32(0), jnp.int32(0))))
+            (None, 1, 1), lambda b, h, *step: (h, jnp.int32(0), jnp.int32(0))))
 
     out = pl.pallas_call(
         kernel,
-        grid=(batch, heads, nq,
-              nk if window is None else max(_band_sizes(nq, blk_q, blk_k, window, nk))),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((None, None, blk_q, dv), q_block),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=len(tables),
+            grid=grid,
+            in_specs=in_specs,
+            out_specs=pl.BlockSpec((None, None, blk_q, dv), q_block),
+            scratch_shapes=[
+                pltpu.VMEM((blk_q, 1), jnp.float32),  # running max
+                pltpu.VMEM((blk_q, 1), jnp.float32),  # running denominator
+                pltpu.VMEM((blk_q, dv), jnp.float32),  # weighted accumulator
+            ],
+        ),
         out_shape=jax.ShapeDtypeStruct(qp.shape[:-1] + (dv,), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((blk_q, 1), jnp.float32),  # running max
-            pltpu.VMEM((blk_q, 1), jnp.float32),  # running denominator
-            pltpu.VMEM((blk_q, dv), jnp.float32),  # weighted accumulator
-        ],
         interpret=interpret,
-    )(*operands)
+    )(*tables, *operands)
     return out[:, :, :seq] if pad_q else out
 
 
@@ -396,8 +480,8 @@ def sparse_attention(
     key s is in query t's set (one mask for every head), and ``sink``
     (heads,) a logit a head in the softmax's denominator: ``p = exp(z) /
     (exp(sink) + Σ_selected exp(z))``. Blocks of keys above the diagonal
-    hold no selected key and are skipped; every other block is a dense
-    pass masked by the selection. No backward pass (scoring)."""
+    hold no selected key and are never visited; every other block is a
+    dense pass masked by the selection. No backward pass (scoring)."""
     return _flash_call(
         q, k, v, (q2, k2), True, float(scale), block, block, bool(interpret),
         selection=selection, sink=sink)

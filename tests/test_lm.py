@@ -406,3 +406,44 @@ def test_map_rows_fn_takes_the_shape_policy():
     )
     counters = tele.flat_counters()
     assert counters["shape_bucketing.pad_rows"] == 2 * (16 - 9)
+
+
+@pytest.mark.parametrize("seq", [64, 2100])
+@pytest.mark.parametrize("name", ["lfm2-8b-a1b", "joyai-llm-flash", "nemotron-3-super-120b-a12b",
+                                  "hy4-preview", "trinity-mini"])
+def test_the_attention_kernels_block_classes_are_counted(name, seq):
+    # `lm.score` books, from the frame's shape before the dispatch, the
+    # (query block, key block) pairs the attention kernels compute without
+    # and with the positional mask: `block_classes` at each layer's kernel
+    # block x heads x layers x rows, over the full, sliding, latent and
+    # sparse layers of each family's small preset. The counters read no
+    # output, so a stand-in function keeps 2,100-token rows cheap
+    import json
+
+    from perf.runners.map_blocks_lm import model_config
+    from tensorframes_tpu.ops import pallas_kernels
+
+    with open(os.path.join(ROOT, "perf", "configs", name + ".json")) as f:
+        cfg = lm.family_keys(model_config(json.load(f), True))
+    blocks = {"full_attention": min(512, seq), "latent_attention": min(1024, seq),
+              "sliding_attention": min(1024, seq), "sparse_attention": min(512, seq)}
+    want = np.zeros(2)
+    for op in lm.layer_plan(cfg)[:, 0]:
+        kind = lm.OPS[op]
+        if kind in blocks:
+            window = cfg["sliding_window"] if kind == "sliding_attention" else None
+            want += pallas_kernels.block_classes(seq, blocks[kind], blocks[kind], window)
+    assert want[1] > 0 and (seq == 64 or want[0] > 0)
+    rows = 2
+    frame = tfs.TensorFrame([tfs.Column("tokens", jnp.zeros((rows, seq), jnp.int32))], [0, rows])
+    params = {"moe": {"w_up": np.zeros((1, int(cfg["num_experts"]), 1), np.float32)}}
+    before = dict(tele.flat_counters())
+    lm.score(lambda tokens, params: {"token_logprob": tokens.astype(jnp.float32)},
+             frame, params, cfg)
+    counters = tele.flat_counters()
+    got = [counters[k] - before.get(k, 0)
+           for k in ("lm.attention_inner_blocks", "lm.attention_edge_blocks")]
+    np.testing.assert_array_equal(got, want * int(cfg["num_attention_heads"]) * rows)
+    model = tfs.diagnostics(format="json")["model"]
+    assert model["lm.attention_inner_blocks"] == counters["lm.attention_inner_blocks"]
+    assert model["lm.attention_edge_blocks"] == counters["lm.attention_edge_blocks"]

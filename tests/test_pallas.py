@@ -143,17 +143,22 @@ class TestFlashAttention:
             np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=2e-4, atol=2e-5)
 
     @staticmethod
-    def _grid(fn, *args):
-        """The grid of the one `pallas_call` in ``fn``'s jaxpr."""
+    def _pallas_call(fn, *args):
+        """The one `pallas_call` equation in ``fn``'s jaxpr."""
         def calls(jaxpr):
             for eqn in jaxpr.eqns:
                 if eqn.primitive.name == "pallas_call":
-                    yield eqn.params["grid_mapping"].grid
+                    yield eqn
                 for sub in jax.core.jaxprs_in_params(eqn.params):
                     yield from calls(sub)
 
-        (grid,) = calls(jax.make_jaxpr(fn)(*args).jaxpr)
-        return grid
+        (call,) = calls(jax.make_jaxpr(fn)(*args).jaxpr)
+        return call
+
+    @classmethod
+    def _grid(cls, fn, *args):
+        """The grid of the one `pallas_call` in ``fn``'s jaxpr."""
+        return cls._pallas_call(fn, *args).params["grid_mapping"].grid
 
     @pytest.mark.parametrize("group", [1, 8])
     @pytest.mark.parametrize("blocks", [(32, 32), (24, 40)])
@@ -189,28 +194,164 @@ class TestFlashAttention:
         elif window < 100:
             assert band < nk  # blocks wholly outside the band are never visited
 
-    def test_without_a_window_the_kernel_traces_as_it_did(self):
-        # `window=None` is the kernel as it was: its jaxpr (the kernel's
-        # source location aside) hashes to what it hashed to before the
-        # window came, for a causal and a plain call
-        import hashlib
-        import re
+    @classmethod
+    def _step_bodies(cls, fn, *args):
+        """The two step bodies in the kernel of the one `pallas_call` in
+        ``fn``'s jaxpr, (masked, whole): the branches that take the dot
+        products, each as the primitive names it holds (with their sub-jaxprs')."""
+        def names(jaxpr):
+            for eqn in jaxpr.eqns:
+                yield eqn.primitive.name
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    yield from names(sub)
 
+        kernel = cls._pallas_call(fn, *args).params["jaxpr"]
+        bodies = [list(names(branch.jaxpr)) for eqn in kernel.eqns if eqn.primitive.name == "cond"
+                  for branch in eqn.params["branches"]]
+        bodies = [b for b in bodies if "dot_general" in b]
+        assert len(bodies) == 2
+        return sorted(bodies, key=lambda b: "iota" not in b)
+
+    def test_without_a_window_the_kernel_traces_as_it_did(self):
+        # a whole block's body holds no iota and no select: only a block
+        # that needs the positional mask (the diagonal's, a band's edge,
+        # the padded tail) builds it, in a causal, a plain and a windowed
+        # call; under sparse attention the selection's two selects stay in
+        # both bodies
         q = jnp.zeros((1, 8, 150, 16), jnp.float32)
         kv = jnp.zeros((1, 1, 150, 16), jnp.float32)
-        pinned = {
-            True: "ef5637e0f1708ad653c95ecb38304188551da4aa4b0de39acd319503fe78b770",
-            False: "a1f3b3e0fe916e69f852c1ea43de539ffcbbad93380df1957bcdd4c995b917cc",
-        }
-        for causal, digest in pinned.items():
-            text = str(jax.make_jaxpr(functools.partial(
-                flash_attention, causal=causal, block_q=32, block_k=32, window=None))(q, kv, kv))
-            text = re.sub(r"name_and_src_info=\S+ at \S+", "", text)
-            assert hashlib.sha256(text.encode()).hexdigest() == digest, causal
+        sparse = lambda q, k, v: pallas_kernels.sparse_attention(
+            q, k, v, jnp.ones((1, 150, 150), jnp.int8), None, q2=q, k2=k, scale=0.25,
+            block=32, interpret=True)
+        for fn, selects in (
+                (functools.partial(flash_attention, causal=True, block_q=32, block_k=32), 0),
+                (functools.partial(flash_attention, block_q=32, block_k=32), 0),
+                (functools.partial(flash_attention, causal=True, block_q=32, block_k=32,
+                                   window=64), 0),
+                (sparse, 2)):
+            masked, whole = self._step_bodies(fn, q, kv, kv)
+            assert "iota" in masked and masked.count("select_n") == 2
+            assert "iota" not in whole and whole.count("select_n") == selects
         with pytest.raises(ValueError, match="causal band"):
             flash_attention(q, kv, kv, window=4)
         with pytest.raises(ValueError, match="causal band"):
             flash_attention(q, kv, kv, causal=True, window=0)
+
+    @staticmethod
+    def _every_block_masked(monkeypatch):
+        """The kernel as it was: every computed block takes the masked body
+        (the block classifier answers "edge" for all), in the same order.
+        Returns the list of the classifier's calls."""
+        calls = []
+
+        def edge(*args, **kwargs):
+            calls.append(args)
+            return True
+
+        monkeypatch.setattr(pallas_kernels, "_edge", edge)
+        return calls
+
+    @pytest.mark.parametrize("causal,window,seq,blocks", [
+        (True, None, 128, (32, 32)),   # blocks that divide the sequence
+        (True, None, 150, (32, 32)),   # a padded tail
+        (True, None, 128, (16, 32)),   # query blocks narrower than key blocks
+        (True, None, 150, (24, 40)),
+        (True, None, 150, (40, 24)),   # and wider
+        (False, None, 128, (32, 32)),  # a plain call
+        (False, None, 150, (24, 40)),
+        (True, 64, 128, (32, 32)),     # band edges on block boundaries
+        (True, 80, 150, (24, 40)),
+        (True, 80, 150, (32, 32)),     # band edges inside blocks
+        (True, 100, 150, (40, 24)),
+    ])
+    def test_a_whole_block_runs_unmasked_to_the_same_numbers(
+            self, monkeypatch, causal, window, seq, blocks):
+        # grouped-query heads (4 over 2) and a second score part (one key
+        # head for all): against plain attention, and bit for bit against
+        # every block masked in the same block order
+        block_q, block_k = blocks
+        rng = np.random.RandomState(seq + block_q + (window or 0))
+        arr = lambda *shape: jnp.asarray(rng.randn(*shape), jnp.float32)
+        q, k, v, q2, k2 = (arr(1, 4, seq, 16), arr(1, 2, seq, 16), arr(1, 2, seq, 24),
+                           arr(1, 4, seq, 8), arr(1, 1, seq, 8))
+        call = lambda: np.asarray(flash_attention(
+            q, k, v, q2=q2, k2=k2, causal=causal, block_q=block_q, block_k=block_k,
+            window=window))
+        out = call()
+        t = np.arange(seq)[:, None] - np.arange(seq)[None, :]
+        seen = np.ones_like(t, bool) if not causal else (t >= 0) & (t < (window or seq))
+        qq = jnp.concatenate([q, q2], -1)
+        kk = jnp.concatenate([jnp.repeat(k, 2, 1), jnp.repeat(k2, 4, 1)], -1)
+        s = jnp.einsum("bhqd,bhkd->bhqk", qq, kk, precision="highest") / np.sqrt(24)
+        s = jnp.where(jnp.asarray(seen), s, -jnp.inf)
+        want = jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, -1), jnp.repeat(v, 2, 1),
+                          precision="highest")
+        np.testing.assert_allclose(out, np.asarray(want), rtol=2e-5, atol=2e-6)
+        if causal:  # the case holds whole blocks, and edge blocks
+            assert min(pallas_kernels.block_classes(seq, block_q, block_k, window)) > 0
+        classified = self._every_block_masked(monkeypatch)
+        np.testing.assert_array_equal(out, call())
+        assert classified
+
+    @pytest.mark.parametrize("block", [16, 32])
+    def test_a_sparse_whole_block_keeps_the_selection(self, monkeypatch, block):
+        # queries 40-63 select nothing in their first key blocks (keys
+        # 0-31), so their rows start with nothing seen: against the plain
+        # selected softmax, and bit for bit against every block masked
+        rng = np.random.RandomState(block)
+        f = lambda *shape: jnp.asarray(rng.randn(*shape), jnp.float32)
+        q, k, v, q2, k2 = (f(2, 4, 64, 16), f(2, 4, 64, 16), f(2, 4, 64, 8), f(2, 4, 64, 8),
+                           f(2, 1, 64, 8))
+        chosen = ((rng.rand(2, 64, 64) < 0.3) | np.eye(64, dtype=bool)) & np.tril(
+            np.ones((64, 64), bool))
+        chosen[:, 40:, :32] = False
+        sink = f(4)
+        call = lambda: np.asarray(pallas_kernels.sparse_attention(
+            q, k, v, jnp.asarray(chosen, jnp.int8), sink, q2=q2, k2=k2, scale=0.2,
+            block=block, interpret=True))
+        out = call()
+        z = 0.2 * (np.einsum("rhtd,rhsd->rhts", q, k) + np.einsum("rhtd,rsd->rhts", q2, k2[:, 0]))
+        z = np.where(chosen[:, None], z, -np.inf)
+        top = np.maximum(z.max(-1, keepdims=True), np.asarray(sink)[None, :, None, None])
+        e = np.exp(z - top)
+        want = np.einsum("rhts,rhsd->rhtd", e / (e.sum(-1, keepdims=True) + np.exp(
+            np.asarray(sink)[None, :, None, None] - top)), v)
+        np.testing.assert_allclose(out, want, rtol=1e-4, atol=1e-5)
+        assert pallas_kernels.block_classes(64, block, block)[0] > 0
+        classified = self._every_block_masked(monkeypatch)
+        np.testing.assert_array_equal(out, call())
+        assert classified
+
+    @pytest.mark.parametrize("window", [None, 1, 24, 64, 100, 1000])
+    @pytest.mark.parametrize("seq,blocks", [(128, (32, 32)), (150, (32, 32)),
+                                            (150, (24, 40)), (150, (40, 24))])
+    def test_block_classes_are_the_positions_and_the_list_is_the_visited_blocks(
+            self, seq, blocks, window):
+        # each (query block, key block) pair by the positions it holds (the
+        # kernel's query rows, padded ones included; keys past the
+        # sequence unseen): empty (no key seen), inner (every key seen by
+        # every query) or edge; a causal call without a window walks the
+        # non-empty pairs, query block by query block, key blocks ascending
+        block_q, block_k = blocks
+        nq, nk = -(-seq // block_q), -(-seq // block_k)
+        t = np.arange(nq * block_q)[:, None]
+        s = np.arange(nk * block_k)[None, :]
+        seen = (s <= t) & (s > t - (window or nq * block_q)) & (s < seq)
+        tiles = seen.reshape(nq, block_q, nk, block_k).transpose(0, 2, 1, 3)
+        visited = [(i, j) for i in range(nq) for j in range(nk) if tiles[i, j].any()]
+        inner = sum(tiles[i, j].all() for i, j in visited)
+        assert pallas_kernels.block_classes(seq, block_q, block_k, window) == (
+            inner, len(visited) - inner)
+        assert pallas_kernels._visits(seq, block_q, block_k, window) == visited
+        if window is None:
+            q = jnp.zeros((1, 2, seq, 8), jnp.float32)
+            closed = jax.make_jaxpr(functools.partial(
+                flash_attention, causal=True, block_q=block_q, block_k=block_k))(q, q, q)
+            (table,) = [np.asarray(c) for c in closed.consts if np.ndim(c) == 1]
+            np.testing.assert_array_equal(table.reshape(-1, 2), np.asarray(visited))
+            assert self._grid(lambda a: flash_attention(
+                a, a, a, causal=True, block_q=block_q, block_k=block_k), q) == (
+                1, 2, len(visited))
 
     def test_grad_through_a_window_is_the_plain_forms(self):
         q, k, v = (jnp.asarray(np.random.RandomState(s).randn(1, 2, 24, 8), jnp.float32)

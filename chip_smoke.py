@@ -7,8 +7,9 @@
                                          # success line
 
 Drives the system's main path once through the entry points a user
-calls (`import tensorframes_tpu as tfs`): the five verbs at the sizes
-`BASELINE.md` tracks, a frozen Inception-v3 GraphDef scored at 299 px, a
+calls (`import tensorframes_tpu as tfs`): the verbs at the sizes
+`BASELINE.md` tracks (the x + 3 chain and the `map_rows` MLP are cells of
+the benchmark, `perf/`), a frozen Inception-v3 GraphDef scored at 299 px, a
 `tfs.serving` endpoint under concurrent clients, a relational plan over
 parquet shards, and the Pallas attention kernel with three
 `TransformerLM.train_step`s. Every result is compared with a numpy (or
@@ -37,13 +38,13 @@ import numpy as np
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 
-# Real sizes: BASELINE.md's tracked configs and bench.py's chip sizes.
+# Real sizes: BASELINE.md's tracked configs.
 REAL = dict(
-    map_rows=200_000_000, map_chain=5,
+    map_chain=5,
     red_blocks=16, red_block_rows=4_194_304, red_dim=4,
     stream_chunks=4, stream_chunk_rows=16_777_216,
     agg_rows=10_000_000, agg_dim=8, agg_keys=(16, 10_000),
-    mlp_rows=1_000_000, mlp_sizes=(512, 512, 512, 10), mlp_check=1024,
+    mlp_sizes=(512, 512, 512, 10),
     inception_hw=299, inception_images=64,
     serve_clients=8, serve_rows=2048,
     plan_shards=4, plan_shard_rows=1_000_000, plan_groups=8,
@@ -52,11 +53,11 @@ REAL = dict(
 )
 # Rehearsal (guide §2 step 1): same control flow, tiny data.
 TINY = dict(
-    map_rows=10_000, map_chain=5,
+    map_chain=5,
     red_blocks=4, red_block_rows=1_024, red_dim=4,
     stream_chunks=4, stream_chunk_rows=2_048,
     agg_rows=20_000, agg_dim=8, agg_keys=(16, 300),
-    mlp_rows=4_096, mlp_sizes=(32, 32, 32, 10), mlp_check=1024,
+    mlp_sizes=(32, 32, 32, 10),
     inception_hw=75, inception_images=4,
     serve_clients=8, serve_rows=64,
     plan_shards=4, plan_shard_rows=4_096, plan_groups=8,
@@ -74,7 +75,7 @@ def _freeze_child(out_dir: str, hw: int, images: int, seed: int) -> int:
     """Freeze the seeded Keras Inception-v3, score seeded images with the
     TF session, leave graph bytes + images + scores under ``out_dir``."""
     sys.path.insert(0, HERE)
-    from benchmarks._util import freeze_keras_inception_v3
+    from tensorframes_tpu.testing.keras_freeze import freeze_keras_inception_v3
 
     wire, in_node, out_node, score = freeze_keras_inception_v3(hw)
     data = np.random.RandomState(seed).rand(images, hw, hw, 3).astype(np.float32)
@@ -207,42 +208,6 @@ def phase_a_readme(run: Run):
         assert zv.dtype == np.float64
         np.testing.assert_array_equal(np.asarray(zv), [4.0, 5.0, 6.0])
         assert float(total) == 6.0
-
-
-def phase_b_map_chain(run: Run):
-    """Chained x+3 `map_blocks` on a device-resident float32 frame."""
-    tfs, jax, sz = run.tfs, run.jax, run.size
-    from tensorframes_tpu.frame import Column
-    from tensorframes_tpu.shape_policy import bucket_for
-    from tensorframes_tpu.utils import telemetry
-
-    n, chain = sz["map_rows"], sz["map_chain"]
-    with run.phase("b_map_chain") as info:
-        # 1 at row 1 like bench.py's check, small integers elsewhere:
-        # every value of the chain is exact in float32
-        host = (np.arange(n, dtype=np.int64) % 1024).astype(np.float32)
-        df = tfs.TensorFrame.from_dict({"x": host}).to_device()
-        z = (tfs.block(df, "x") + 3.0).named("z")
-        cur = df
-        for _ in range(chain):
-            out = tfs.map_blocks(z, cur)
-            cur = tfs.TensorFrame([Column("x", out["z"].values)])
-            # One call in flight at a time. Left to run ahead, the host
-            # enqueues all five before the first ends, and each holds its
-            # pad copy, padded output and slice (2.9 GB at 200M rows):
-            # the chip's run of that peaked at 15.75 of 16 GiB
-            # (CHANGES.md PR 22) — too near the limit for a smoke check.
-            jax.block_until_ready(cur["x"].values)
-        final = cur["x"].values
-        info["devices"] = run.on_device(final)
-        got = np.asarray(final)
-        assert got[1] == 1.0 + 3.0 * chain
-        np.testing.assert_array_equal(got, host + np.float32(3.0 * chain))
-        info["rows"] = n
-        info["bucket_rows"] = bucket_for(n)
-        info["shape_bucketing.pad_rows"] = telemetry.flat_counters().get(
-            "shape_bucketing.pad_rows", 0
-        )
 
 
 def _sum_min_fetches(tfs, df, col):
@@ -402,45 +367,6 @@ def phase_d_aggregate(run: Run):
             info["rows"], info["keys"] = rows, nkeys
 
 
-def _numpy_mlp(model, x):
-    """Plain numpy forward of `models.MLP` (relu hidden, softmax out)."""
-    h = x.astype(np.float64)
-    params = [(np.asarray(w, np.float64), np.asarray(b, np.float64))
-              for w, b in model.params]
-    for i, (w, b) in enumerate(params):
-        h = h @ w + b
-        if i < len(params) - 1:
-            h = np.maximum(h, 0.0)
-    e = np.exp(h - h.max(axis=1, keepdims=True))
-    return e / e.sum(axis=1, keepdims=True)
-
-
-def phase_e_map_rows_mlp(run: Run):
-    """`map_rows` MLP inference (BASELINE config 3)."""
-    tfs, jax, sz = run.tfs, run.jax, run.size
-    from tensorframes_tpu.models import MLP
-
-    rows, sizes, check = sz["mlp_rows"], sz["mlp_sizes"], sz["mlp_check"]
-    with run.phase("e_map_rows_mlp") as info:
-        data = run.rng(5).rand(rows, sizes[0]).astype(np.float32)
-        df = tfs.TensorFrame.from_dict({"features": data}).to_device()
-        model = MLP(list(sizes), seed=run.seed)
-        out = tfs.map_rows(model.scoring_graph("features", block=False), df)
-        probs = out["probs"].values
-        jax.block_until_ready(probs)
-        info["devices"] = run.on_device(probs)
-        assert probs.shape == (rows, sizes[-1])
-        got = np.asarray(probs[:check])
-        np.testing.assert_allclose(
-            got, _numpy_mlp(model, data[:check]), rtol=1e-4, atol=1e-6
-        )
-        tail = np.asarray(probs[-check:])  # the far end of the block too
-        np.testing.assert_allclose(
-            tail, _numpy_mlp(model, data[-check:]), rtol=1e-4, atol=1e-6
-        )
-        info["rows"], info["rows_checked"] = rows, 2 * check
-
-
 # the tolerance of tests/test_foreign_graphdef.py; it held on the chip
 # (max abs error 1.4e-9, CHANGES.md PR 22), so no wider one is offered
 INCEPTION_RTOL, INCEPTION_ATOL = 1e-4, 1e-5
@@ -532,9 +458,8 @@ def phase_g_serving(run: Run):
     """`tfs.serving` behind the HTTP front-end, concurrent clients. Two
     endpoints, because the batcher coalesces only graphs its row-local
     walk can prove (`aggregate._ROWWISE_OPS` has no MatMul): the MLP
-    scoring graph is served per request, and an elementwise scorer (the
-    graph of `benchmarks/serving_bench.py`) shows the micro-batcher
-    coalescing on the device."""
+    scoring graph is served per request, and an elementwise scorer shows
+    the micro-batcher coalescing on the device."""
     tfs, sz = run.tfs, run.size
     import socket
 
@@ -572,12 +497,11 @@ def phase_g_serving(run: Run):
             info["mlp_bit_identical_to_direct_map_blocks"] = ident
             info["mlp_max_abs_diff"] = worst
             before = tfs.serving.batcher().snapshot()
-            # Sizes just off a bucket rung, as in serving_bench.py: a
-            # batch that lands exactly on a rung closes at once by design
-            # (2,048 rows is one). And a window wider than the default
-            # 5 ms: eight Python client threads reach the server tens of
-            # ms apart, and this phase shows coalescing on the device,
-            # not a latency.
+            # Sizes just off a bucket rung: a batch that lands exactly
+            # on a rung closes at once by design (2,048 rows is one).
+            # And a window wider than the default 5 ms: eight Python
+            # client threads reach the server tens of ms apart, and this
+            # phase shows coalescing on the device, not a latency.
             with tfs.config.override(serve_batch_window_ms=250.0):
                 ident, worst = _serve_round(
                     run, handle.url, "affine", affine,
@@ -903,10 +827,8 @@ def phase_multichip(run: Run, ndev: int):
 
 ONE_CHIP_PHASES = {
     "a": phase_a_readme,
-    "b": phase_b_map_chain,
     "c": phase_c_reduce,
     "d": phase_d_aggregate,
-    "e": phase_e_map_rows_mlp,
     "f": phase_f_inception,
     "g": phase_g_serving,
     "h": phase_h_plan,

@@ -19,8 +19,7 @@ run:
 
 The streamed end-to-end number then has context: perfect overlap gives
 wall ~ rows / min(on_chip, ingest); the gap from that bound is the
-pipeline's own overhead (`stream_overlap_bench.py` measures the overlap
-efficiency directly).
+pipeline's own overhead.
 """
 
 import os

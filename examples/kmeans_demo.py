@@ -9,8 +9,8 @@ when one is given, and the combine is a tiny host sum of (k, dim+1)
 partials instead of a Spark treeAggregate.
 
 The reference demo ends with a timing comparison against MLlib KMeans;
-here the comparison baseline is a host-NumPy Lloyd loop
-(`benchmarks/kmeans_bench.py` records it as JSON).
+this one prints its own wall time, which on a CPU host is no device
+figure.
 """
 
 import os

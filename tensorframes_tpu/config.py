@@ -313,9 +313,10 @@ class Config:
     # readers run shard discovery -> parallel decode -> H2D transfer ->
     # compute as concurrently-executing stages over bounded queues.
     # Off = stage-serial: the SAME stage functions run inline on the
-    # consumer thread (no overlap) — the A/B baseline
-    # benchmarks/ingest_bench.py measures against, and an escape hatch
-    # for single-core hosts where pipeline threads only add overhead.
+    # consumer thread (no overlap) — the stage-serial reference
+    # tests/test_ingest.py compares the pipeline's results against, and
+    # an escape hatch for single-core hosts where pipeline threads only
+    # add overhead.
     # Env override TFS_INGEST_PIPELINE ("0" disables) seeds the initial
     # value, mirroring TFS_SHAPE_BUCKETING.
     ingest_pipeline: bool = dataclasses.field(
@@ -342,10 +343,8 @@ class Config:
     # reduce given checkpoint= without an explicit checkpoint_every=
     # atomically commits its manifest + partial table after this many
     # FOLDED chunks (empty chunks advance the watermark but do not
-    # count as folds). Lower = tighter recovery point, more fsyncs;
-    # the checkpoint bench asserts the default's commit overhead stays
-    # <= 5% of stream wall time. Env override
-    # TFS_STREAM_CHECKPOINT_EVERY seeds the initial value.
+    # count as folds). Lower = tighter recovery point, more fsyncs.
+    # Env override TFS_STREAM_CHECKPOINT_EVERY seeds the initial value.
     stream_checkpoint_every: int = dataclasses.field(
         default_factory=lambda: _env_int(
             "TFS_STREAM_CHECKPOINT_EVERY", 16, "stream_checkpoint_every",
@@ -358,9 +357,9 @@ class Config:
     # consumer thread dispatches block k, so H2D transfer overlaps
     # compute across the plan's blocks. Off = the historical
     # block-serial loop (prep and dispatch interleaved on one thread) —
-    # the A/B baseline benchmarks/plan_pipeline_bench.py measures
-    # against, and the single-core escape hatch. Env override
-    # TFS_PLAN_PIPELINE ("0" disables) seeds the initial value.
+    # the reference tests/test_materialize.py compares the pipelined
+    # loop's results against, and the single-core escape hatch. Env
+    # override TFS_PLAN_PIPELINE ("0" disables) seeds the initial value.
     plan_pipeline: bool = dataclasses.field(
         default_factory=lambda: _env_bool(
             "TFS_PLAN_PIPELINE", True, "plan_pipeline"
@@ -385,9 +384,10 @@ class Config:
     # map fusion across relational boundaries. Every rewrite is priced
     # against the cost ledger's residuals-corrected throughput and
     # accepted only when the modeled plan cost strictly drops; off =
-    # execute the verbs exactly as written (the A/B baseline
-    # benchmarks/relational_bench.py measures against). Env override
-    # TFS_PLAN_OPTIMIZER ("0" disables) seeds the initial value.
+    # execute the verbs exactly as written (the reference
+    # tests/test_plan.py compares the rewritten plan's results and
+    # decode counts against). Env override TFS_PLAN_OPTIMIZER ("0"
+    # disables) seeds the initial value.
     plan_optimizer: bool = dataclasses.field(
         default_factory=lambda: _env_bool(
             "TFS_PLAN_OPTIMIZER", True, "plan_optimizer"
@@ -756,8 +756,7 @@ class Config:
     # EARLY the moment its row total lands exactly on a bucket-ladder
     # rung (padding waste zero — waiting longer could only push it to
     # the next rung) or reaches serve_max_batch_rows. 0 disables
-    # coalescing entirely: every request dispatches alone (the A/B
-    # baseline serving_bench measures against). Env override
+    # coalescing entirely: every request dispatches alone. Env override
     # TFS_SERVE_BATCH_WINDOW_MS seeds the initial value.
     serve_batch_window_ms: float = dataclasses.field(
         default_factory=lambda: _env_float(
@@ -768,7 +767,7 @@ class Config:
     # top of the bucket ladder `serving.register(warm=True)` compiles
     # at registration — requests whose batches stay under it hit only
     # warmed rungs (zero steady-state compiles, asserted by
-    # serving_bench). A single oversized request still dispatches
+    # tests/test_serving.py). A single oversized request still dispatches
     # (alone), paying its own compile. Env override
     # TFS_SERVE_MAX_BATCH_ROWS seeds the initial value.
     serve_max_batch_rows: int = dataclasses.field(
@@ -983,8 +982,8 @@ def enable_compilation_cache() -> str:
     cache is ``<checkout>/.jax_cache``, resolved from this package's own
     location — a fixed path, because the path is part of the cache key
     (a temp name, pid or time would never hit). Entry points
-    (`chip_smoke.py`, `bench.py`, `benchmarks/run_all.py`) call this at
-    start-up; importing the package never does."""
+    (`chip_smoke.py`, `perf/run.py`) call this at start-up; importing
+    the package never does."""
     import os
 
     import jax

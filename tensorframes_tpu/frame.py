@@ -414,8 +414,8 @@ class TensorFrame:
         block (`shape_policy.bucket_for`) with ``config.shape_bucketing``
         on, the raw `block_sizes` with it off — so ``len(set(...))`` is
         an honest compiled-shape budget either way (the introspection
-        surface `benchmarks/bucketing_bench.py` and the bucketing tests
-        assert against). Empty blocks map to 0 (never dispatched).
+        surface the bucketing tests assert against). Empty blocks map
+        to 0 (never dispatched).
         Per-dispatch eligibility (non-row-local maps, unclassified
         reduces) can still keep individual programs on the raw sizes."""
         from . import config as _config

@@ -121,8 +121,8 @@ def lift_to_block_level(graph):
     placeholders, scalar predicates) and lifted: after the lift the
     predicates carry the block's row axis and the masked dense
     lowerings in this module take over. This is how branchy serving
-    endpoints and block-level branchy maps are built (tests and
-    `benchmarks/autobatch_bench.py` use it)."""
+    endpoints and block-level branchy maps are built (the tests use
+    it)."""
     from ..proto.graphdef import AttrValue
     from ..schema import Shape
 

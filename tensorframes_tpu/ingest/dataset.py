@@ -6,7 +6,7 @@ ordered list of CHUNKS (groups of row groups / record batches). Shard
 discovery is deterministic: user-given order is preserved, and every
 directory/glob expansion is sorted lexicographically, so two runs over
 the same dataset see the same chunk ordinals — which is what makes the
-device rotation, fault injection and benchmark comparisons
+device rotation, fault injection and the tests' comparisons
 reproducible.
 
 Decode is per-chunk and self-contained: each `ChunkTask` re-opens its

@@ -57,9 +57,9 @@ Telemetry (always-live counters; gauges/spans gated on
 - the legacy ``stream_queue_depth`` gauge on the delivery queue.
 
 ``config.ingest_pipeline`` off runs the SAME stage functions inline on
-the consumer thread (stage-serial) — the A/B baseline
-`benchmarks/ingest_bench.py` measures against; error stamping and
-retry classification behave identically.
+the consumer thread (stage-serial) — the reference
+`tests/test_ingest.py` compares the pipeline's results against; error
+stamping and retry classification behave identically.
 """
 
 from __future__ import annotations

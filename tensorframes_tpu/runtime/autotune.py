@@ -41,8 +41,6 @@ Entry points: ``tfs.autotune(profile=...)`` — one-shot tuning from a
 live snapshot or a saved `WorkloadProfile` (path or object);
 ``config.autotune`` / ``TFS_AUTOTUNE`` — the in-process background
 loop (off by default: no thread starts, no knob is ever mutated).
-``benchmarks/autotune_bench.py`` proves each policy beats the static
-default on an adversarial workload.
 
 The four policies:
 
@@ -556,7 +554,7 @@ def recommend(profile=None, knobs: Optional[Dict] = None) -> List[Recommendation
     `runtime.profiler.snapshot()`) and return the recommendations —
     NOTHING is applied. ``knobs`` overrides the current knob values
     the policies compare against (default: the live config), which is
-    how benches/tests evaluate a policy against hypothetical settings.
+    how tests evaluate a policy against hypothetical settings.
     Deterministic: the same profile + knobs always recommend the same
     moves."""
     if profile is None:
@@ -858,7 +856,7 @@ class AutoTuner:
 
     def cycle(self) -> List[Dict]:
         """One deterministic tuning step (what the loop runs; callable
-        directly from tests/benches): snapshot -> delta vs the previous
+        directly from tests): snapshot -> delta vs the previous
         cycle -> recommend -> apply."""
         from . import profiler as _prof
 

@@ -68,8 +68,7 @@ def enabled() -> bool:
 
 
 # ---------------------------------------------------------------------------
-# device peaks (datasheet table — the ONE copy; benchmarks/_util.py and
-# bench.py import it from here)
+# device peaks (datasheet table — the ONE copy)
 # ---------------------------------------------------------------------------
 
 # Chip-level datasheet peaks by `device.device_kind`. f32 data runs the

@@ -417,8 +417,8 @@ class Executor:
     def cache_keys(self) -> List[Tuple]:
         """Snapshot of live compile-cache keys
         ``(kind, graph fingerprint, fetches, feed names)`` — the
-        introspection surface `benchmarks/fusion_bench.py` and the
-        fusion tests use to prove cache keying: a fused lazy pipeline
+        introspection surface the fusion tests use to prove cache
+        keying: a fused lazy pipeline
         must create exactly ONE ``"block"``-kind entry (the fused
         fingerprint) where the eager chain creates one per verb."""
         with self._lock:

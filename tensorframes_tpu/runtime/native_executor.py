@@ -311,7 +311,7 @@ class NativeExecutor:
 
     def cache_keys(self):
         """Interface parity with `Executor.cache_keys` (live compile-cache
-        key snapshot; the fusion bench/tests count kinds through it)."""
+        key snapshot; the fusion tests count kinds through it)."""
         with self._lock:
             return list(self._cache.keys())
 

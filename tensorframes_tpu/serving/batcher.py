@@ -25,7 +25,7 @@ analogue of the ingest engine's stage overlap:
   the shared row-local walk proves every fetch row-local, so output
   row i is a function of input row i alone — concat + dispatch + slice
   is bit-identical to per-request execution by construction
-  (serving_bench asserts it against direct verb calls).
+  (tests/test_serving.py asserts it against direct verb calls).
 
 Overload: a lane whose queue exceeds ``config.serve_queue_limit``
 sheds new arrivals immediately with the same typed `OverloadError` the

@@ -7,7 +7,7 @@ Spark task (`DebugRowOps.scala:790`). A serving process inverts that:
 programs are registered ONCE, validated against a declared column
 schema, and compiled WARM across every bucket-ladder rung up to the
 configured max batch — so steady-state traffic compiles nothing
-(`Executor.jit_shape_compiles` flat, asserted by serving_bench), the
+(`Executor.jit_shape_compiles` flat, asserted by tests/test_serving.py), the
 long-lived-session model of "TensorFlow: A system for large-scale
 machine learning" (PAPERS.md).
 

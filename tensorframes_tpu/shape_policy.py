@@ -272,8 +272,8 @@ def bucket_ladder(
     min_bucket: Optional[int] = None,
 ) -> List[int]:
     """The distinct rungs covering block sizes 1..max_rows — the bound
-    on compiled shape specializations per program (benchmarks and tests
-    assert against its length)."""
+    on compiled shape specializations per program (the tests assert
+    against its length)."""
     rungs: List[int] = []
     n = 1
     while n <= max_rows:

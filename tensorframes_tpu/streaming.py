@@ -402,7 +402,7 @@ def reduce_blocks_stream(
     # reassociation stays within the documented float-sum tolerance;
     # min/max/prod/int-sum are exact), never for durable streams (the
     # checkpoint protocol commits the partials LIST), and gated on
-    # ``config.plan_pipeline`` so the A/B benchmark can hold it still.
+    # ``config.plan_pipeline`` so a test can hold it still.
     dbuf: List[Optional[Dict]] = [None, None]
     dbuf_n = [0]
     dbuf_ok = [True]

@@ -30,8 +30,8 @@ Overhead contract: ``config.telemetry`` (env ``TFS_TELEMETRY``, default
 ON) gates ALL span recording, histogram observation and annotation —
 when off, a span site costs one config read and a no-op context
 manager. Counters are always live (they predate this module:
-``host_sync``, ``<verb>.calls`` and friends are asserted by tests and
-benchmarks), and `record()`/`count()` keep their exact signatures as
+``host_sync``, ``<verb>.calls`` and friends are asserted by tests),
+and `record()`/`count()` keep their exact signatures as
 thin shims over the registry, so no call site breaks.
 """
 
@@ -1955,7 +1955,7 @@ def diagnostics(executor=None, format: str = "text"):
     memory, OOM forensics, merged with `executor_stats()` and the
     recompile-storm signal. ``format="text"`` (default) renders the
     human table; ``format="json"`` returns the machine-readable dict
-    (`diagnostics_data`) so benches and CI consume structured data
+    (`diagnostics_data`) so tests and CI consume structured data
     instead of scraping text. Exposed as ``tfs.diagnostics()``."""
     if format not in ("text", "json"):
         raise ValueError(

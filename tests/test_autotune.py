@@ -798,6 +798,77 @@ class TestOneShotAndLoop:
         tuner.cycle()  # nothing new happened: the delta has no evidence
         assert config.tuned().get("shape_bucket_growth") == 1.5
 
+    def test_closed_loop_on_clustered_blocks_keeps_results(self):
+        """Block sizes clustered just above a growth-2 rung: run,
+        snapshot, recommend, apply until quiet. The loop shrinks the
+        growth, the ladder's mean fill recovers, and the tuned ladder's
+        map and min are bit-identical to the static one's, its sum
+        within the reassociation tolerance."""
+        from tensorframes_tpu import shape_policy as sp
+        from tensorframes_tpu.runtime import costmodel
+        from tensorframes_tpu.runtime.executor import Executor
+
+        sizes = [33_000 + (i * 37) % 4_000 for i in range(16)]
+        static_growth = config.default_value("shape_bucket_growth")
+        static_fill = float(np.mean([
+            s / sp.bucket_for(s, growth=static_growth, min_bucket=8)
+            for s in sizes
+        ]))
+        assert static_fill <= 0.70  # the workload wastes >= 30% as set
+        n = sum(sizes)
+        col = tfs.TensorFrame.from_dict(
+            {"x": (np.arange(n) % 251).astype(np.float32)}
+        )["x"]
+        df = tfs.TensorFrame([col], list(np.cumsum([0] + sizes)))
+
+        def workload(ex):
+            x = tfs.block(df, "x")
+            y = (dsl.tanh(x * 0.5) * 2.0 + x).named("y")
+            mn = dsl.reduce_min(
+                tfs.block(df, "x", tf_name="x_input"), axes=[0]
+            ).named("x")
+            s = dsl.reduce_sum(
+                tfs.block(df, "x", tf_name="x_input"), axes=[0]
+            ).named("x")
+            return {
+                "map": np.asarray(
+                    tfs.map_blocks(y, df, executor=ex)["y"].values
+                ),
+                "min": float(np.asarray(
+                    tfs.reduce_blocks(mn, df, executor=ex)
+                )),
+                "sum": float(np.asarray(
+                    tfs.reduce_blocks(s, df, executor=ex)
+                )),
+            }
+
+        with config.override(block_scheduler="off"):
+            ref = workload(Executor())
+            probe = Executor()
+            moves = []
+            for _ in range(4):
+                telemetry.reset()
+                costmodel.reset()
+                workload(probe)
+                moved = [
+                    d for d in tfs.autotune()["applied"]
+                    if d["outcome"] == "applied"
+                ]
+                moves += moved
+                if not moved:
+                    break
+            tuned_growth = config.get().shape_bucket_growth
+            tuned_fill = float(np.mean(
+                [s / sp.bucket_for(s) for s in sizes]
+            ))
+            got = workload(Executor())
+        assert [d for d in moves if d["knob"] == "shape_bucket_growth"]
+        assert tuned_growth < static_growth
+        assert tuned_fill > static_fill + 0.1, (static_fill, tuned_fill)
+        np.testing.assert_array_equal(got["map"], ref["map"])
+        assert got["min"] == ref["min"]
+        np.testing.assert_allclose(got["sum"], ref["sum"], rtol=1e-5)
+
     def test_diagnostics_and_profile_surface_state(self):
         config.set_tuned("stream_prefetch_depth", 3)
         data = tfs.diagnostics(format="json")

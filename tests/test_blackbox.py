@@ -137,6 +137,46 @@ class TestAcceptance:
         with config.override(incident_dir=str(tmp_path)):
             assert float(np.asarray(_chain(df))) == ref
 
+    def test_fault_free_runs_capture_nothing_armed_or_disarmed(
+        self, tmp_path
+    ):
+        df = _frame()
+        with config.override(incident_dir=str(tmp_path)):
+            ref = float(np.asarray(_chain(df)))
+            for _ in range(3):
+                assert float(np.asarray(_chain(df))) == ref
+                with config.override(incident_capture=False):
+                    assert float(np.asarray(_chain(df))) == ref
+            assert tfs.incidents() == []
+        assert blackbox.state()["captured"] == 0
+
+    def test_deadline_storm_without_dedup_bundles_every_fault(
+        self, tmp_path
+    ):
+        """With the rate limit off, each of a burst of hung, deadlined
+        verbs writes its own bundle and observes one capture latency;
+        the gate drains and the next call is bit-identical."""
+        df = _frame()
+        ref = float(np.asarray(_chain(df)))
+        storm = 3
+        hits = 0
+        with config.override(
+            incident_dir=str(tmp_path), incident_rate_limit_s=0.0
+        ):
+            with chaos.inject(rate=1.0, seed=1, fault="hang", delay_s=30.0):
+                for _ in range(storm):
+                    try:
+                        _chain(df, timeout_s=0.05)
+                    except tfs.DeadlineExceeded:
+                        hits += 1
+            assert hits == storm
+            assert len(tfs.incidents()) == storm
+        assert blackbox.state()["captured"] == storm
+        _c, _g, hists = telemetry.metrics_snapshot()
+        assert hists[("incident_capture_seconds", ())][3] == storm
+        assert dl.controller().in_flight_now() == 0
+        assert float(np.asarray(_chain(df))) == ref
+
     def test_overload_burst_one_bundle_rest_suppressed(self, tmp_path):
         df = _frame()
         _chain(df)  # warm so every burst call sheds at admission

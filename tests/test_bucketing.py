@@ -1394,6 +1394,51 @@ class TestLazyFusion:
         )
 
 
+class TestWarmRerun:
+    def test_rerun_adds_no_program_and_no_shape(self):
+        """64 distinct block sizes through a map, three reduces and a
+        fused chain: every program stays on the ladder, and a second
+        pass on the warm executor compiles nothing and answers the
+        same."""
+        sizes = [5 + 3 * i for i in range(64)]
+        df = _uneven(sizes, mod=251)
+
+        def workload(ex):
+            mapped = tfs.map_blocks(
+                (tfs.block(df, "x") * 2.0 + 1.0).named("y"), df,
+                executor=ex,
+            )
+            out = {"map": np.asarray(mapped["y"].values)}
+            for op in ("sum", "min", "mean"):
+                out[op] = np.asarray(
+                    tfs.reduce_blocks(_reduce(df, op), df, executor=ex)
+                )
+            lf = df.lazy().map_blocks(
+                (tfs.block(df, "x") * 3.0).named("z"), executor=ex
+            )
+            out["fused"] = np.asarray(
+                lf.reduce_blocks(_reduce(lf, "sum", "z"), executor=ex)
+            )
+            return out
+
+        ex = Executor()
+        # single-device bound (scheduler-off; see TestBucketedMap note)
+        with tfs.config.override(block_scheduler="off"):
+            first = workload(ex)
+            ladder = math.ceil(math.log2(max(sizes))) + 2
+            per_program = [
+                fn._cache_size() for fn in ex._cache.values()
+                if callable(getattr(fn, "_cache_size", None))
+            ]
+            assert per_program and max(per_program) <= ladder, per_program
+            misses, shapes = ex.cache_misses, ex.jit_shape_compiles()
+            again = workload(ex)
+        assert ex.cache_misses == misses
+        assert ex.jit_shape_compiles() == shapes
+        for k in first:
+            np.testing.assert_array_equal(again[k], first[k])
+
+
 class TestObservability:
     def test_executor_stats_has_shape_compiles(self):
         ex = Executor()

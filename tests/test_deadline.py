@@ -356,6 +356,51 @@ class TestVerbTimeouts:
             again = float(np.asarray(chain(df)))
         assert again == ref
 
+    def test_deadline_storm_leaks_no_thread_and_no_slot(self):
+        """Verb after verb wedged by injected hangs, then a stalled
+        stream, each killed by a small budget: every one raises, the
+        process is left with no thread it did not have before and a
+        drained admission gate, and the next call is bit-identical."""
+        df = _frame(n=256, blocks=4, seed=5)
+        fetch = _sum_fetch(df)
+        ref = float(np.asarray(tfs.reduce_blocks(fetch, df)))
+        before = {t.ident for t in threading.enumerate() if t.is_alive()}
+        storm = 3
+        hits = 0
+        with chaos.inject(rate=1.0, seed=1, fault="hang", delay_s=30.0):
+            for _ in range(storm):
+                try:
+                    tfs.reduce_blocks(fetch, df, timeout_s=0.05)
+                except dl.DeadlineExceeded:
+                    hits += 1
+
+        def stalling_chunks():
+            for i in range(10_000):
+                time.sleep(0.02)
+                yield TensorFrame.from_dict(
+                    {"x": np.ones(16, dtype=np.float32) * i}
+                )
+
+        try:
+            tfs.reduce_blocks_stream(fetch, stalling_chunks(), timeout_s=0.2)
+        except dl.DeadlineExceeded:
+            hits += 1
+        assert hits == storm + 1
+        # teardown is cooperative: wait for it, within a bound
+        end = time.monotonic() + 10.0
+        leaked = None
+        while time.monotonic() < end:
+            leaked = [
+                t.name for t in threading.enumerate()
+                if t.is_alive() and t.ident not in before
+            ]
+            if not leaked:
+                break
+            time.sleep(0.05)
+        assert not leaked, leaked
+        assert dl.controller().in_flight_now() == 0
+        assert float(np.asarray(tfs.reduce_blocks(fetch, df))) == ref
+
     def test_default_verb_timeout_config_knob(self):
         df = _frame()
         with config.override(default_verb_timeout_s=0.2):

@@ -21,6 +21,7 @@ from tensorframes_tpu.runtime.scheduler import (
     device_health,
 )
 from tensorframes_tpu.testing import faults as chaos
+from tensorframes_tpu.utils.profiling import reset_stats, stats
 
 
 def _sum_graph(df):
@@ -213,6 +214,49 @@ class TestClassifiedRetries:
         assert ref_min == got_min
         led = rtf.ledger_snapshot()
         assert led["transient"] > 0 and led["retries"] > 0
+
+    def test_chained_map_reduce_under_faults_keeps_results_and_syncs(self):
+        """30% transient faults over a chained map -> sum/min/max on a
+        device-resident frame: the map, min and max are bit-identical
+        to the fault-free chain, the sum is within the reassociation
+        tolerance, and retrying adds no host sync."""
+        rng = np.random.RandomState(0)
+        df = tfs.TensorFrame.from_dict(
+            {"x": rng.rand(8192).astype(np.float32)}, num_blocks=8
+        ).to_device()
+        z = (tfs.block(df, "x") * 2.0 + 1.0).named("y")
+
+        def chained():
+            mapped = tfs.map_blocks(z, df)
+            res = {
+                op: float(np.asarray(tfs.reduce_blocks(
+                    fn(tfs.block(mapped, "y", tf_name="y_input"),
+                       axes=[0]).named("y"),
+                    mapped,
+                )))
+                for op, fn in (("sum", dsl.reduce_sum),
+                               ("min", dsl.reduce_min),
+                               ("max", dsl.reduce_max))
+            }
+            return np.asarray(mapped["y"].values), res
+
+        chained()  # compiles out of the counted runs
+        reset_stats()
+        ref_map, ref = chained()
+        syncs_clean = stats().get("host_sync", 0)
+        with config.override(
+            block_retry_attempts=8, verb_retry_budget=500, **FAST_RETRY
+        ):
+            reset_stats()
+            with chaos.inject(rate=0.3, seed=7, fault="transient") as plan:
+                got_map, got = chained()
+            syncs_chaos = stats().get("host_sync", 0)
+        assert plan.injected > 0
+        np.testing.assert_array_equal(ref_map, got_map)
+        assert got["min"] == ref["min"] and got["max"] == ref["max"]
+        np.testing.assert_allclose(got["sum"], ref["sum"], rtol=1e-5)
+        assert syncs_chaos == syncs_clean
+        assert rtf.ledger_snapshot()["retries"] > 0
 
     def test_deterministic_error_single_attempt_e2e(self):
         """check_numerics' FloatingPointError must surface immediately

@@ -251,11 +251,11 @@ def _run_freeze_child(body: str, tmpdir: str, tag: str):
 
 
 def _freeze_via_subprocess(model: str, hw: int, batch: int, tmpdir):
-    """Keras-zoo freeze through the SAME shared helper the benchmark
-    uses, so the graph measured there is byte-identical to the graph
-    validated here."""
+    """Keras-zoo freeze through the SAME shared helper `chip_smoke.py`
+    phase f uses, so the graph scored on the chip is byte-identical to
+    the graph validated here."""
     body = (
-        "from benchmarks._util import freeze_keras_model\n"
+        "from tensorframes_tpu.testing.keras_freeze import freeze_keras_model\n"
         f"wire, innode, outnode, score = freeze_keras_model({model!r}, {hw})\n"
         "rng = np.random.default_rng(0)\n"
         f"feeds = rng.normal(size=({batch},{hw},{hw},3))"
@@ -276,8 +276,8 @@ class TestFrozenKerasInceptionV3:
     75x75 input (the architecture's documented minimum) keeps the CPU
     conv cost testable; the weight tensors — 96 MB — are identical to
     the 299x299 configuration, so proto decode and constant ingestion
-    run at full production scale. The bench scores the 299x299 form
-    (`benchmarks/run_all.py`)."""
+    run at full production scale. `chip_smoke.py` phase f scores the
+    299x299 form on the chip."""
 
     @pytest.fixture(scope="class")
     def frozen(self, tmp_path_factory):

@@ -19,6 +19,7 @@ from tensorframes_tpu import dsl
 from tensorframes_tpu.lazy import LazyFrame, LazyPlan
 from tensorframes_tpu.runtime.executor import Executor
 from tensorframes_tpu.schema import ScalarType, Shape
+from tensorframes_tpu.utils.profiling import reset_stats, stats
 
 
 def _frame(rows=24, blocks=3, dtype=np.float32):
@@ -278,6 +279,27 @@ class TestCacheKeying:
         r2 = run()  # freshly spliced graph, identical fused fingerprint
         assert ex.cache_misses == misses
         assert np.asarray(r1) == np.asarray(r2)
+
+    def test_fused_chain_issues_no_host_sync(self):
+        """Repeated fused runs on a device-resident frame fetch nothing
+        to the host: the lazy plan never materialises an intermediate,
+        and every run equals the eager chain."""
+        df = _frame(rows=4000, blocks=4).to_device()
+        ex = Executor()
+        m = _eager_chain(df, executor=ex)
+        eager = tfs.reduce_blocks(_sum_of(m, "z"), m, executor=ex)
+
+        def fused():
+            lf = _lazy_chain(df, executor=ex)
+            return lf.reduce_blocks(_sum_of(lf, "z"), executor=ex)
+
+        fused()  # compiles out of the counted runs
+        reset_stats()
+        outs = [jax.block_until_ready(fused()) for _ in range(3)]
+        assert stats().get("host_sync", 0) == 0
+        for out in outs:
+            assert isinstance(out, jax.Array)
+            assert np.asarray(out) == np.asarray(eager)
 
 
 class TestLazyModeAndPlan:

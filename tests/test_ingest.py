@@ -331,6 +331,62 @@ class TestStreamDataset:
         assert got_min == want_min  # bit-identical
         np.testing.assert_allclose(got_sum, want_sum, rtol=1e-6)
 
+    @pytest.mark.parametrize(
+        "ingest_pipeline, plan_pipeline",
+        [(True, True), (False, True), (False, False)],
+        ids=["pipelined", "ingest-serial", "stage-serial"],
+    )
+    def test_multi_fetch_stream_matches_whole_frame(
+        self, tmp_path, ingest_pipeline, plan_pipeline
+    ):
+        """A multi-shard dataset streamed into a sum/min/max reduce, and
+        a lazy per-chunk map fused into a min, give the whole frame's
+        answers (min and max bit-identical, the sum within the
+        reassociation tolerance) with no host sync, whether the stages
+        overlap or run one after another."""
+        root, allx = _write_shards(tmp_path, [300, 300, 300, 300], blocks=2)
+        df0 = TensorFrame.from_dict({"x": allx[:2]})
+        fetches = [
+            fn(tfs.block(df0, "x", tf_name=f"{name}_input"), axes=[0])
+            .named(name)
+            for name, fn in (("s", dsl.reduce_sum), ("mn", dsl.reduce_min),
+                             ("mx", dsl.reduce_max))
+        ]
+        feeds = {"s_input": "x", "mn_input": "x", "mx_input": "x"}
+        xi = tfs.block(df0, "x", tf_name="x_input")
+        z = (dsl.tanh(xi) * 0.25 + xi).named("z")
+        zmin = dsl.reduce_min(
+            tfs.block(df0, "x", tf_name="zmn_input"), axes=[0]
+        ).named("zmn")
+        whole = TensorFrame.from_dict({"x": allx}, num_blocks=4)
+        ref = tfs.reduce_blocks(fetches, whole, feed_dict=feeds)
+        ref_map = whole.lazy().map_blocks(
+            z, feed_dict={"x_input": "x"}
+        ).reduce_blocks(zmin, feed_dict={"zmn_input": "z"})
+        with config.override(
+            ingest_pipeline=ingest_pipeline, plan_pipeline=plan_pipeline
+        ):
+            tfs.reduce_blocks_stream(  # compiles out of the counted run
+                fetches, stream_dataset(root, decode_workers=2), feeds
+            )
+            reset_stats()
+            got = tfs.reduce_blocks_stream(
+                fetches, stream_dataset(root, decode_workers=2), feeds
+            )
+            got = {k: float(np.asarray(v)) for k, v in got.items()}
+            syncs = stats().get("host_sync", 0)
+            got_map = tfs.reduce_blocks_stream(
+                zmin,
+                (f.lazy().map_blocks(z, feed_dict={"x_input": "x"})
+                 for f in stream_dataset(root, decode_workers=2)),
+                feed_dict={"zmn_input": "z"},
+            )
+        assert syncs == 0
+        assert got["mn"] == float(ref["mn"])
+        assert got["mx"] == float(ref["mx"])
+        np.testing.assert_allclose(got["s"], float(ref["s"]), rtol=1e-5)
+        assert float(np.asarray(got_map)) == float(np.asarray(ref_map))
+
     def test_empty_shard_contributes_nothing(self, tmp_path):
         root, allx = _write_shards(tmp_path, [8, 8])
         empty = TensorFrame.from_dict({"x": np.zeros(0, np.float32)})

@@ -9,13 +9,10 @@ forensics (`runtime.faults.record_oom`): snapshots in
 ``executor_stats()["faults"]["forensics"]`` naming program / modeled
 footprint / split decision for split and re-raise paths; the HTTP
 endpoint (`utils.telemetry_http`): all four routes, concurrent-scrape
-consistency during a scheduled multi-device run, health degradation;
-and the `tools/bench_compare.py` regression differ.
+consistency during a scheduled multi-device run, health degradation.
 """
 
-import importlib.util
 import json
-import os
 import re
 import threading
 import urllib.error
@@ -185,11 +182,6 @@ class TestCostLedger:
         jax.block_until_ready(df.column("x").values)
         text = telemetry.export_prometheus()
         assert "tfs_live_buffer_bytes{device=" in text
-
-    def test_mfu_harness_reads_the_ledger(self):
-        from benchmarks._util import DEVICE_PEAKS as reexported
-
-        assert reexported is costmodel.DEVICE_PEAKS
 
 
 # ---------------------------------------------------------------------------
@@ -409,91 +401,3 @@ class TestEndpoint:
         finally:
             if srv is not None:
                 srv.close()
-
-
-# ---------------------------------------------------------------------------
-# tools/bench_compare.py
-# ---------------------------------------------------------------------------
-
-
-def _load_bench_compare():
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "tools", "bench_compare.py",
-    )
-    spec = importlib.util.spec_from_file_location("bench_compare", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-class TestBenchCompare:
-    def test_parse_results_skips_noise(self):
-        bc = _load_bench_compare()
-        text = (
-            'warming up...\n'
-            '{"metric": "m1", "value": 10, "unit": "rows/s"}\n'
-            '{"not_metric": true}\n'
-            '{"metric": "m2", "value": "NaNish", "unit": "s"}\n'
-            '{"metric": "m3", "value": 1.5, "unit": "s"}\n'
-        )
-        got = bc.parse_results(text)
-        assert [m["metric"] for m in got] == ["m1", "m3"]
-
-    def test_baseline_formats(self):
-        bc = _load_bench_compare()
-        one = '{"metric": "a", "value": 1, "unit": "x", "history": []}'
-        arr = '[{"metric": "a", "value": 1}, {"metric": "b", "value": 2}]'
-        lines = '{"metric": "a", "value": 1}\n{"metric": "b", "value": 2}'
-        assert len(bc.parse_baseline(one)) == 1
-        assert len(bc.parse_baseline(arr)) == 2
-        assert len(bc.parse_baseline(lines)) == 2
-
-    def test_direction_aware_verdicts(self):
-        bc = _load_bench_compare()
-        base = [
-            {"metric": "thr", "value": 100.0, "unit": "rows/s"},
-            {"metric": "lat", "value": 1.0, "unit": "s"},
-        ]
-        # 30% worse both ways -> both regress at 20% tolerance
-        res = [
-            {"metric": "thr", "value": 70.0, "unit": "rows/s"},
-            {"metric": "lat", "value": 1.3, "unit": "s"},
-        ]
-        _, regressions = bc.compare(res, base, 0.20)
-        assert {r["metric"] for r in regressions} == {"thr", "lat"}
-        # 30% BETTER both ways -> clean
-        res = [
-            {"metric": "thr", "value": 130.0, "unit": "rows/s"},
-            {"metric": "lat", "value": 0.7, "unit": "s"},
-        ]
-        _, regressions = bc.compare(res, base, 0.20)
-        assert regressions == []
-
-    def test_per_metric_tolerance_and_table(self):
-        bc = _load_bench_compare()
-        base = [{"metric": "thr", "value": 100.0, "unit": "rows/s"}]
-        res = [
-            {"metric": "thr", "value": 60.0, "unit": "rows/s"},
-            {"metric": "new", "value": 1.0, "unit": "x"},
-        ]
-        rows, regressions = bc.compare(res, base, 0.20, {"thr": 0.5})
-        assert regressions == []
-        verdicts = {r["metric"]: r["verdict"] for r in rows}
-        assert verdicts == {"thr": "ok", "new": "no-baseline"}
-        table = bc.render(rows)
-        assert "thr" in table and "no-baseline" in table
-
-    def test_main_exit_codes(self, tmp_path):
-        bc = _load_bench_compare()
-        res = tmp_path / "res.jsonl"
-        base = tmp_path / "base.json"
-        res.write_text('{"metric": "m", "value": 50, "unit": "rows/s"}\n')
-        base.write_text('{"metric": "m", "value": 100, "unit": "rows/s"}')
-        assert bc.main([str(res), str(base)]) == 1
-        assert bc.main([str(res), str(base), "--tolerance", "0.6"]) == 0
-        base.write_text('{"metric": "other", "value": 1, "unit": "x"}')
-        assert bc.main([str(res), str(base)]) == 0
-        assert (
-            bc.main([str(res), str(base), "--require-match"]) == 1
-        )

@@ -475,6 +475,44 @@ class TestServer:
         if shed_header is not None:
             assert int(shed_header) >= 1
 
+    def test_overload_admitted_requests_finish_bit_identical(self, served):
+        """A burst behind a wedged dispatch and a 1-deep lane queue:
+        every client returns, the excess sheds typed with a positive
+        hint, and what was admitted equals the unbatched answer."""
+        _handle, client = served
+        _register_score(warm=False)
+        from tensorframes_tpu.testing import faults as chaos
+
+        req = _req(5, seed=4)
+        want = (req.column("x").host_values() * 2.0 + 1.0).astype(
+            np.float32
+        )
+        sheds, oks = [], []
+
+        def one():
+            try:
+                out = client.run("score", req, timeout_s=15.0)
+                oks.append(np.asarray(out.column("score").host_values()))
+            except tfs.OverloadError as e:
+                sheds.append(e)
+
+        with config.override(serve_queue_limit=1):
+            with chaos.inject(
+                rate=1.0, seed=1, fault="hang", delay_s=1.5, max_faults=1
+            ):
+                hold = threading.Thread(target=one)
+                hold.start()
+                time.sleep(0.5)  # dispatcher inside the hang
+                ts = [threading.Thread(target=one) for _ in range(4)]
+                for t in ts:
+                    t.start()
+                for t in ts + [hold]:
+                    t.join(timeout=60)
+        assert not any(t.is_alive() for t in ts + [hold])
+        assert len(sheds) + len(oks) == 5
+        assert sheds and all(e.retry_after_s > 0 for e in sheds)
+        assert oks and all(np.array_equal(o, want) for o in oks)
+
     def test_shared_server_still_serves_telemetry(self, served):
         handle, _client = served
         base = f"http://{handle.host}:{handle.port}"

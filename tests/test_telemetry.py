@@ -254,6 +254,49 @@ class TestExporters:
             assert verb["ts"] <= d["ts"]
             assert verb["ts"] + verb["dur"] >= d["ts"] + d["dur"]
 
+    def test_traced_chain_on_fresh_executor_exports_compiles_and_blocks(
+        self, tmp_path
+    ):
+        """A chained map -> reduce traced on a fresh executor: the
+        exported trace holds the chain's compiles and one block-labelled
+        dispatch a block, each under a verb, and the answer is exact."""
+        tele.reset()
+        df = tfs.TensorFrame.from_dict(
+            {"x": np.arange(4000, dtype=np.float32)}, num_blocks=4
+        ).to_device()
+        ex = tfs.Executor()
+        with config.override(telemetry=True):
+            mapped = tfs.map_blocks(
+                (tfs.block(df, "x") * 2.0 + 1.0).named("y"), df, executor=ex
+            )
+            total = tfs.reduce_blocks(
+                dsl.reduce_sum(
+                    tfs.block(mapped, "y", tf_name="y_input"), axes=[0]
+                ).named("y"),
+                mapped, executor=ex,
+            )
+        assert float(np.asarray(total)) == float(
+            (2.0 * np.arange(4000.0) + 1.0).sum()
+        )
+        events = tele.export_chrome_trace(str(tmp_path / "t.json"))[
+            "traceEvents"
+        ]
+        assert [e for e in events if e["cat"] == "compile"]
+        per_block = [
+            e for e in events
+            if e["cat"] == "dispatch" and e["args"].get("block") is not None
+        ]
+        assert {e["args"]["block"] for e in per_block} == {0, 1, 2, 3}
+        verbs = {e["args"]["span_id"] for e in events if e["cat"] == "verb"}
+        by_id = {e["args"]["span_id"]: e for e in events}
+
+        def under_a_verb(e):
+            while e is not None and e["args"]["span_id"] not in verbs:
+                e = by_id.get(e["args"].get("parent_id"))
+            return e is not None
+
+        assert all(under_a_verb(e) for e in per_block)
+
     def test_prometheus_text_format(self):
         tele.reset()
         reset_stats()

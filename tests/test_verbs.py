@@ -240,6 +240,38 @@ class TestReduceBlocks:
         res = tfs.reduce_blocks([x, y], df)
         assert res["x"] == 6.0 and res["y"] == 4.0
 
+    def test_int64_map_then_sum_exact(self):
+        # the reference's performance suite: x + x over longs, then a sum
+        n = 100_000
+        df = tfs.TensorFrame.from_dict(
+            {"x": np.arange(n, dtype=np.int64)}, num_blocks=4
+        ).to_device()
+        mapped = tfs.map_blocks((tfs.block(df, "x") * 2).named("z"), df)
+        s = dsl.reduce_sum(
+            tfs.block(mapped, "z", tf_name="z_input"), axes=[0]
+        ).named("z")
+        assert int(tfs.reduce_blocks(s, mapped)) == (n - 1) * n
+
+    def test_mean_variance_in_one_two_fetch_pass(self):
+        # squares by map_blocks, then [sum, sum of squares] from ONE
+        # reduce pass: the associative form of mean and variance
+        data = np.random.RandomState(0).rand(4000, 8).astype(np.float32)
+        df = tfs.TensorFrame.from_dict({"v": data}, num_blocks=8)
+        squared = tfs.map_blocks(
+            dsl.square(tfs.block(df, "v")).named("vsq"), df
+        )
+        s = dsl.reduce_sum(
+            tfs.block(squared, "v", tf_name="v_input"), axes=[0]
+        ).named("v")
+        sq = dsl.reduce_sum(
+            tfs.block(squared, "vsq", tf_name="vsq_input"), axes=[0]
+        ).named("vsq")
+        res = tfs.reduce_blocks([s, sq], squared)
+        mean = np.asarray(res["v"]) / len(data)
+        var = np.asarray(res["vsq"]) / len(data) - mean**2
+        np.testing.assert_allclose(mean, data.mean(0), rtol=1e-5)
+        np.testing.assert_allclose(var, data.var(0), rtol=1e-3)
+
     def test_naming_convention_enforced(self):
         df = frame_of(x=np.arange(3.0))
         bad = tfs.block(df, "x", tf_name="wrong")  # must be named 'x_input'

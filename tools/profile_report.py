@@ -8,8 +8,7 @@ Usage::
     python tools/profile_report.py PROFILE.json --json
 
 A profile is what `tfs.profile.snapshot().save(path)` writes (also
-scraped live from the telemetry server's ``/profile`` route, or emitted
-by ``benchmarks/run_all.py`` as its ``PROFILE_ARTIFACT``). The report
+scraped live from the telemetry server's ``/profile`` route). The report
 renders the sections a tuning/capacity reader wants in one screen:
 per-verb totals + latency quantile sketch, per-program exec/rung/cost
 rows, bucket fill economics, serving batch economics, ingest
